@@ -31,19 +31,9 @@ SPARK_LOCAL_BASELINE_S = 1800.0
 
 
 def make_data(rows: int, cols: int, seed: int = 11):
-    import numpy as np
-    import pandas as pd
+    from transmogrifai_tpu.testkit import planted_linear_frame
 
-    rng = np.random.default_rng(seed)
-    X = rng.normal(size=(rows, cols)).astype(np.float32)
-    beta = np.zeros(cols, np.float32)
-    informative = rng.choice(cols, max(3, cols // 20), replace=False)
-    beta[informative] = rng.normal(size=len(informative)) * 1.5
-    z = X @ beta + 0.5 * rng.normal(size=rows).astype(np.float32)
-    y = (1 / (1 + np.exp(-z)) > rng.random(rows)).astype(np.float32)
-    df = pd.DataFrame(X, columns=[f"f{j}" for j in range(cols)])
-    df.insert(0, "label", y)
-    return df
+    return planted_linear_frame(rows, cols, seed)
 
 
 def default_grid_models():

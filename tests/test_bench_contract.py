@@ -1,13 +1,21 @@
 """bench.py driver contract: config order, headline priority, crash
-resilience, and measured-cost-history estimates.
+resilience, measured-cost-history estimates, and the no-chip failure.
 
 The driver invokes ``python bench.py`` blind and parses the LAST complete
-JSON line; these tests pin that contract with the heavy configs mocked.
+JSON line; these tests pin that contract with the heavy configs mocked and
+the device faked (the suite runs on CPU, where the real bench refuses to
+measure).
 """
 import importlib
+import inspect
 import json
 import sys
 import types
+
+import pytest
+
+_FAKE_CHIP = {"platform": "tpu", "device_kind": "TPU v5 lite", "count": 1}
+
 
 def _load_bench(tmp_path, monkeypatch, scale_behavior, xgb_behavior=None):
     """Import a fresh bench module wired to mock workloads.
@@ -65,16 +73,7 @@ def _load_bench(tmp_path, monkeypatch, scale_behavior, xgb_behavior=None):
                              or {"hist_mfu": 0.01})
     monkeypatch.setitem(sys.modules, "bench_kernels", fake_kern)
 
-    def headline_runner(timeout_s):
-        calls.append((1_000_000, 500, "default"))
-        out = scale_behavior(1_000_000, 500, "default")
-        if isinstance(out, Exception):
-            return None, {"error": f"headline subprocess rc=1; "
-                                   f"stderr tail: {out}",
-                          "elapsed_s": 1.0}
-        return out, None
-
-    monkeypatch.setattr(bench, "_HEADLINE_RUNNER", headline_runner)
+    monkeypatch.setattr(bench, "_device", lambda: dict(_FAKE_CHIP))
     return bench, calls
 
 
@@ -103,7 +102,8 @@ class TestBenchContract:
         monkeypatch.delenv("TMOG_BENCH_SKIP_1M_DEFAULT", raising=False)
         last = _run_main(bench, capsys)
         grid_calls = [c for c in calls if len(c) == 3]
-        # light 1M, 100k default, then the quarantined 1M default LAST
+        # light 1M, 100k default, then the 1M default LAST — in process,
+        # through the same bench_scale.run as every other grid config
         assert grid_calls == [(1_000_000, 500, "light"),
                               (100_000, 500, "default"),
                               (1_000_000, 500, "default")]
@@ -114,6 +114,10 @@ class TestBenchContract:
         assert set(last["configs"]) >= {"titanic", "scale_1m_x_500",
                                         "default_grid_1m_x_500",
                                         "xgb_wide", "kernels"}
+        # every line names the device it ran on
+        assert last["device"] == _FAKE_CHIP
+        assert last["backend"] == "tpu"
+        assert "backend_fallback" not in last
 
     def test_headline_priority_when_default_1m_crashes(self, tmp_path,
                                                        monkeypatch, capsys):
@@ -168,58 +172,58 @@ class TestBenchContract:
             last["configs"]["default_grid_1m_x_500"]["skipped"])
 
 
-class TestHeadlineSubprocessParsing:
-    """The real _run_headline_subprocess parse/classify logic (below the
-    _HEADLINE_RUNNER seam) — subprocess.run is faked."""
+class TestNoChip:
+    """A run that finds no accelerator ends in a non-zero exit and ONE
+    parseable JSON error line — never a CPU re-exec, never a child
+    process that would need the chip its parent holds."""
 
-    def _bench_with_proc(self, tmp_path, monkeypatch, returncode, stdout,
-                         stderr="", timeout_raises=False):
-        import subprocess as sp
-
+    def _bench(self, tmp_path, monkeypatch):
         import bench as bench_mod
+
         bench = importlib.reload(bench_mod)
         monkeypatch.setattr(bench, "COST_HISTORY",
                             str(tmp_path / "ch.json"))
-
-        class FakeProc:
-            def __init__(self):
-                self.returncode = returncode
-                self.stdout = stdout
-                self.stderr = stderr
-
-        def fake_run(cmd, capture_output, text, timeout):
-            assert "--baseline-s" in cmd       # baselines.json wiring
-            if timeout_raises:
-                raise sp.TimeoutExpired(cmd, timeout)
-            return FakeProc()
-
-        monkeypatch.setattr(bench.subprocess if hasattr(bench, "subprocess")
-                            else sp, "run", fake_run)
+        monkeypatch.setattr(
+            bench, "run_titanic",
+            lambda: pytest.fail("a config ran without a chip"))
         return bench
 
-    def test_success_parses_last_json_line(self, tmp_path, monkeypatch):
-        good = json.dumps({"value": 9.0, "aupr": 0.9})
-        bench = self._bench_with_proc(
-            tmp_path, monkeypatch, 0, f"noise\n{good}\n")
-        d, err = bench._run_headline_subprocess(60)
-        assert err is None and d["value"] == 9.0
+    def _fails_with_json(self, bench, capsys):
+        with pytest.raises(SystemExit) as exc:
+            bench.main()
+        assert exc.value.code not in (0, None)
+        lines = [ln for ln in capsys.readouterr().out.splitlines()
+                 if ln.strip()]
+        assert len(lines) == 1
+        doc = json.loads(lines[0])
+        assert doc["ok"] is False
+        return doc
 
-    def test_nonzero_rc_records_stderr_tail(self, tmp_path, monkeypatch):
-        bench = self._bench_with_proc(
-            tmp_path, monkeypatch, 1, "", stderr="x" * 600 + "BOOM")
-        d, err = bench._run_headline_subprocess(60)
-        assert d is None and "rc=1" in err["error"]
-        assert "BOOM" in err["error"]
+    def test_cpu_platform_is_a_failure(self, tmp_path, monkeypatch, capsys):
+        # the suite runs under JAX_PLATFORMS=cpu: the REAL device probe
+        bench = self._bench(tmp_path, monkeypatch)
+        assert bench._device()["platform"] == "cpu"
+        doc = self._fails_with_json(bench, capsys)
+        assert "no accelerator" in doc["error"] and "cpu" in doc["error"]
 
-    def test_unparseable_stdout_names_the_parse_failure(self, tmp_path,
-                                                        monkeypatch):
-        bench = self._bench_with_proc(
-            tmp_path, monkeypatch, 0, '{"value": 9.0, "aup')
-        d, err = bench._run_headline_subprocess(60)
-        assert d is None and "failed to parse" in err["error"]
+    def test_backend_init_failure_is_a_failure(self, tmp_path, monkeypatch,
+                                               capsys):
+        bench = self._bench(tmp_path, monkeypatch)
 
-    def test_timeout_is_classified(self, tmp_path, monkeypatch):
-        bench = self._bench_with_proc(
-            tmp_path, monkeypatch, 0, "", timeout_raises=True)
-        d, err = bench._run_headline_subprocess(60)
-        assert d is None and "cap" in err["error"]
+        def boom():
+            raise RuntimeError("Unable to initialize backend 'tpu'")
+
+        monkeypatch.setattr(bench, "_device", boom)
+        doc = self._fails_with_json(bench, capsys)
+        assert "Unable to initialize backend" in doc["error"]
+
+    def test_no_child_process_and_no_failover(self):
+        import bench
+
+        src = inspect.getsource(bench)
+        assert "subprocess" not in src
+        assert "execv" not in src
+        for gone in ("_ensure_backend", "_backend_failover", "_guarded",
+                     "_is_backend_unavailable", "_run_headline_subprocess",
+                     "backend_fallback"):
+            assert gone not in src, gone

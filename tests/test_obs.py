@@ -492,9 +492,15 @@ class TestLockAudit:
 class TestBenchMeta:
     def test_standard_fields(self):
         meta = obs.bench_meta(wall_s=1.25)
-        for key in ("backend", "rssMb", "at", "pid", "runId", "traceId",
-                    "jax", "wallSecs"):
+        for key in ("backend", "platform", "deviceKind", "deviceCount",
+                    "rssMb", "at", "pid", "runId", "traceId", "jax",
+                    "wallSecs"):
             assert key in meta, key
+        import jax
+
+        assert meta["platform"] == jax.devices()[0].platform
+        assert meta["deviceKind"] == jax.devices()[0].device_kind
+        assert meta["deviceCount"] == len(jax.devices())
         assert meta["traceId"] is None
         assert meta["wallSecs"] == 1.25
         json.dumps(meta)

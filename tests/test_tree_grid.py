@@ -250,6 +250,25 @@ class TestGOSS:
         os.environ["TMOG_GOSS"] = "0"
         assert goss_plan(100_000, 10) is None
 
+    def test_chain_chunk_counts_the_one_hot_per_chain(self):
+        """Under GOSS nothing is shared between chains (each gathers its
+        own rows), so the launch budget must count the bins one-hot per
+        chain: the smoke's XGB group — 6 chains, depth 10, 250k x 500 x 32
+        bins — asked the v5e compiler for 24.85 GB in one launch when it
+        was counted once (PR 21 chip run)."""
+        from transmogrifai_tpu.models.gbdt_kernels import gbt_chain_chunk
+
+        os.environ.pop("TMOG_GOSS", None)
+        for n in (250_000, 1_000_000):
+            shared = gbt_chain_chunk(6, 10, 500, 32, n)
+            goss = gbt_chain_chunk(6, 10, 500, 32, n,
+                                   goss_rows=sum(goss_plan(n, 10)))
+            assert shared == 6
+            assert 1 <= goss <= 2
+        # small problems still launch every chain at once
+        assert gbt_chain_chunk(6, 10, 32, 32, 30_000,
+                               goss_rows=sum(goss_plan(30_000, 10))) == 6
+
     def test_seed_determinism(self):
         os.environ["TMOG_EFB"] = "0"
         os.environ["TMOG_GOSS"] = "1"

@@ -294,10 +294,7 @@ def init_pod_from_env(local_devices: Optional[int] = None) -> PodContext:
     # Selected unconditionally (it only affects the CPU client) and
     # WITHOUT consulting jax.default_backend() — that call would
     # initialize the backend, after which distributed.initialize refuses
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # pragma: no cover - old jax
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coord,
                                num_processes=n, process_id=idx)
     _POD = PodContext(process_index=idx, process_count=n,

@@ -155,16 +155,20 @@ def predict_ensemble(binned: np.ndarray, feat: np.ndarray, thresh: np.ndarray,
             _i32p(binned), n, d, _i32p(feat), _i32p(thresh), _f32p(leaf),
             n_trees, depth, k, _f32p(out), n_threads)
         return out
-    # numpy fallback: vectorized heap walk per tree
+    # numpy fallback: vectorized heap walk per tree; routing mirrors
+    # gbdt_kernels._route_right (a negative threshold is a default-
+    # direction split: effective threshold -t-1, bin 0 routes right)
     out = np.zeros((n, k), np.float32)
     rows = np.arange(n)
     for t in range(n_trees):
         node = np.zeros(n, np.int64)
         for l in range(depth):
             heap = (1 << l) - 1 + node
-            f = feat[t][heap]
+            x = binned[rows, feat[t][heap]]
             th = thresh[t][heap]
-            node = 2 * node + (binned[rows, f] > th)
+            dr = th < 0
+            go_right = (x > np.where(dr, -th - 1, th)) | (dr & (x == 0))
+            node = 2 * node + go_right
         out += leaf[t][node]
     return out
 

@@ -27,28 +27,31 @@ def _rss_mb() -> Optional[float]:
 
 
 def bench_meta(wall_s: Optional[float] = None) -> Dict[str, Any]:
-    """The standard metadata block every bench JSON carries:
-    backend, jax version, peak RSS, pid, unix time, a fresh run id, and
-    the active trace id (None when the run was untraced)."""
+    """The standard metadata block every bench JSON carries: backend and
+    the device as JAX reports it (platform, kind, count — a number without
+    them cannot be told from a CPU run), jax version, peak RSS, pid, unix
+    time, a fresh run id, and the active trace id (None when the run was
+    untraced)."""
+    import jax
+
     from ..utils.profiling import backend_name
     from ..utils.uid import uid_for
     from .trace import current_tracer
 
     tracer = current_tracer()
+    devices = jax.devices()
     meta: Dict[str, Any] = {
         "backend": backend_name(),
+        "platform": devices[0].platform,
+        "deviceKind": devices[0].device_kind,
+        "deviceCount": len(devices),
+        "jax": jax.__version__,
         "rssMb": _rss_mb(),
         "at": int(time.time()),
         "pid": os.getpid(),
         "runId": uid_for("Bench"),
         "traceId": tracer.trace_id if tracer is not None else None,
     }
-    try:
-        import jax
-
-        meta["jax"] = jax.__version__
-    except Exception:  # pragma: no cover - jax must be importable
-        pass
     if wall_s is not None:
         meta["wallSecs"] = round(float(wall_s), 3)
     return meta

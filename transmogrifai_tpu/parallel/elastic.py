@@ -4,13 +4,12 @@ Real TPU fleets are preemptible and resize under you (cf. the TPU
 serving/fine-tuning comparison in PAPERS.md): a chip drops mid-sweep, the
 backend restarts, or a preempted pod comes back smaller.  Before this
 module the pod-scale selector sweep (parallel/mesh.py + selector/
-validators.py) answered every one of those with an aborted train — the
-only recovery was bench.py's whole-process re-exec.  This module holds
-the pieces that turn "restartable" into "finishes anyway":
+validators.py) answered every one of those with an aborted train.
+This module holds the pieces that turn "restartable" into "finishes
+anyway":
 
 * :func:`is_device_loss` / :func:`classify_sweep_error` — the shared
-  classifier for backend/XLA runtime errors, promoted out of bench.py's
-  ``_is_backend_unavailable`` taxonomy so every sweep-unit exception
+  classifier for backend/XLA runtime errors: every sweep-unit exception
   handler routes through ONE list of needles (the TM046 lint pins this:
   a broad ``except Exception`` around sweep-unit execution that does not
   consult the classifier is a static error).
@@ -49,9 +48,8 @@ __all__ = [
 
 #: message fragments that say the accelerator BACKEND is missing/broken —
 #: as opposed to a workload failure (a diverging candidate, a shape
-#: error).  Superset of bench.py's ``_is_backend_unavailable`` needles
-#: (that function now delegates here) plus the runtime device-loss shapes
-#: XLA raises mid-execution and the fault harness's injected form.
+#: error): backend-init failures, the runtime device-loss shapes XLA
+#: raises mid-execution, and the fault harness's injected form.
 DEVICE_LOSS_NEEDLES = (
     "Unable to initialize backend",
     "backend setup/compile error",

@@ -646,7 +646,7 @@ class ModelSelector(PredictorEstimator):
         consume the full matrix — tree candidates then quantize on device
         from it instead of a host binning pass.  Large matrices upload as
         bf16 (see ``trees._dev_f32``; TMOG_MATRIX_PRECISION=f32 forces
-        exact uploads at ~2x the tunnel cost).
+        exact uploads at twice the bytes).
 
         A mesh-sharded ``jax.Array`` (the streaming→sharded ingest
         hand-off, ``parallel.ingest``) is kept device-resident when a

@@ -2,9 +2,10 @@
 from .builder import TestFeatureBuilder
 from .generators import (
     RandomBinary, RandomIntegral, RandomList, RandomMap, RandomPickList,
-    RandomReal, RandomSet, RandomText, RandomVector,
+    RandomReal, RandomSet, RandomText, RandomVector, planted_linear_frame,
 )
 
 __all__ = ["TestFeatureBuilder", "RandomReal", "RandomIntegral",
            "RandomBinary", "RandomText", "RandomPickList", "RandomList",
-           "RandomSet", "RandomMap", "RandomVector"]
+           "RandomSet", "RandomMap", "RandomVector",
+           "planted_linear_frame"]

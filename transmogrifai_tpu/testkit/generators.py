@@ -15,6 +15,7 @@ import numpy as np
 __all__ = [
     "RandomReal", "RandomIntegral", "RandomBinary", "RandomText",
     "RandomPickList", "RandomList", "RandomSet", "RandomMap", "RandomVector",
+    "planted_linear_frame",
 ]
 
 
@@ -148,3 +149,24 @@ class RandomVector(_RandomBase):
 
     def _one(self):
         return self.rng.normal(size=self.dim).astype(np.float32)
+
+
+def planted_linear_frame(rows: int, cols: int, seed: int = 11):
+    """Wide synthetic binary-classification frame (BASELINE config 4's
+    shape): ``cols`` standard-normal Real columns ``f0..f{cols-1}`` and a
+    ``label`` drawn from a logistic model over a planted sparse linear
+    signal (``max(3, cols // 20)`` informative columns, N(0, 1.5) weights,
+    0.5-sigma logit noise).  Deterministic in ``(rows, cols, seed)`` — the
+    generator the scale benches and ``chip_smoke.py`` share."""
+    import pandas as pd
+
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(rows, cols)).astype(np.float32)
+    beta = np.zeros(cols, np.float32)
+    informative = rng.choice(cols, max(3, cols // 20), replace=False)
+    beta[informative] = rng.normal(size=len(informative)) * 1.5
+    z = X @ beta + 0.5 * rng.normal(size=rows).astype(np.float32)
+    y = (1 / (1 + np.exp(-z)) > rng.random(rows)).astype(np.float32)
+    df = pd.DataFrame(X, columns=[f"f{j}" for j in range(cols)])
+    df.insert(0, "label", y)
+    return df
