@@ -1,0 +1,278 @@
+"""A later PR adds a cell, a configuration, a traffic mix, a per-layer
+metric, a generator and a mode as NEW files plus BENCHMARK.json entries,
+and edits no file that is there.  Shown here in a temporary copy of the
+benchmark: one of each is added, the new cell runs, and every file the
+copy started with is byte-for-byte what it was.
+"""
+import hashlib
+import json
+import os
+import shutil
+import sys
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+from _child import RESULT_KEYS, ROOT, TINY, run_cell  # noqa: E402
+
+NEW_MODE = '''"""Mode ``matmul_loop``: a stand-in for a later PR's mode (say an
+open-loop server): it never trains, it runs one jitted product per step."""
+import time
+
+
+def run(ctx):
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.asarray(ctx.df.drop(columns=["label"]).to_numpy())
+    step = jax.jit(lambda a: (a.T @ a).sum())
+    step(x).block_until_ready()
+    ctx.setup_done()
+    t0, walls = time.perf_counter(), []
+    while time.perf_counter() - t0 < ctx.args.seconds:
+        t = time.perf_counter()
+        step(x).block_until_ready()
+        walls.append(time.perf_counter() - t)
+    return {"correct": True, "problems": [], "attempted": len(walls),
+            "failed": 0,
+            "end_to_end": {"train_s": sorted(walls)[len(walls) // 2],
+                           "holdout_aupr": 1.0},
+            "sources": {"steps": len(walls), "trace": None}}
+'''
+
+NEW_GENERATOR = '''"""Generator ``uniform_frame``: uniform columns, a coin for a label."""
+import numpy as np
+
+
+def generate(rows, cols, seed, low=0.0):
+    import pandas as pd
+
+    rng = np.random.default_rng(seed)
+    df = pd.DataFrame(rng.uniform(low, 1.0, (rows, cols)).astype("float32"),
+                      columns=[f"f{j}" for j in range(cols)])
+    df.insert(0, "label", (rng.random(rows) < 0.5).astype("float32"))
+    return df, np.zeros(cols, np.float32)
+'''
+
+NEW_METRIC = '''"""Steps the window completed (a later PR's counter)."""
+LAYER = "front end"
+UNIT = "count"
+MOVES = "train_s"
+
+
+def read(sources):
+    return sources.get("steps")
+'''
+
+
+def _hashes(root):
+    out = {}
+    for base, dirs, files in os.walk(root):
+        dirs[:] = [d for d in dirs if d not in ("__pycache__", ".jax_cache",
+                                                "chiprun_out")]
+        for f in files:
+            path = os.path.join(base, f)
+            if os.path.islink(path):
+                continue
+            with open(path, "rb") as fh:
+                out[os.path.relpath(path, root)] = hashlib.sha256(
+                    fh.read()).hexdigest()
+    return out
+
+
+def _copy_of_the_benchmark(tmp_path):
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(os.path.join(ROOT, "perfbench"), tmp_path / "perfbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    # the program beside the copy, as in a checkout
+    os.symlink(os.path.join(ROOT, "transmogrifai_tpu"),
+               tmp_path / "transmogrifai_tpu")
+
+
+LATER = os.path.join(ROOT, "tests", "perfbench", "later")
+
+
+def _enter(tmp_path, bench_edit, files: dict):
+    """Add ``files`` (``{path under the copy: text}``) and the entries
+    ``bench_edit`` makes to the copy; returns the hashes from before."""
+    _copy_of_the_benchmark(tmp_path)
+    before = _hashes(tmp_path)
+    for rel, text in files.items():
+        assert rel not in before, f"{rel} would edit a file that is there"
+        (tmp_path / rel).write_text(text)
+    with open(tmp_path / "BENCHMARK.json") as f:
+        bench = json.load(f)
+    bench_edit(bench)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    return before
+
+
+def _list_cell(bench, cell, *metrics):
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in metrics:
+            m["workloads"].append(cell)
+
+
+def _later(name):
+    with open(os.path.join(LATER, name)) as f:
+        return f.read()
+
+
+@pytest.mark.parametrize("cell,traffic", [
+    ("dense500-rf", "rf-pairs"), ("dense500-linear", "lr-grid-full-train")])
+def test_a_later_cell_is_a_data_file_and_an_entry(cell, traffic, tmp_path):
+    """ISSUE 22's ``dense500-rf`` and ``dense500-linear`` wait under
+    PERF.md's Open questions; their mixes are kept in ``later/``.  The PR
+    that enters one adds the data file (for the LR cell its reader too) and
+    entries, and the cell's path (RF grid chunks; vectorizer and
+    SanityChecker fit with the LR grid, ``full_train``) runs end to end."""
+    files = {f"perfbench/traffic/{traffic}.json": _later(traffic + ".json")}
+    if cell == "dense500-linear":
+        files["perfbench/metrics/linear_device_s.py"] = _later(
+            "linear_device_s.py")
+
+    def edit(bench):
+        bench["workloads"].append({
+            "name": cell, "config": "dense500-binary", "traffic": traffic,
+            "chips": 1, "why": "entered by a later PR"})
+        # a metric that exists only in some cells lists them: the new cell
+        # puts its name on the lists of the metrics it reports
+        _list_cell(bench, cell, "train_s", "selector_s", "drain_s")
+        if cell == "dense500-linear":
+            bench["per_layer"].append({
+                "name": "linear_device_s", "unit": "s", "better": "lower",
+                "source": "device_trace", "layer": "linear solver",
+                "moves": "train_s", "workloads": [cell]})
+
+    before = _enter(tmp_path, edit, files)
+    out, last = run_cell(cell, "--allow-cpu", *TINY, root=str(tmp_path),
+                         cache_dir=tmp_path / "jax_cache")
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert last["correct"] is True and last["failed"] == 0
+    assert set(last["metrics"]) == {"train_s", "holdout_aupr", "setup_s"}
+    if traffic == "rf-pairs":
+        assert "rf_grid_chunk" in out.stdout
+    else:
+        assert '"RealVectorizer:fit"' in out.stdout
+        assert '"SanityChecker:fit"' in out.stdout
+        sys.path.insert(0, ROOT)
+        from perfbench import spec
+
+        names = [m["name"] for m in spec.load_cell(
+            cell, root=str(tmp_path))["per_layer"]]
+        assert "linear_device_s" in names
+    after = _hashes(tmp_path)
+    assert [k for k in before if after.get(k) != before[k]] == [
+        "BENCHMARK.json"]
+
+
+COLD_MODE = '''"""Mode ``train_loop_cold``: ``train_loop`` with JAX's in-memory
+caches dropped where set-up ends, so that the first train of the window has
+to build its programs again (from the persistent cache or the compiler): a
+stand-in for a program change that re-jits in every train."""
+from perfbench.modes import train_loop
+
+
+def run(ctx):
+    warm_setup_done = ctx.setup_done
+
+    def setup_done():
+        import jax
+
+        jax.clear_caches()
+        warm_setup_done()
+
+    ctx.setup_done = setup_done
+    return train_loop.run(ctx)
+'''
+
+
+def test_one_chip_cell_on_the_mesh_cell_s_mix_fails_on_a_program_in_the_window(
+        tmp_path):
+    """``mesh4-trees`` may build programs inside its window (its
+    configuration says why and how many).  The allowance is the mesh
+    configuration's: a one-chip cell on the very same mix is held to zero."""
+    with open(os.path.join(ROOT, "perfbench", "traffic",
+                           "tree-groups.json")) as f:
+        mix = json.load(f)
+    mix["mode"] = "train_loop_cold"
+
+    def edit(bench):
+        bench["workloads"].append({
+            "name": "dense500-trees-cold", "config": "dense500-binary",
+            "traffic": "tree-groups-cold", "chips": 1,
+            "why": "the mesh cell's mix on one chip, re-jitting"})
+
+    _enter(tmp_path, edit, {
+        "perfbench/modes/train_loop_cold.py": COLD_MODE,
+        "perfbench/traffic/tree-groups-cold.json": json.dumps(mix)})
+    out, last = run_cell("dense500-trees-cold", "--allow-cpu", *TINY,
+                         root=str(tmp_path), cache_dir=tmp_path / "jax_cache")
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert last["correct"] is False and last["failed"] == 0
+    assert "programs compiled or loaded inside the window, 0 allowed" in (
+        out.stdout)
+
+
+def test_new_files_and_entries_are_enough(tmp_path):
+    _copy_of_the_benchmark(tmp_path)
+    before = _hashes(tmp_path)
+
+    pb = tmp_path / "perfbench"
+    (pb / "modes" / "matmul_loop.py").write_text(NEW_MODE)
+    (pb / "generators" / "uniform_frame.py").write_text(NEW_GENERATOR)
+    (pb / "metrics" / "steps_done.py").write_text(NEW_METRIC)
+    with open(pb / "configs" / "dense500-binary.json") as f:
+        cfg = json.load(f)
+    cfg.update(name="uniform64", source="a later PR's own source",
+               rows=2000, holdout_rows=100,
+               generator={"name": "uniform_frame", "params": {"low": 0.5}})
+    cfg["schema"]["predictors"]["count"] = 64
+    (pb / "configs" / "uniform64.json").write_text(json.dumps(cfg))
+    (pb / "traffic" / "matmul-steady.json").write_text(json.dumps(
+        {"mode": "matmul_loop", "why": "a new mix is a data file"}))
+    with open(tmp_path / "BENCHMARK.json") as f:
+        bench = json.load(f)
+    bench["configs"].append({
+        "name": "uniform64", "source": cfg["source"],
+        "file": "perfbench/configs/uniform64.json", "reduced": [],
+        "why": "shows a configuration is a file"})
+    bench["workloads"].append({
+        "name": "uniform64-matmul", "config": "uniform64",
+        "traffic": "matmul-steady", "chips": 1,
+        "why": "shows a cell is an entry"})
+    bench["per_layer"].append({
+        "name": "steps_done", "unit": "count", "better": "higher",
+        "source": "program_counter", "layer": "front end",
+        "moves": "train_s", "workloads": ["uniform64-matmul"]})
+    _list_cell(bench, "uniform64-matmul", "train_s")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    cache = tmp_path / "jax_cache"
+    out, last = run_cell("uniform64-matmul", "--allow-cpu", seconds="1",
+                         root=str(tmp_path), cache_dir=cache)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert RESULT_KEYS <= set(last) and last["correct"] is True
+    assert last["attempted"] > 0
+    assert set(last["metrics"]) == {"train_s", "holdout_aupr", "setup_s"}
+    assert "rows=2000 cols=64" in out.stdout
+
+    after = _hashes(tmp_path)
+    changed = [k for k in before if after.get(k) != before[k]]
+    assert changed == ["BENCHMARK.json"]  # entries added, no file edited
+    added = sorted(set(after) - set(before))
+    assert added == ["perfbench/configs/uniform64.json",
+                     "perfbench/generators/uniform_frame.py",
+                     "perfbench/metrics/steps_done.py",
+                     "perfbench/modes/matmul_loop.py",
+                     "perfbench/traffic/matmul-steady.json"]
+
+    # and the new per-layer metric is found by its file name
+    sys.path.insert(0, ROOT)
+    from perfbench import spec
+
+    loaded = spec.load_cell("uniform64-matmul", root=str(tmp_path))
+    names = [m["name"] for m in loaded["per_layer"]]
+    assert "steps_done" in names and "collective_s" not in names
+    reader = spec.load_module("metrics", "steps_done", root=str(tmp_path))
+    assert reader.read({"steps": 7}) == 7
