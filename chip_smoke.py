@@ -98,7 +98,16 @@ def say(leg: str, **fields) -> None:
 class CompileMeter:
     """Sums JAX's own trace/lower/backend-compile durations and the
     persistent-cache hit/miss events, so compile seconds print apart from
-    the train wall without a second process."""
+    the train wall without a second process.
+
+    ``programs`` and ``cache_hits`` are NOT disjoint: JAX raises its
+    backend-compile event round the cache lookup and the compile alike, so
+    a program loaded from the persistent cache counts as a program too
+    (with the load's duration).  ``programs`` is every program built,
+    ``cache_hits`` those of them that were loaded and not compiled,
+    ``cache_misses`` those compiled and written to the cache: never add
+    ``cache_hits`` to ``programs`` (``tests/perfbench`` pins the event on
+    the benchmark's copy of this class)."""
 
     _DUR = ("/jax/core/compile/jaxpr_trace_duration",
             "/jax/core/compile/jaxpr_to_mlir_module_duration",

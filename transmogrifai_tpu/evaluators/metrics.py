@@ -128,24 +128,28 @@ def aupr(y_true, y_score, sample_weight=None):
 
 @jax.jit
 def _aupr_dev(y_true, y_score, sample_weight=None) -> jnp.ndarray:
-    y, w = _weights(y_true, sample_weight)
-    s = jnp.asarray(y_score, jnp.float32)
-    n = s.shape[0]
-    order = jnp.argsort(-s)
-    s_sorted = s[order]
-    wy = (w * y)[order]
-    ww = w[order]
-    # evaluate precision/recall only at distinct-threshold boundaries
-    is_new = jnp.concatenate([jnp.ones(1, bool), s_sorted[1:] != s_sorted[:-1]])
-    gid = jnp.cumsum(is_new) - 1
-    pos_g = jax.ops.segment_sum(wy, gid, num_segments=n)
-    tot_g = jax.ops.segment_sum(ww, gid, num_segments=n)
-    tp = jnp.cumsum(pos_g)
-    all_pred = jnp.cumsum(tot_g)
-    pos = jnp.maximum(jnp.sum(wy), 1e-12)
-    precision = tp / jnp.maximum(all_pred, 1e-12)
-    dr = pos_g / pos
-    return jnp.clip(jnp.sum(dr * precision), 0.0, 1.0)
+    # (the scope is inside the jitted body: a jit traces with a fresh name
+    # stack, so a scope round ``binary_metric_grid`` would not reach the ops)
+    with jax.named_scope("metric.grid"):
+        y, w = _weights(y_true, sample_weight)
+        s = jnp.asarray(y_score, jnp.float32)
+        n = s.shape[0]
+        order = jnp.argsort(-s)
+        s_sorted = s[order]
+        wy = (w * y)[order]
+        ww = w[order]
+        # evaluate precision/recall only at distinct-threshold boundaries
+        is_new = jnp.concatenate(
+            [jnp.ones(1, bool), s_sorted[1:] != s_sorted[:-1]])
+        gid = jnp.cumsum(is_new) - 1
+        pos_g = jax.ops.segment_sum(wy, gid, num_segments=n)
+        tot_g = jax.ops.segment_sum(ww, gid, num_segments=n)
+        tp = jnp.cumsum(pos_g)
+        all_pred = jnp.cumsum(tot_g)
+        pos = jnp.maximum(jnp.sum(wy), 1e-12)
+        precision = tp / jnp.maximum(all_pred, 1e-12)
+        dr = pos_g / pos
+        return jnp.clip(jnp.sum(dr * precision), 0.0, 1.0)
 
 
 def binary_metric_grid(y_true, scores, weights, metric: str):

@@ -565,14 +565,15 @@ def _goss_select(ga, key, k_top: int, k_rest: int):
     amplified by (N - k_top)/k_rest — the standard unbiasedness weights.
     Returns (row indices (k_top+k_rest,), per-row multipliers);
     deterministic in ``key``."""
-    _, top_idx = lax.top_k(ga, k_top)
-    r = jax.random.uniform(key, ga.shape)
-    r = r.at[top_idx].set(-1.0)             # exclude kept rows
-    _, rest_idx = lax.top_k(r, k_rest)
-    idx = jnp.concatenate([top_idx, rest_idx])
-    amp = (ga.shape[0] - k_top) / k_rest
-    mult = jnp.concatenate([jnp.ones(k_top, jnp.float32),
-                            jnp.full(k_rest, amp, jnp.float32)])
+    with jax.named_scope("goss.select"):
+        _, top_idx = lax.top_k(ga, k_top)
+        r = jax.random.uniform(key, ga.shape)
+        r = r.at[top_idx].set(-1.0)             # exclude kept rows
+        _, rest_idx = lax.top_k(r, k_rest)
+        idx = jnp.concatenate([top_idx, rest_idx])
+        amp = (ga.shape[0] - k_top) / k_rest
+        mult = jnp.concatenate([jnp.ones(k_top, jnp.float32),
+                                jnp.full(k_rest, amp, jnp.float32)])
     return idx, mult
 
 
@@ -1027,292 +1028,305 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
     prev_cums = None   # previous level's per-channel bin cumsums (M, B, d)
 
     for level in range(max_depth):
-        level_nodes = 2 ** level
-        compact = level_nodes > n_cap
-        M = n_cap if compact else level_nodes        # static slot count
+        with jax.named_scope("tree.hist"):
+            level_nodes = 2 ** level
+            compact = level_nodes > n_cap
+            M = n_cap if compact else level_nodes        # static slot count
 
-        # Sibling subtraction: at wide non-compact levels build histograms
-        # for LEFT children only (slot 2j -> column j; right-child rows
-        # contribute zero) and derive the right child's cumsums from the
-        # retained parent cumsums (right = parent − left) — halves the
-        # (rows, M) node one-hot stream and the histogram dots exactly
-        # where M makes them dominant.  Non-compact level l implies
-        # non-compact l−1, so the parent cumsums are always full-layout.
-        # Integer-channel bag modes only (RF one-hot/bagged): the bagged
-        # channels are integer-valued so parent − left is exact, while
-        # continuous GBT gradient/hessian channels suffer cancellation —
-        # tiny negative hessian residuals could flip min_child_weight /
-        # min_instances gating vs the direct build (ADVICE r3).
-        sib = (level >= 1 and not compact and M >= SIBLING_MIN_SLOTS
-               and prev_cums is not None
-               and bag_mode in ("onehot", "bagged"))
-        Mh = M // 2 if sib else M
+            # Sibling subtraction: at wide non-compact levels build histograms
+            # for LEFT children only (slot 2j -> column j; right-child rows
+            # contribute zero) and derive the right child's cumsums from the
+            # retained parent cumsums (right = parent − left) — halves the
+            # (rows, M) node one-hot stream and the histogram dots exactly
+            # where M makes them dominant.  Non-compact level l implies
+            # non-compact l−1, so the parent cumsums are always full-layout.
+            # Integer-channel bag modes only (RF one-hot/bagged): the bagged
+            # channels are integer-valued so parent − left is exact, while
+            # continuous GBT gradient/hessian channels suffer cancellation —
+            # tiny negative hessian residuals could flip min_child_weight /
+            # min_instances gating vs the direct build (ADVICE r3).
+            sib = (level >= 1 and not compact and M >= SIBLING_MIN_SLOTS
+                   and prev_cums is not None
+                   and bag_mode in ("onehot", "bagged"))
+            Mh = M // 2 if sib else M
 
-        if compact:
-            # rows occupy ≤ N distinct nodes: rank their sorted ids
-            sorted_ids = jnp.sort(node)
-            first = jnp.concatenate(
-                [jnp.ones(1, bool), sorted_ids[1:] != sorted_ids[:-1]])
-            uniq = jnp.sort(jnp.where(first, sorted_ids, jnp.int32(2**31 - 1)))
-            # (M,) padded with INT32_MAX (n ≤ M = next_pow2(n) by construction)
-            uniq = jnp.full(M, jnp.int32(2**31 - 1)).at[:n].set(uniq)
-            # compare_all: the default 'scan' method lowers to a sequential
-            # log(M) loop — poor fit for the TPU's wide vector units
-            slot = jnp.searchsorted(uniq, node,
-                                    method="compare_all").astype(jnp.int32)
-        else:
-            uniq = jnp.arange(M, dtype=jnp.int32)
-            slot = node
-
-        def node_onehot(slot_v, rows: int):
-            """(rows, Mh) one-hot — full slots, or left children only."""
-            if sib:
-                oh = (((slot_v // 2)[:, None] == jnp.arange(Mh)[None, :])
-                      & (slot_v % 2 == 0)[:, None])
-            else:
-                oh = slot_v[:, None] == jnp.arange(Mh)[None, :]
-            return oh.astype(hdt)
-
-        if seg and not sib and Mh <= SEG_MAX_SLOTS:
-            hists = _seg_level_hists(binned_seg, slot, chans, Mh, B, d)
-        elif csr is not None and not sib and Mh <= SPARSE_MAX_SLOTS:
-            hists = _sparse_level_hists(csr[0], csr[1], csr[2], slot,
-                                        chans, Mh, B, hdt, dot_prec)
-        elif blocked:
-            slot_blk = jnp.pad(slot, (0, n_pad - n)).reshape(
-                n_blocks, ROW_BLOCK)
-
-            def hist_block(acc, xs):
-                slot_b, binned_b, ch_b = xs
-                oh_bins = bins_onehot(binned_b)            # (RB, B·d)
-                oh_node = node_onehot(slot_b, ROW_BLOCK)   # (RB, Mh)
-                ch_h = ch_b.astype(hdt)
-                # all channels in ONE dot: separate per-channel dots re-read
-                # the (RB, B·D) bins one-hot — the stream that IS the
-                # kernel's bandwidth floor — nchan times from HBM
-                wnode = jnp.concatenate(
-                    [oh_node * ch_h[:, c][:, None] for c in range(nchan)],
-                    axis=1)                            # (RB, nchan·Mh)
-                part = jax.lax.dot(wnode.T, oh_bins,
-                                   precision=dot_prec,
-                                   preferred_element_type=adt)
-                return acc + part.reshape(nchan, Mh, B * d), None
-
-            acc0 = jnp.zeros((nchan, Mh, B * d), adt)
-            hist_stack, _ = lax.scan(
-                hist_block, acc0, (slot_blk, binned_blk, chans_blk))
-            hists = [hist_stack[c].reshape(Mh, B, d) for c in range(nchan)]
-        else:
-            onehot_node = node_onehot(slot, n)            # (N, Mh)
-            wnode = jnp.concatenate(
-                [onehot_node * ch.astype(hdt)[:, None] for ch in chans],
-                axis=1)                               # (N, nchan·Mh)
-            hist_all = jax.lax.dot(
-                wnode.T, onehot_bins, precision=dot_prec,
-                preferred_element_type=adt)           # (nchan·Mh, B·D)
-            hists = [hist_all[c * Mh:(c + 1) * Mh].reshape(Mh, B, d)
-                     for c in range(nchan)]           # 2K+1 × (Mh, B, D)
-        if acc_bf16:
-            # upcast once per level: gain search / gating stay f32
-            hists = [h.astype(jnp.float32) for h in hists]
-        if all_reduce is not None:
-            # ICI collective replaces Spark's treeAggregate / Rabit allreduce
-            # (channel reduction also means fewer collectives per level)
-            hists = [all_reduce(h) for h in hists]
-        cums_h = [jnp.cumsum(h, axis=1) for h in hists]
-        if sib:
-            # interleave left cumsums with (parent − left) right cumsums
-            cums = [jnp.stack([lc, pc - lc], axis=1).reshape(M, B, d)
-                    for lc, pc in zip(cums_h, prev_cums)]
-        else:
-            cums = cums_h
-        # retain for the next level only when it will engage the sibling path
-        prev_cums = cums if (level + 1 < max_depth
-                             and 2 * level_nodes <= n_cap
-                             and 2 * M >= SIBLING_MIN_SLOTS) else None
-        if bag_mode == "onehot":
-            CL = cums[-1]
-            GLs = list(cums[: k - 1])
-            GLs.append(CL - sum(GLs) if GLs else CL)
-            HLs = [CL] * k
-        elif bag_mode == "bagged":
-            CL = cums[-1]
-            GLs = list(cums[:k])
-            HLs = [CL] * k
-        elif bag_mode == "newton":
-            GLs = list(cums[:k])
-            HLs = list(cums[k:2 * k])
-            CL = HLs[0]   # hessian mass stands in; gating inert (min_inst 0)
-        else:
-            CL = cums[-1]
-            GLs = list(cums[:k])
-            HLs = list(cums[k:2 * k])
-
-        if level in leaf_levels:
-            # depth-``level`` truncation leaves: per-node value sums are the
-            # histograms' full-bin totals (feature 0's column — every row of
-            # a node lands in exactly one bin of any feature), so the
-            # snapshot costs no extra data pass
-            Gs_n = jnp.stack([GL[:, -1, 0] for GL in GLs], axis=1)  # (M, K)
-            Hs_n = jnp.stack([HL[:, -1, 0] for HL in HLs], axis=1)
-            Cs_n = cums[-1][:, -1, 0]                               # (M,)
-            snap = jnp.where(newton_leaf,
-                             -learning_rate * Gs_n / (Hs_n + lam),
-                             Gs_n / jnp.maximum(Cs_n, 1e-12)[:, None])
             if compact:
-                snap = jnp.zeros((level_nodes, k), jnp.float32).at[uniq].set(
-                    snap, mode="drop")
-            leaf_snaps.append(snap)
+                # rows occupy ≤ N distinct nodes: rank their sorted ids
+                sorted_ids = jnp.sort(node)
+                first = jnp.concatenate(
+                    [jnp.ones(1, bool), sorted_ids[1:] != sorted_ids[:-1]])
+                uniq = jnp.sort(jnp.where(first, sorted_ids,
+                                          jnp.int32(2**31 - 1)))
+                # (M,) padded with INT32_MAX (n ≤ M = next_pow2(n) by
+                # construction)
+                uniq = jnp.full(M, jnp.int32(2**31 - 1)).at[:n].set(uniq)
+                # compare_all: the default 'scan' method lowers to a sequential
+                # log(M) loop — poor fit for the TPU's wide vector units
+                slot = jnp.searchsorted(uniq, node,
+                                        method="compare_all").astype(jnp.int32)
+            else:
+                uniq = jnp.arange(M, dtype=jnp.int32)
+                slot = node
 
-        gain = 0.0
-        HLmin = jnp.inf
-        HRmin = jnp.inf
-        if bundle_end is not None:
-            # EFB interval splits: right = bins in (t, E(t)] — the owner
-            # member's remaining bins; left = everything else (other
-            # members + the shared default bin).  Unbundled columns carry
-            # E = B-1, collapsing to the standard form bit-for-bit.
-            # Entries with E = B-1 (unbundled columns, and a bundle's
-            # LAST member) compute the STANDARD arithmetic (Gtot - GL)
-            # rather than GL[E] - GL: the two agree exactly in real
-            # arithmetic but differ by f32 cumsum rounding, and that
-            # last-ulp noise would break gain-PLATEAU ties (thresholds
-            # spanning empty bins) differently from the unbundled
-            # program — the bit-for-tree contract hinges on it.
-            Eb = jnp.broadcast_to(bundle_end[None], (M, B, d))
-            is_std = Eb == (B - 1)
+            def node_onehot(slot_v, rows: int):
+                """(rows, Mh) one-hot — full slots, or left children only."""
+                if sib:
+                    oh = (((slot_v // 2)[:, None] == jnp.arange(Mh)[None, :])
+                          & (slot_v % 2 == 0)[:, None])
+                else:
+                    oh = slot_v[:, None] == jnp.arange(Mh)[None, :]
+                return oh.astype(hdt)
 
-            def right_interval(A):
-                return jnp.take_along_axis(A, Eb, axis=1) - A
+            if seg and not sib and Mh <= SEG_MAX_SLOTS:
+                hists = _seg_level_hists(binned_seg, slot, chans, Mh, B, d)
+            elif csr is not None and not sib and Mh <= SPARSE_MAX_SLOTS:
+                hists = _sparse_level_hists(csr[0], csr[1], csr[2], slot,
+                                            chans, Mh, B, hdt, dot_prec)
+            elif blocked:
+                slot_blk = jnp.pad(slot, (0, n_pad - n)).reshape(
+                    n_blocks, ROW_BLOCK)
 
-            for GL, HL in zip(GLs, HLs):
-                Gtot = GL[:, -1:, :1]
-                Htot = HL[:, -1:, :1]
-                GR = jnp.where(is_std, Gtot - GL, right_interval(GL))
-                HR = jnp.where(is_std, Htot - HL, right_interval(HL))
-                GLft = jnp.where(is_std, GL, Gtot - GR)
-                HLft = jnp.where(is_std, HL, Htot - HR)
-                gain = gain + (GLft ** 2 / (HLft + lam)
-                               + GR ** 2 / (HR + lam)
-                               - Gtot ** 2 / (Htot + lam))
-                HLmin = jnp.minimum(HLmin, HLft)
-                HRmin = jnp.minimum(HRmin, HR)
-            Ctot = CL[:, -1:, :1]
-            CR = jnp.where(is_std, Ctot - CL, right_interval(CL))
-            CLft = jnp.where(is_std, CL, Ctot - CR)
-        else:
-            for GL, HL in zip(GLs, HLs):
-                Gtot = GL[:, -1:, :1]
-                Htot = HL[:, -1:, :1]
-                GR, HR = Gtot - GL, Htot - HL
-                gain = gain + (GL ** 2 / (HL + lam) + GR ** 2 / (HR + lam)
-                               - Gtot ** 2 / (Htot + lam))
-                HLmin = jnp.minimum(HLmin, HL)
-                HRmin = jnp.minimum(HRmin, HR)
-            Ctot = CL[:, -1:, :1]
-            CR = Ctot - CL
-            CLft = CL
+                def hist_block(acc, xs):
+                    slot_b, binned_b, ch_b = xs
+                    oh_bins = bins_onehot(binned_b)            # (RB, B·d)
+                    oh_node = node_onehot(slot_b, ROW_BLOCK)   # (RB, Mh)
+                    ch_h = ch_b.astype(hdt)
+                    # all channels in ONE dot: separate per-channel dots
+                    # re-read the (RB, B·D) bins one-hot — the stream that
+                    # IS the kernel's bandwidth floor — nchan times from HBM
+                    wnode = jnp.concatenate(
+                        [oh_node * ch_h[:, c][:, None] for c in range(nchan)],
+                        axis=1)                            # (RB, nchan·Mh)
+                    part = jax.lax.dot(wnode.T, oh_bins,
+                                       precision=dot_prec,
+                                       preferred_element_type=adt)
+                    return acc + part.reshape(nchan, Mh, B * d), None
 
-        valid = ((HLmin >= min_child_weight) & (HRmin >= min_child_weight)
-                 & (CLft >= min_instances) & (CR >= min_instances)
-                 & (jnp.arange(B)[None, :, None] < B - 1)
-                 & feat_mask[None, None, :])
-        node_w = jnp.maximum(Ctot[:, 0, 0], 1e-12)
-        gain = jnp.where(valid, gain, -jnp.inf)      # (M, B, D)
-        flat_gain = gain.reshape(M, B * d)
+                acc0 = jnp.zeros((nchan, Mh, B * d), adt)
+                hist_stack, _ = lax.scan(
+                    hist_block, acc0, (slot_blk, binned_blk, chans_blk))
+                hists = [hist_stack[c].reshape(Mh, B, d) for c in range(nchan)]
+            else:
+                onehot_node = node_onehot(slot, n)            # (N, Mh)
+                wnode = jnp.concatenate(
+                    [onehot_node * ch.astype(hdt)[:, None] for ch in chans],
+                    axis=1)                               # (N, nchan·Mh)
+                hist_all = jax.lax.dot(
+                    wnode.T, onehot_bins, precision=dot_prec,
+                    preferred_element_type=adt)           # (nchan·Mh, B·D)
+                hists = [hist_all[c * Mh:(c + 1) * Mh].reshape(Mh, B, d)
+                         for c in range(nchan)]           # 2K+1 × (Mh, B, D)
+            if acc_bf16:
+                # upcast once per level: gain search / gating stay f32
+                hists = [h.astype(jnp.float32) for h in hists]
+            if all_reduce is not None:
+                # ICI collective replaces Spark's treeAggregate / Rabit
+                # allreduce
+                # (channel reduction also means fewer collectives per level)
+                hists = [all_reduce(h) for h in hists]
+        with jax.named_scope("tree.split"):
+            cums_h = [jnp.cumsum(h, axis=1) for h in hists]
+            if sib:
+                # interleave left cumsums with (parent − left) right cumsums
+                cums = [jnp.stack([lc, pc - lc], axis=1).reshape(M, B, d)
+                        for lc, pc in zip(cums_h, prev_cums)]
+            else:
+                cums = cums_h
+            # retain for the next level only when it will engage the
+            # sibling path
+            prev_cums = cums if (level + 1 < max_depth
+                                 and 2 * level_nodes <= n_cap
+                                 and 2 * M >= SIBLING_MIN_SLOTS) else None
+            if bag_mode == "onehot":
+                CL = cums[-1]
+                GLs = list(cums[: k - 1])
+                GLs.append(CL - sum(GLs) if GLs else CL)
+                HLs = [CL] * k
+            elif bag_mode == "bagged":
+                CL = cums[-1]
+                GLs = list(cums[:k])
+                HLs = [CL] * k
+            elif bag_mode == "newton":
+                GLs = list(cums[:k])
+                HLs = list(cums[k:2 * k])
+                # hessian mass stands in; gating inert (min_inst 0)
+                CL = HLs[0]
+            else:
+                CL = cums[-1]
+                GLs = list(cums[:k])
+                HLs = list(cums[k:2 * k])
 
-        if default_dir:
-            # XGBoost default-direction (missing/sparse) splits: variant b
-            # routes the bin-0 (missing/absent) mass RIGHT — its cumsums
-            # are the plain ones minus the bin-0 row — a per-(node, t,
-            # feature) 2-way gain compare, exactly the C++ core's
-            # enumerate-both-directions loop (OpXGBoostClassifier.scala:47
-            # wraps those semantics).  Encoded as a NEGATIVE threshold
-            # -(t+1) so heap shapes/persistence are unchanged.  ``dd_mask``
-            # (from the caller's bin edges) limits variant b to features
-            # whose bin 0 IS a genuine missing/zero bucket (first edge
-            # pinned at 0.0 by the sparse-aware sketch): on a dense
-            # feature, bin 0 is just the lowest quantile, and routing it
-            # with the high side would fabricate non-contiguous splits real
-            # XGBoost cannot produce (code-review r5).
-            gain_b = 0.0
-            HLbmin = jnp.inf
-            HRbmin = jnp.inf
-            for GL, HL in zip(GLs, HLs):
-                Gtot = GL[:, -1:, :1]
-                Htot = HL[:, -1:, :1]
-                GLb, HLb = GL - GL[:, 0:1, :], HL - HL[:, 0:1, :]
-                GRb, HRb = Gtot - GLb, Htot - HLb
-                gain_b = gain_b + (GLb ** 2 / (HLb + lam)
-                                   + GRb ** 2 / (HRb + lam)
+            if level in leaf_levels:
+                # depth-``level`` truncation leaves: per-node value sums are
+                # the histograms' full-bin totals (feature 0's column — every
+                # row of
+                # a node lands in exactly one bin of any feature), so the
+                # snapshot costs no extra data pass
+                # (M, K)
+                Gs_n = jnp.stack([GL[:, -1, 0] for GL in GLs], axis=1)
+                Hs_n = jnp.stack([HL[:, -1, 0] for HL in HLs], axis=1)
+                Cs_n = cums[-1][:, -1, 0]                               # (M,)
+                snap = jnp.where(newton_leaf,
+                                 -learning_rate * Gs_n / (Hs_n + lam),
+                                 Gs_n / jnp.maximum(Cs_n, 1e-12)[:, None])
+                if compact:
+                    snap = jnp.zeros((level_nodes, k), jnp.float32
+                                     ).at[uniq].set(snap, mode="drop")
+                leaf_snaps.append(snap)
+
+            gain = 0.0
+            HLmin = jnp.inf
+            HRmin = jnp.inf
+            if bundle_end is not None:
+                # EFB interval splits: right = bins in (t, E(t)] — the owner
+                # member's remaining bins; left = everything else (other
+                # members + the shared default bin).  Unbundled columns carry
+                # E = B-1, collapsing to the standard form bit-for-bit.
+                # Entries with E = B-1 (unbundled columns, and a bundle's
+                # LAST member) compute the STANDARD arithmetic (Gtot - GL)
+                # rather than GL[E] - GL: the two agree exactly in real
+                # arithmetic but differ by f32 cumsum rounding, and that
+                # last-ulp noise would break gain-PLATEAU ties (thresholds
+                # spanning empty bins) differently from the unbundled
+                # program — the bit-for-tree contract hinges on it.
+                Eb = jnp.broadcast_to(bundle_end[None], (M, B, d))
+                is_std = Eb == (B - 1)
+
+                def right_interval(A):
+                    return jnp.take_along_axis(A, Eb, axis=1) - A
+
+                for GL, HL in zip(GLs, HLs):
+                    Gtot = GL[:, -1:, :1]
+                    Htot = HL[:, -1:, :1]
+                    GR = jnp.where(is_std, Gtot - GL, right_interval(GL))
+                    HR = jnp.where(is_std, Htot - HL, right_interval(HL))
+                    GLft = jnp.where(is_std, GL, Gtot - GR)
+                    HLft = jnp.where(is_std, HL, Htot - HR)
+                    gain = gain + (GLft ** 2 / (HLft + lam)
+                                   + GR ** 2 / (HR + lam)
                                    - Gtot ** 2 / (Htot + lam))
-                HLbmin = jnp.minimum(HLbmin, HLb)
-                HRbmin = jnp.minimum(HRbmin, HRb)
-            c0 = CL[:, 0:1, :]
-            CLb = CL - c0
-            CRb = Ctot - CLb
-            valid_b = ((HLbmin >= min_child_weight)
-                       & (HRbmin >= min_child_weight)
-                       & (CLb >= min_instances) & (CRb >= min_instances)
-                       & (jnp.arange(B)[None, :, None] < B - 1)
-                       & feat_mask[None, None, :]
-                       & (c0 > 0))        # no bin-0 mass -> b duplicates a
-            if dd_mask is not None:
-                valid_b = valid_b & dd_mask[None, None, :]
-            gain_b = jnp.where(valid_b, gain_b, -jnp.inf)
-            flat_gain = jnp.concatenate(
-                [flat_gain, gain_b.reshape(M, B * d)], axis=1)  # (M, 2Bd)
+                    HLmin = jnp.minimum(HLmin, HLft)
+                    HRmin = jnp.minimum(HRmin, HR)
+                Ctot = CL[:, -1:, :1]
+                CR = jnp.where(is_std, Ctot - CL, right_interval(CL))
+                CLft = jnp.where(is_std, CL, Ctot - CR)
+            else:
+                for GL, HL in zip(GLs, HLs):
+                    Gtot = GL[:, -1:, :1]
+                    Htot = HL[:, -1:, :1]
+                    GR, HR = Gtot - GL, Htot - HL
+                    gain = gain + (GL ** 2 / (HL + lam) + GR ** 2 / (HR + lam)
+                                   - Gtot ** 2 / (Htot + lam))
+                    HLmin = jnp.minimum(HLmin, HL)
+                    HRmin = jnp.minimum(HRmin, HR)
+                Ctot = CL[:, -1:, :1]
+                CR = Ctot - CL
+                CLft = CL
 
-        best = jnp.argmax(flat_gain, axis=1)
-        best_gain = jnp.take_along_axis(flat_gain, best[:, None], 1)[:, 0]
-        # depth_limit is a TRACED scalar: trees of different requested depths
-        # share one compiled program (one XLA compile per sweep, not one per
-        # distinct max_depth); levels at/past the limit emit no splits
-        ok = ((best_gain > 0) & (best_gain / node_w >= min_info_gain)
-              & jnp.isfinite(best_gain) & (level < depth_limit))
-        if min_gain_raw is not None:
-            # XGBoost's gamma thresholds the RAW loss-reduction, unlike
-            # Spark's per-node-weight minInfoGain
-            ok = ok & (best_gain >= min_gain_raw)
-        if default_dir:
-            is_b = best >= B * d
-            bloc = best - jnp.where(is_b, B * d, 0)
-            t_raw = (bloc // d).astype(jnp.int32)
-            feat_l = jnp.where(ok, bloc % d, 0).astype(jnp.int32)
-            thresh_l = jnp.where(
-                ok, jnp.where(is_b, -(t_raw + 1), t_raw), B
-            ).astype(jnp.int32)
-        else:
-            feat_l = jnp.where(ok, best % d, 0).astype(jnp.int32)
-            thresh_l = jnp.where(ok, best // d, B).astype(jnp.int32)
+            valid = ((HLmin >= min_child_weight) & (HRmin >= min_child_weight)
+                     & (CLft >= min_instances) & (CR >= min_instances)
+                     & (jnp.arange(B)[None, :, None] < B - 1)
+                     & feat_mask[None, None, :])
+            node_w = jnp.maximum(Ctot[:, 0, 0], 1e-12)
+            gain = jnp.where(valid, gain, -jnp.inf)      # (M, B, D)
+            flat_gain = gain.reshape(M, B * d)
 
-        if compact:
-            # write per-slot results back to the level's heap segment at the
-            # slots' true node ids; INT32_MAX padding slots drop out of range
-            seg_feat = jnp.zeros(level_nodes, jnp.int32)
-            seg_thresh = jnp.full(level_nodes, B, jnp.int32)
-            seg_feat = seg_feat.at[uniq].set(feat_l, mode="drop")
-            seg_thresh = seg_thresh.at[uniq].set(thresh_l, mode="drop")
-        else:
-            seg_feat, seg_thresh = feat_l, thresh_l
-        heap_feat_levels.append(seg_feat)
-        heap_thresh_levels.append(seg_thresh)
+            if default_dir:
+                # XGBoost default-direction (missing/sparse) splits: variant b
+                # routes the bin-0 (missing/absent) mass RIGHT — its cumsums
+                # are the plain ones minus the bin-0 row — a per-(node, t,
+                # feature) 2-way gain compare, exactly the C++ core's
+                # enumerate-both-directions loop (OpXGBoostClassifier.scala:47
+                # wraps those semantics).  Encoded as a NEGATIVE threshold
+                # -(t+1) so heap shapes/persistence are unchanged.  ``dd_mask``
+                # (from the caller's bin edges) limits variant b to features
+                # whose bin 0 IS a genuine missing/zero bucket (first edge
+                # pinned at 0.0 by the sparse-aware sketch): on a dense
+                # feature, bin 0 is just the lowest quantile, and routing it
+                # with the high side would fabricate non-contiguous splits real
+                # XGBoost cannot produce (code-review r5).
+                gain_b = 0.0
+                HLbmin = jnp.inf
+                HRbmin = jnp.inf
+                for GL, HL in zip(GLs, HLs):
+                    Gtot = GL[:, -1:, :1]
+                    Htot = HL[:, -1:, :1]
+                    GLb, HLb = GL - GL[:, 0:1, :], HL - HL[:, 0:1, :]
+                    GRb, HRb = Gtot - GLb, Htot - HLb
+                    gain_b = gain_b + (GLb ** 2 / (HLb + lam)
+                                       + GRb ** 2 / (HRb + lam)
+                                       - Gtot ** 2 / (Htot + lam))
+                    HLbmin = jnp.minimum(HLbmin, HLb)
+                    HRbmin = jnp.minimum(HRbmin, HRb)
+                c0 = CL[:, 0:1, :]
+                CLb = CL - c0
+                CRb = Ctot - CLb
+                valid_b = ((HLbmin >= min_child_weight)
+                           & (HRbmin >= min_child_weight)
+                           & (CLb >= min_instances) & (CRb >= min_instances)
+                           & (jnp.arange(B)[None, :, None] < B - 1)
+                           & feat_mask[None, None, :]
+                           & (c0 > 0))        # no bin-0 mass -> b duplicates a
+                if dd_mask is not None:
+                    valid_b = valid_b & dd_mask[None, None, :]
+                gain_b = jnp.where(valid_b, gain_b, -jnp.inf)
+                flat_gain = jnp.concatenate(
+                    [flat_gain, gain_b.reshape(M, B * d)], axis=1)  # (M, 2Bd)
 
-        # routing reads the FULL-width matrix: subset-local split ids map
-        # through feat_idx (no msub-wide gathered copy exists anymore)
-        fid = feat_idx[feat_l] if feat_idx is not None else feat_l
-        x_row = jnp.take_along_axis(binned_full, fid[slot][:, None], 1)[:, 0]
-        tv = thresh_l[slot]
-        go_right = _route_right(x_row, tv)
-        if bundle_end is not None:
-            # interval cap: rows past the owner member's end bin belong
-            # to OTHER members of the bundle and route left (flat gather:
-            # 2-D advanced indexing miscompiles at some shapes, see
-            # predict_ensemble)
-            ev = bundle_end.reshape(-1)[
-                jnp.clip(tv, 0, B - 1) * d + fid[slot]]
-            go_right = go_right & (x_row <= ev)
-        node = 2 * node + go_right.astype(jnp.int32)
+            best = jnp.argmax(flat_gain, axis=1)
+            best_gain = jnp.take_along_axis(flat_gain, best[:, None], 1)[:, 0]
+            # depth_limit is a TRACED scalar: trees of different requested
+            # depths share one compiled program (one XLA compile per sweep,
+            # not one per
+            # distinct max_depth); levels at/past the limit emit no splits
+            ok = ((best_gain > 0) & (best_gain / node_w >= min_info_gain)
+                  & jnp.isfinite(best_gain) & (level < depth_limit))
+            if min_gain_raw is not None:
+                # XGBoost's gamma thresholds the RAW loss-reduction, unlike
+                # Spark's per-node-weight minInfoGain
+                ok = ok & (best_gain >= min_gain_raw)
+            if default_dir:
+                is_b = best >= B * d
+                bloc = best - jnp.where(is_b, B * d, 0)
+                t_raw = (bloc // d).astype(jnp.int32)
+                feat_l = jnp.where(ok, bloc % d, 0).astype(jnp.int32)
+                thresh_l = jnp.where(
+                    ok, jnp.where(is_b, -(t_raw + 1), t_raw), B
+                ).astype(jnp.int32)
+            else:
+                feat_l = jnp.where(ok, best % d, 0).astype(jnp.int32)
+                thresh_l = jnp.where(ok, best // d, B).astype(jnp.int32)
+
+            if compact:
+                # write per-slot results back to the level's heap segment at
+                # the slots' true node ids; INT32_MAX padding slots drop out of
+                # range
+                seg_feat = jnp.zeros(level_nodes, jnp.int32)
+                seg_thresh = jnp.full(level_nodes, B, jnp.int32)
+                seg_feat = seg_feat.at[uniq].set(feat_l, mode="drop")
+                seg_thresh = seg_thresh.at[uniq].set(thresh_l, mode="drop")
+            else:
+                seg_feat, seg_thresh = feat_l, thresh_l
+            heap_feat_levels.append(seg_feat)
+            heap_thresh_levels.append(seg_thresh)
+
+        with jax.named_scope("tree.route"):
+            # routing reads the FULL-width matrix: subset-local split ids map
+            # through feat_idx (no msub-wide gathered copy exists anymore)
+            fid = feat_idx[feat_l] if feat_idx is not None else feat_l
+            x_row = jnp.take_along_axis(binned_full, fid[slot][:, None],
+                                        1)[:, 0]
+            tv = thresh_l[slot]
+            go_right = _route_right(x_row, tv)
+            if bundle_end is not None:
+                # interval cap: rows past the owner member's end bin belong
+                # to OTHER members of the bundle and route left (flat gather:
+                # 2-D advanced indexing miscompiles at some shapes, see
+                # predict_ensemble)
+                ev = bundle_end.reshape(-1)[
+                    jnp.clip(tv, 0, B - 1) * d + fid[slot]]
+                go_right = go_right & (x_row <= ev)
+            node = 2 * node + go_right.astype(jnp.int32)
 
     # heap layout: level l occupies slots [2^l - 1, 2^{l+1} - 1)
     heap_feat = jnp.concatenate(heap_feat_levels)
@@ -1323,24 +1337,26 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
         # regardless of the mapped feature id)
         heap_feat = feat_idx[heap_feat]
 
-    n_leaves = 2 ** max_depth
-    if n * n_leaves <= (64 << 20):
-        # leaf sums as one-hot matmuls (same scatter-avoidance as histograms)
-        onehot_leaf = (node[:, None] == jnp.arange(n_leaves)[None, :]
-                       ).astype(jnp.float32)          # (N, 2^d)
-        stacked = jnp.concatenate([G, H, C[:, None]], axis=1)  # (N, 2K+1)
-        sums = jax.lax.dot(onehot_leaf.T, stacked,
-                           precision=jax.lax.Precision.HIGHEST)
-        Gs, Hs, Cs = sums[:, :k], sums[:, k:2 * k], sums[:, 2 * k]
-    else:  # one-hot too large for very deep trees; scatter scales with N
-        Gs = jnp.zeros((n_leaves, k), jnp.float32).at[node].add(G)
-        Hs = jnp.zeros((n_leaves, k), jnp.float32).at[node].add(H)
-        Cs = jnp.zeros((n_leaves,), jnp.float32).at[node].add(C)
-    if all_reduce is not None:
-        Gs, Hs, Cs = all_reduce(Gs), all_reduce(Hs), all_reduce(Cs)
-    newton_val = -learning_rate * Gs / (Hs + lam)
-    mean_val = Gs / jnp.maximum(Cs, 1e-12)[:, None]
-    leaf = jnp.where(newton_leaf, newton_val, mean_val)
+    with jax.named_scope("tree.leaf"):
+        n_leaves = 2 ** max_depth
+        if n * n_leaves <= (64 << 20):
+            # leaf sums as one-hot matmuls (same scatter-avoidance as
+            # histograms)
+            onehot_leaf = (node[:, None] == jnp.arange(n_leaves)[None, :]
+                           ).astype(jnp.float32)          # (N, 2^d)
+            stacked = jnp.concatenate([G, H, C[:, None]], axis=1)  # (N, 2K+1)
+            sums = jax.lax.dot(onehot_leaf.T, stacked,
+                               precision=jax.lax.Precision.HIGHEST)
+            Gs, Hs, Cs = sums[:, :k], sums[:, k:2 * k], sums[:, 2 * k]
+        else:  # one-hot too large for very deep trees; scatter scales with N
+            Gs = jnp.zeros((n_leaves, k), jnp.float32).at[node].add(G)
+            Hs = jnp.zeros((n_leaves, k), jnp.float32).at[node].add(H)
+            Cs = jnp.zeros((n_leaves,), jnp.float32).at[node].add(C)
+        if all_reduce is not None:
+            Gs, Hs, Cs = all_reduce(Gs), all_reduce(Hs), all_reduce(Cs)
+        newton_val = -learning_rate * Gs / (Hs + lam)
+        mean_val = Gs / jnp.maximum(Cs, 1e-12)[:, None]
+        leaf = jnp.where(newton_leaf, newton_val, mean_val)
     return heap_feat, heap_thresh, leaf, tuple(leaf_snaps)
 
 
@@ -1497,17 +1513,17 @@ def grow_forest(binned: jnp.ndarray, Y: np.ndarray, BW: np.ndarray,
     feat_mask = np.asarray(feat_mask, bool)
     limit = jnp.full((chunk,), max_depth, jnp.int32)
     feats, threshs, leaves = [], [], []
-    from ..utils.profiling import count_launch
+    from ..utils.profiling import launch
 
     for s in range(0, T, chunk):
-        count_launch("forest_chunk")
-        e = min(s + chunk, T)
-        pad = chunk - (e - s)
-        BWc = jnp.asarray(np.pad(BW[s:e], ((0, pad), (0, 0))))
-        Mc = jnp.asarray(np.pad(feat_mask[s:e], ((0, pad), (0, 0))))
-        f, t, lf = _grow_chunk_bagged(binned, Yj, BWc, Mc, limit, heap_depth,
-                                      n_bins, *args,
-                                      onehot_targets=onehot_targets)
+        with launch("forest_chunk"):
+            e = min(s + chunk, T)
+            pad = chunk - (e - s)
+            BWc = jnp.asarray(np.pad(BW[s:e], ((0, pad), (0, 0))))
+            Mc = jnp.asarray(np.pad(feat_mask[s:e], ((0, pad), (0, 0))))
+            f, t, lf = _grow_chunk_bagged(
+                binned, Yj, BWc, Mc, limit, heap_depth, n_bins, *args,
+                onehot_targets=onehot_targets)
         if as_numpy:
             f, t, lf = np.asarray(f), np.asarray(t), np.asarray(lf)
         feats.append(f[:e - s])
@@ -1670,19 +1686,20 @@ def grow_rf_grid(binned, Y, W_tr, seed: int, n_trees: int,
     pg = jnp.asarray(pair_min_ig, jnp.float32)
     pi = jnp.asarray(pair_min_inst, jnp.float32)
     pd_ = jnp.asarray(pair_depth, jnp.int32)
-    from ..utils.profiling import count_launch
+    from ..utils.profiling import launch
 
     feats, threshs, leaves = [], [], []
     snaps: List[list] = [[] for _ in leaf_levels]
     for s in range(0, total, chunk):
-        count_launch("rf_grid_chunk")
-        f, t, lf, sn = _grow_chunk_rf_grid(
-            binned, Y, W_tr, jnp.int32(seed), jnp.int32(s), jnp.int32(total),
-            pf, pg, pi, pd_, jnp.float32(subsample_rate), chunk, msub,
-            heap_depth, n_bins, jnp.float32(lam),
-            jnp.float32(min_child_weight), n_trees,
-            onehot_targets=onehot_targets, leaf_levels=leaf_levels,
-            hist_bf16=hist_bf16)
+        with launch("rf_grid_chunk"):
+            f, t, lf, sn = _grow_chunk_rf_grid(
+                binned, Y, W_tr, jnp.int32(seed), jnp.int32(s),
+                jnp.int32(total), pf, pg, pi, pd_,
+                jnp.float32(subsample_rate), chunk, msub,
+                heap_depth, n_bins, jnp.float32(lam),
+                jnp.float32(min_child_weight), n_trees,
+                onehot_targets=onehot_targets, leaf_levels=leaf_levels,
+                hist_bf16=hist_bf16)
         e = min(s + chunk, total)
         feats.append(f[:e - s])
         threshs.append(t[:e - s])
@@ -1730,16 +1747,17 @@ def grow_forest_rf(binned, Y, base_w, seed: int, n_trees: int, msub: int,
     args = (jnp.float32(lam), jnp.float32(min_child_weight),
             jnp.float32(min_info_gain), jnp.float32(min_instances),
             jnp.float32(1.0))
-    from ..utils.profiling import count_launch
+    from ..utils.profiling import launch
 
     feats, threshs, leaves = [], [], []
     for s in range(0, n_trees, chunk):
-        count_launch("rf_chunk")
-        f, t, lf = _grow_chunk_rf(
-            binned, Y, base_w, jnp.int32(seed), jnp.int32(s),
-            jnp.int32(n_trees), jnp.int32(max_depth),
-            jnp.float32(subsample_rate), chunk, msub, heap_depth, n_bins,
-            *args, onehot_targets=onehot_targets, hist_bf16=hist_bf16)
+        with launch("rf_chunk"):
+            f, t, lf = _grow_chunk_rf(
+                binned, Y, base_w, jnp.int32(seed), jnp.int32(s),
+                jnp.int32(n_trees), jnp.int32(max_depth),
+                jnp.float32(subsample_rate), chunk, msub, heap_depth,
+                n_bins, *args, onehot_targets=onehot_targets,
+                hist_bf16=hist_bf16)
         e = min(s + chunk, n_trees)
         if e - s < chunk:
             f, t, lf = f[:e - s], t[:e - s], lf[:e - s]
@@ -1823,13 +1841,14 @@ def _gbt_chain_rounds_jit(binned, y, W, Fm0, vi, depth_lim, lams, mcws,
                    bundle_end=bundle_end, acc_bf16=acc_bf16)
 
     def round_step(Fm, rid):
-        if obj == "binary":
-            P = jax.nn.sigmoid(Fm)                   # (S, N)
-            G = W * (P - y[None, :])
-            H = W * jnp.maximum(P * (1 - P), 1e-6)
-        else:
-            G = W * (Fm - y[None, :])
-            H = W
+        with jax.named_scope("gbt.grad"):
+            if obj == "binary":
+                P = jax.nn.sigmoid(Fm)                   # (S, N)
+                G = W * (P - y[None, :])
+                H = W * jnp.maximum(P * (1 - P), 1e-6)
+            else:
+                G = W * (Fm - y[None, :])
+                H = W
 
         if goss is not None:
             k_top, k_rest = goss
@@ -1857,17 +1876,20 @@ def _gbt_chain_rounds_jit(binned, y, W, Fm0, vi, depth_lim, lams, mcws,
 
             f, t, lf = jax.vmap(one)(G, H, W, depth_lim, lams, mcws, migs,
                                      mins_, lrs, mgrs)
-        if bundle_end is not None:
-            inc = jax.vmap(lambda ff, tt, ll: _predict_tree_bundled(
-                binned, ff, tt, ll, max_depth, bundle_end))(f, t, lf)[:, :, 0]
-        else:
-            inc = jax.vmap(lambda ff, tt, ll: predict_tree(
-                binned, ff, tt, ll, max_depth))(f, t, lf)[:, :, 0]
-        Fm = Fm + inc
-        if use_es:
-            m = _chain_es_metric(Fm, y, vi, obj)
-        else:
-            m = jnp.zeros(Fm.shape[0], jnp.float32)
+        with jax.named_scope("gbt.update"):
+            if bundle_end is not None:
+                inc = jax.vmap(lambda ff, tt, ll: _predict_tree_bundled(
+                    binned, ff, tt, ll, max_depth, bundle_end))(
+                        f, t, lf)[:, :, 0]
+            else:
+                inc = jax.vmap(lambda ff, tt, ll: predict_tree(
+                    binned, ff, tt, ll, max_depth))(f, t, lf)[:, :, 0]
+            Fm = Fm + inc
+        with jax.named_scope("gbt.es_metric"):
+            if use_es:
+                m = _chain_es_metric(Fm, y, vi, obj)
+            else:
+                m = jnp.zeros(Fm.shape[0], jnp.float32)
         return Fm, (f, t, lf, m)
 
     rounds = jnp.arange(n_rounds, dtype=jnp.int32)
@@ -2020,8 +2042,9 @@ def predict_tree(binned: jnp.ndarray, feat: jnp.ndarray, thresh: jnp.ndarray,
         x = jnp.take_along_axis(binned, f[:, None], 1)[:, 0]
         return 2 * node + _route_right(x, t).astype(jnp.int32)
 
-    node = lax.fori_loop(0, max_depth, level, node)
-    return leaf[node]
+    with jax.named_scope("tree.predict"):
+        node = lax.fori_loop(0, max_depth, level, node)
+        return leaf[node]
 
 
 def _predict_tree_bundled(binned, feat, thresh, leaf, max_depth: int,
@@ -2082,30 +2105,31 @@ def predict_ensemble(binned: jnp.ndarray, feat: jnp.ndarray,
             [predict_ensemble(binned[s:s + rows], feat, thresh, leaf,
                               max_depth)
              for s in range(0, n, rows)], axis=0)
-    node = jnp.zeros((T, n), jnp.int32)
-    feat_f = feat.reshape(-1)
-    thresh_f = thresh.reshape(-1)
-    binned_f = binned.reshape(-1)
-    tree_off = (jnp.arange(T, dtype=jnp.int32) * nodes)[:, None]
-    row_off = (jnp.arange(n, dtype=jnp.int32) * jnp.int32(d))[None, :]
+    with jax.named_scope("tree.predict"):
+        node = jnp.zeros((T, n), jnp.int32)
+        feat_f = feat.reshape(-1)
+        thresh_f = thresh.reshape(-1)
+        binned_f = binned.reshape(-1)
+        tree_off = (jnp.arange(T, dtype=jnp.int32) * nodes)[:, None]
+        row_off = (jnp.arange(n, dtype=jnp.int32) * jnp.int32(d))[None, :]
 
-    def level(l, node):
-        heap = (2 ** l - 1) + node + tree_off            # (T, N) flat ids
-        f = feat_f[heap]
-        t = thresh_f[heap]
-        x = binned_f[row_off + f]                        # (T, N)
-        return 2 * node + _route_right(x, t).astype(jnp.int32)
+        def level(l, node):
+            heap = (2 ** l - 1) + node + tree_off            # (T, N) flat ids
+            f = feat_f[heap]
+            t = thresh_f[heap]
+            x = binned_f[row_off + f]                        # (T, N)
+            return 2 * node + _route_right(x, t).astype(jnp.int32)
 
-    node = lax.fori_loop(0, max_depth, level, node)
-    # leaf-sum in tree chunks: one (T, N, K) gather would cost T·N·K·4 bytes
-    # of HBM (4 GB for 512 trees × 1M rows); chunks bound it at ~32 MB
-    k = leaf.shape[2]
-    n_leaves = leaf.shape[1]
-    leaf_f = leaf.reshape(T * n_leaves, k)
-    leaf_off = (jnp.arange(T, dtype=jnp.int32) * n_leaves)[:, None]
-    chunk = max(1, min(T, (32 << 20) // max(n * k * 4, 1)))
-    out = jnp.zeros((n, k), jnp.float32)
-    for s in range(0, T, chunk):
-        e = min(s + chunk, T)
-        out = out + leaf_f[node[s:e] + leaf_off[s:e]].sum(axis=0)
-    return out
+        node = lax.fori_loop(0, max_depth, level, node)
+        # leaf-sum in tree chunks: one (T, N, K) gather would cost T·N·K·4 bytes
+        # of HBM (4 GB for 512 trees × 1M rows); chunks bound it at ~32 MB
+        k = leaf.shape[2]
+        n_leaves = leaf.shape[1]
+        leaf_f = leaf.reshape(T * n_leaves, k)
+        leaf_off = (jnp.arange(T, dtype=jnp.int32) * n_leaves)[:, None]
+        chunk = max(1, min(T, (32 << 20) // max(n * k * 4, 1)))
+        out = jnp.zeros((n, k), jnp.float32)
+        for s in range(0, T, chunk):
+            e = min(s + chunk, T)
+            out = out + leaf_f[node[s:e] + leaf_off[s:e]].sum(axis=0)
+        return out

@@ -152,8 +152,12 @@ def _score_ensemble_jit(binned, feat, thresh, leaf, base_score, depth: int,
     return raw[:, 0] + base_score  # gbdt_reg
 
 
+import contextlib
 import threading
 from collections import OrderedDict
+
+from ..obs.trace import span as _span
+from ..utils.profiling import count_memo
 
 _BIN_CACHE: "OrderedDict" = OrderedDict()
 _BIN_CACHE_CAPACITY = 32
@@ -191,7 +195,7 @@ def _memo_peek(key):
         return hit
 
 
-def _memo(key, build):
+def _memo(key, build, span: Optional[str] = None):
     """Content-keyed sweep memo with LRU eviction.
 
     A CV×grid sweep re-touches the same fold matrices for every candidate,
@@ -203,28 +207,41 @@ def _memo(key, build):
     selector's sketch-prefetch thread overlaps host prep with the sweep's
     queued device work; when the tree group arrives it waits for the
     in-flight build instead of re-sketching a GB-scale matrix).
+
+    Every probe is counted by memo kind (``key[0]``) in
+    ``COUNTERS.memo_tags`` as a hit, a build or a wait; ``span`` names the
+    span a traced run records round the build (``tree.prep.*``), and a
+    wait for another thread's build is the span ``tree.prep.wait``.
     """
     with _MEMO_LOCK:
         hit = _BIN_CACHE.get(key)
         if hit is not None:
             _BIN_CACHE.move_to_end(key)
-            return hit
-        ev = _MEMO_INFLIGHT.get(key)
-        owner = ev is None
-        if owner:
-            ev = threading.Event()
-            _MEMO_INFLIGHT[key] = ev
-        gen = _MEMO_GEN
+        else:
+            ev = _MEMO_INFLIGHT.get(key)
+            owner = ev is None
+            if owner:
+                ev = threading.Event()
+                _MEMO_INFLIGHT[key] = ev
+            gen = _MEMO_GEN
+    if hit is not None:
+        count_memo(key[0], "hits")
+        return hit
     if not owner:
-        ev.wait()
+        count_memo(key[0], "waits")
+        with _span("tree.prep.wait", cat="prep", memo=key[0]):
+            ev.wait()
         with _MEMO_LOCK:
             hit = _BIN_CACHE.get(key)
         if hit is not None:
             return hit
         # the owning build failed (or a clear raced it): build here too —
         # concurrent rebuilds on this rare path are benign (same content)
+    count_memo(key[0], "builds")
     try:
-        val = build()
+        with (_span(span, cat="prep", memo=key[0]) if span
+              else contextlib.nullcontext()):
+            val = build()
         with _MEMO_LOCK:
             # insert BEFORE waking waiters (they re-probe the cache on
             # wake); skip if clear_sweep_caches ran since the build began
@@ -292,7 +309,8 @@ def _content_hash(a: np.ndarray) -> str:
     k = id(a)
     h = _HASH_BY_ID.get(k)
     if h is None:
-        h = _full_hash(a)
+        with _span("tree.prep.hash", cat="prep", bytes=a.nbytes):
+            h = _full_hash(a)
         _HASH_BY_ID[k] = h
         try:
             weakref.finalize(a, _HASH_BY_ID.pop, k, None)
@@ -334,7 +352,8 @@ def _as_f32(X) -> np.ndarray:
     hit = _CONTIG_BY_ID.get(k)
     if hit is not None and hit[0] == digest:
         return hit[1]
-    Xc = np.ascontiguousarray(Xf)
+    with _span("tree.prep.contiguous", cat="prep", bytes=Xf.nbytes):
+        Xc = np.ascontiguousarray(Xf)
     _CONTIG_BY_ID[k] = (digest, Xc)
     try:
         import weakref
@@ -349,9 +368,10 @@ def _upload_timed(a):
     import time as _time
 
     from ..utils.profiling import count_upload
-    t0 = _time.perf_counter()
-    out = jnp.asarray(a)
-    count_upload(a.nbytes, _time.perf_counter() - t0)
+    with _span("tree.prep.upload", cat="prep", bytes=a.nbytes):
+        t0 = _time.perf_counter()
+        out = jnp.asarray(a)
+        count_upload(a.nbytes, _time.perf_counter() - t0)
     return out
 
 
@@ -414,7 +434,11 @@ def _dev_memo_sharded(arr, sharding, tag: str = "up"):
 
     a = np.ascontiguousarray(np.asarray(arr))
     key = (tag, _content_hash(a), a.shape, str(a.dtype), str(sharding))
-    return _memo(key, lambda: jax.device_put(a, sharding))
+
+    def build():
+        with _span("tree.prep.upload", cat="prep", bytes=a.nbytes):
+            return jax.device_put(a, sharding)
+    return _memo(key, build)
 
 
 @jax.jit
@@ -440,8 +464,9 @@ def _binned_cached(Xf: np.ndarray, hx: str, edges):
     key = ("bins", hx, _content_hash(ef), Xf.shape)
 
     def build():
-        big = Xf.size > _HOST_BIN_ELEMS and ef.shape[1] < 127
-        if big:
+        with _span("tree.prep.bin", cat="prep"):
+            if not (Xf.size > _HOST_BIN_ELEMS and ef.shape[1] < 127):
+                return apply_bins(jnp.asarray(Xf), jnp.asarray(ef))
             # reuse the sweep's shared upload when present: device binning
             # is one launch vs a ~10 s/1M-row host pass + a second upload.
             # (Binning the bf16 copy can flip values that sit within bf16
@@ -451,11 +476,11 @@ def _binned_cached(Xf: np.ndarray, hx: str, edges):
             if xdev is None:
                 xdev = _memo_peek(("X_f32", hx, Xf.shape, "float32"))
             if xdev is not None:
-                from ..utils.profiling import count_launch
-                count_launch("device_bin")
-                return _apply_bins_i8(xdev, jnp.asarray(ef))
-            return _upload_timed(_host_bins(Xf, ef))
-        return apply_bins(jnp.asarray(Xf), jnp.asarray(ef))
+                from ..utils.profiling import launch
+                with launch("device_bin"):
+                    return _apply_bins_i8(xdev, jnp.asarray(ef))
+            host = _host_bins(Xf, ef)
+        return _upload_timed(host)    # its own span: tree.prep.upload
     return _memo(key, build)
 
 
@@ -490,7 +515,8 @@ def _prep_tree_inputs(X, max_bins):
     Xf = _as_f32(X)
     hx = _content_hash(Xf)
     edges = _memo(("edges", hx, Xf.shape, max_bins),
-                  lambda: quantile_bins(Xf, max_bins))
+                  lambda: quantile_bins(Xf, max_bins),
+                  span="tree.prep.sketch")
     return edges, _binned_cached(Xf, hx, edges)
 
 
@@ -518,7 +544,8 @@ def _prep_tree_inputs_mesh(X, max_bins, mesh):
     hx = _content_hash(Xf)
     mesh_key = tuple(sorted(mesh.shape.items()))
     edges = _memo(("edges_mesh", hx, Xf.shape, max_bins, mesh_key),
-                  lambda: quantile_bins_sharded(Xf, mesh, max_bins))
+                  lambda: quantile_bins_sharded(Xf, mesh, max_bins),
+                  span="tree.prep.sketch")
     return edges, _binned_cached(Xf, hx, edges)
 
 
@@ -566,7 +593,8 @@ def _prep_tree_inputs_sparse(X, max_bins):
         return e, b, None
     hx = _content_hash(Xf)
     edges = _memo(("edges_sp", hx, Xf.shape, max_bins),
-                  lambda: quantile_bins_sparse_aware(Xf, max_bins))
+                  lambda: quantile_bins_sparse_aware(Xf, max_bins),
+                  span="tree.prep.sketch")
     binned = _binned_cached(Xf, hx, edges)
     if os.environ.get("TMOG_SPARSE_HIST", "0") != "1":
         return edges, binned, None
@@ -579,7 +607,8 @@ def _prep_tree_inputs_sparse(X, max_bins):
         zb_oh = np.eye(max_bins, dtype=np.float32)[zero_bin]   # (D, B)
         return (_upload_timed(rows), _upload_timed(bins),
                 _upload_timed(zb_oh))
-    csr = _memo(("csr", hx, Xf.shape, max_bins), build)
+    csr = _memo(("csr", hx, Xf.shape, max_bins), build,
+                span="tree.prep.csr")
     return edges, binned, (csr if csr else None)
 
 
@@ -615,14 +644,15 @@ def _maybe_bundle(hx: str, edges, binned, max_bins: int):
            force)
 
     def build():
-        host = np.asarray(binned)
-        b = bundle_features(host, np.asarray(edges), max_bins,
-                            min_width_ratio=(1.0 if force
-                                             else EFB_MIN_WIDTH_RATIO))
-        if b is None:
-            return ()
-        return (b, _upload_timed(bundle_matrix(b, host)),
-                _upload_timed(b.end_bin))
+        with _span("tree.prep.bundle", cat="prep"):
+            host = np.asarray(binned)
+            b = bundle_features(host, np.asarray(edges), max_bins,
+                                min_width_ratio=(1.0 if force
+                                                 else EFB_MIN_WIDTH_RATIO))
+            if b is None:
+                return ()
+            bundled = bundle_matrix(b, host)
+        return (b, _upload_timed(bundled), _upload_timed(b.end_bin))
 
     val = _memo(key, build)
     return val if val else None
@@ -653,10 +683,12 @@ def _prep_tree_inputs_weighted(X, max_bins: int, row_weight=None):
         from .gbdt_kernels import quantile_bins_sparse_aware
 
         edges = _memo(("edges_sp", hxm, Xm.shape, max_bins),
-                      lambda: quantile_bins_sparse_aware(Xm, max_bins))
+                      lambda: quantile_bins_sparse_aware(Xm, max_bins),
+                      span="tree.prep.sketch")
     else:
         edges = _memo(("edges", hxm, Xm.shape, max_bins),
-                      lambda: quantile_bins(Xm, max_bins))
+                      lambda: quantile_bins(Xm, max_bins),
+                      span="tree.prep.sketch")
     return edges, _binned_cached(Xf, _content_hash(Xf), edges), None
 
 
@@ -1128,7 +1160,7 @@ class _GBTBase(PredictorEstimator):
         (``goss_plan``) engages for deep fits (max_depth >= 8), growing
         each round's tree on a gradient-selected row gather; bf16
         histogram accumulation rides ``TMOG_MATRIX_PRECISION=bf16``."""
-        from ..utils.profiling import count_launch
+        from ..utils.profiling import launch
         from .gbdt_kernels import (_gbt_chain_rounds_jit,
                                    _resolve_compile_depth, default_dir_mask,
                                    goss_plan, hist_accum_bf16,
@@ -1184,21 +1216,21 @@ class _GBTBase(PredictorEstimator):
         fb, tb, lb = [], [], []
         n_rounds = 0
         for ci in range(-(-self.max_iter // es_chunk)):
-            count_launch("gbt_rounds")
-            Fm, fs, ts, lfs, ms = _gbt_chain_rounds_jit(
-                binned, yj, W1, Fm, vi_arr, depth1,
-                one(self.reg_lambda), one(self.min_child_weight),
-                one(self.min_info_gain),
-                one(self.min_instances_per_node),
-                one(self.step_size), one(self.min_split_gain_raw),
-                es_chunk, heap_depth, self.max_bins, obj,
-                self._hist_bf16(), run_es, csr=csr,
-                skip_counts=skip_counts, seg_hist=seg,
-                default_dir=self.sparse_default_direction, dd_mask=dd,
-                bundle_end=bend, acc_bf16=acc, goss=goss,
-                goss_seed=jnp.int32(self.seed),
-                chain_ids=jnp.zeros(1, jnp.int32),
-                round_offset=jnp.int32(n_rounds))
+            with launch("gbt_rounds"):
+                Fm, fs, ts, lfs, ms = _gbt_chain_rounds_jit(
+                    binned, yj, W1, Fm, vi_arr, depth1,
+                    one(self.reg_lambda), one(self.min_child_weight),
+                    one(self.min_info_gain),
+                    one(self.min_instances_per_node),
+                    one(self.step_size), one(self.min_split_gain_raw),
+                    es_chunk, heap_depth, self.max_bins, obj,
+                    self._hist_bf16(), run_es, csr=csr,
+                    skip_counts=skip_counts, seg_hist=seg,
+                    default_dir=self.sparse_default_direction, dd_mask=dd,
+                    bundle_end=bend, acc_bf16=acc, goss=goss,
+                    goss_seed=jnp.int32(self.seed),
+                    chain_ids=jnp.zeros(1, jnp.int32),
+                    round_offset=jnp.int32(n_rounds))
             fb.append(fs)
             tb.append(ts)
             lb.append(lfs)

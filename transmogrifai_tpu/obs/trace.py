@@ -109,6 +109,13 @@ class Tracer:
         #: FlightRecorder installed alongside this tracer (start_trace
         #: wires one by default so span ids link events to the tree)
         self.flight = None
+        #: perf_counter minus time.time(), sampled once: puts an interval
+        #: that arrives on the wall clock (JAX's compile events) on the
+        #: clock every other span is on
+        self.unix_to_perf = time.perf_counter() - time.time()
+        #: the JAX monitoring listener start_trace registered for this
+        #: tracer (stop_trace takes exactly this one out again)
+        self.compile_listener = None
 
     def begin(self, name: str, cat: str, parent_id: Optional[int],
               attrs: Dict[str, Any]) -> Span:
@@ -117,11 +124,27 @@ class Tracer:
 
     def end(self, sp: Span) -> None:
         sp.dur_s = time.perf_counter() - sp.t0
+        self._store(sp)
+
+    def _store(self, sp: Span) -> None:
         with self._lock:
             if len(self.spans) < self.max_spans:
                 self.spans.append(sp)
             else:
                 self.dropped += 1
+
+    def record(self, name: str, cat: str, parent_id: Optional[int],
+               t0_unix: float, t1_unix: float,
+               attrs: Dict[str, Any]) -> Span:
+        """A finished span whose interval is known only after the fact
+        (``time.time()`` seconds): stored like any other, with its start
+        moved onto the ``perf_counter`` clock."""
+        sp = self.begin(name, cat, parent_id, attrs)
+        sp.t0_unix = t0_unix
+        sp.t0 = t0_unix + self.unix_to_perf
+        sp.dur_s = max(t1_unix - t0_unix, 0.0)
+        self._store(sp)
+        return sp
 
     def snapshot(self) -> List[Span]:
         with self._lock:
@@ -154,14 +177,45 @@ def install_tracer(tracer: Optional[Tracer]) -> Optional[Tracer]:
     return tracer
 
 
+#: JAX monitoring events recorded as spans while a tracer is armed: every
+#: program a traced run builds shows as ``jit.trace:<fun_name>``,
+#: ``jit.lower:<fun_name>`` and ``jit.compile:<fun_name>`` (the last is
+#: raised for a load from the persistent cache too)
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.compile",
+}
+
+
+def _compile_listener(tracer: Tracer):
+    """The time-span listener of ``tracer``: JAX calls it on the thread
+    that compiled, when the event ends, so the span current there is the
+    parent."""
+    def on_time_span(event: str, start_time: float, end_time: float,
+                     **kwargs) -> None:
+        kind = _COMPILE_EVENTS.get(event)
+        if kind is None:
+            return
+        parent = current_span()
+        tracer.record(f"{kind}:{kwargs.get('fun_name', '?')}", "compile",
+                      parent.span_id if parent is not None else None,
+                      start_time, end_time, dict(_GLOBAL_ATTRS))
+    return on_time_span
+
+
 def start_trace(label: str = "", max_spans: int = 100_000,
                 flight_capacity: int = 4096,
                 capture_hlo: bool = True) -> Tracer:
     """Arm tracing process-wide: installs a fresh :class:`Tracer`, a
     linked :class:`~transmogrifai_tpu.obs.flight.FlightRecorder` (span-id
-    causality links come for free), and — unless ``capture_hlo=False`` —
-    the compiled-program feature hook (``obs/hlo.py``) so device stages
-    record their HLO op mix / FLOPs / bytes-accessed."""
+    causality links come for free), a listener that records every program
+    JAX traces, lowers and compiles as a ``jit.*`` span, and — unless
+    ``capture_hlo=False`` — the compiled-program feature hook
+    (``obs/hlo.py``) so device stages record their HLO op mix / FLOPs /
+    bytes-accessed."""
+    import jax.monitoring
+
     from . import flight as _flight
     from . import hlo as _hlo
 
@@ -171,6 +225,9 @@ def start_trace(label: str = "", max_spans: int = 100_000,
     _flight.install_recorder(tracer.flight)
     if capture_hlo:
         _hlo.arm()
+    tracer.compile_listener = _compile_listener(tracer)
+    jax.monitoring.register_event_time_span_listener(
+        tracer.compile_listener)
     install_tracer(tracer)
     return tracer
 
@@ -185,6 +242,14 @@ def stop_trace() -> Optional[Tracer]:
     install_tracer(None)
     _flight.install_recorder(None)
     _hlo.disarm()
+    if tracer is not None and tracer.compile_listener is not None:
+        import jax.monitoring
+
+        # this one listener only: clear_event_listeners() would take
+        # every other meter in the process (the benchmark's) with it
+        jax.monitoring.unregister_event_time_span_listener(
+            tracer.compile_listener)
+        tracer.compile_listener = None
     return tracer
 
 

@@ -361,7 +361,7 @@ def grow_rf_grid_sharded(binned, Y, W_tr, BWr, feat_idx, pair_fold,
     """
     from ..models.gbdt_kernels import (_accel_bf16, _grow_tree_traced,
                                        forest_chunk_size)
-    from ..utils.profiling import count_launch
+    from ..utils.profiling import launch
     from .mesh import grid_sharding, shard_map_compat
 
     data_axis, grid_axis = mesh.axis_names
@@ -436,17 +436,17 @@ def grow_rf_grid_sharded(binned, Y, W_tr, BWr, feat_idx, pair_fold,
     snap_parts = [[] for _ in leaf_levels]
     fi_dev = jnp.asarray(np.asarray(feat_idx, np.int32))
     for s in range(0, total, chunk):
-        count_launch("rf_grid_chunk_sharded")
-        flat = np.arange(s, s + chunk)
-        t_loc = (flat % n_trees).astype(np.int32)
-        p_idx = np.minimum(flat // n_trees, P_pairs - 1)
-        args = [jax.device_put(np.ascontiguousarray(a), gs) for a in (
-            t_loc, np.asarray(pair_fold, np.int32)[p_idx],
-            np.asarray(pair_min_ig, np.float32)[p_idx],
-            np.asarray(pair_min_inst, np.float32)[p_idx],
-            np.asarray(pair_depth, np.int32)[p_idx],
-            (flat < total).astype(np.int32))]
-        f, t, lf, snaps = fn(binned, Y, W_tr, BWr, fi_dev, *args)
+        with launch("rf_grid_chunk_sharded"):
+            flat = np.arange(s, s + chunk)
+            t_loc = (flat % n_trees).astype(np.int32)
+            p_idx = np.minimum(flat // n_trees, P_pairs - 1)
+            args = [jax.device_put(np.ascontiguousarray(a), gs) for a in (
+                t_loc, np.asarray(pair_fold, np.int32)[p_idx],
+                np.asarray(pair_min_ig, np.float32)[p_idx],
+                np.asarray(pair_min_inst, np.float32)[p_idx],
+                np.asarray(pair_depth, np.int32)[p_idx],
+                (flat < total).astype(np.int32))]
+            f, t, lf, snaps = fn(binned, Y, W_tr, BWr, fi_dev, *args)
         e = min(s + chunk, total)
         feats.append(np.asarray(f)[: e - s])
         threshs.append(np.asarray(t)[: e - s])

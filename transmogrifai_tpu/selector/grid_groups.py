@@ -791,7 +791,7 @@ class GBTGridGroup(TreeGridGroup):
                                           regression_metric_grid)
         from ..models.gbdt_kernels import predict_ensemble, predict_tree
         from ..models.trees import _dev_memo, _prep_tree_inputs_sparse
-        from ..utils.profiling import count_launch
+        from ..utils.profiling import launch
 
         ests = self._chains()
         e0 = ests[0]
@@ -1004,43 +1004,43 @@ class GBTGridGroup(TreeGridGroup):
         n_rounds = 0
         for ci in range(-(-e0.max_iter // es_chunk)):
             if self.mesh is not None:
-                count_launch("gbt_chain_rounds_sharded")
-                Fm, fs, ts, lfs, ms = gbt_chain_rounds_sharded(
-                    binned_sh, y_sh, Wj, Fm, yv_dev, vi_arr, *vecs_sh,
-                    self.mesh, n_rounds=es_chunk, max_depth=heap_depth,
-                    n_bins=int(e0.max_bins), obj=obj, hist_bf16=bf16,
-                    use_es=run_es, skip_counts=skip_counts,
-                    bundle_end=(bundles.end_bin if bundles is not None
-                                else None), acc_bf16=acc)
+                with launch("gbt_chain_rounds_sharded"):
+                    Fm, fs, ts, lfs, ms = gbt_chain_rounds_sharded(
+                        binned_sh, y_sh, Wj, Fm, yv_dev, vi_arr, *vecs_sh,
+                        self.mesh, n_rounds=es_chunk, max_depth=heap_depth,
+                        n_bins=int(e0.max_bins), obj=obj, hist_bf16=bf16,
+                        use_es=run_es, skip_counts=skip_counts,
+                        bundle_end=(bundles.end_bin if bundles is not None
+                                    else None), acc_bf16=acc)
             elif chunk >= S:
-                count_launch("gbt_chain_rounds")
-                Fm, fs, ts, lfs, ms = _gbt_chain_rounds_jit(
-                    binned, yj, Wj, Fm, vi_arr, depth_lim, lams, mcws, migs,
-                    mins_, lrs, mgrs, es_chunk, heap_depth,
-                    int(e0.max_bins), obj, bf16, run_es, csr=csr,
-                    skip_counts=skip_counts, seg_hist=seg,
-                    default_dir=e0.sparse_default_direction, dd_mask=dd,
-                    bundle_end=bend, acc_bf16=acc, goss=goss,
-                    goss_seed=jnp.int32(e0.seed),
-                    chain_ids=jnp.arange(S, dtype=jnp.int32),
-                    round_offset=jnp.int32(n_rounds))
-            else:
-                parts = []
-                for s0 in range(0, S, chunk):
-                    s1 = min(s0 + chunk, S)
-                    count_launch("gbt_chain_rounds")
-                    parts.append(_gbt_chain_rounds_jit(
-                        binned, yj, Wj[s0:s1], Fm[s0:s1], vi_arr,
-                        depth_lim[s0:s1], lams[s0:s1], mcws[s0:s1],
-                        migs[s0:s1], mins_[s0:s1], lrs[s0:s1],
-                        mgrs[s0:s1], es_chunk, heap_depth,
+                with launch("gbt_chain_rounds"):
+                    Fm, fs, ts, lfs, ms = _gbt_chain_rounds_jit(
+                        binned, yj, Wj, Fm, vi_arr, depth_lim, lams, mcws,
+                        migs, mins_, lrs, mgrs, es_chunk, heap_depth,
                         int(e0.max_bins), obj, bf16, run_es, csr=csr,
                         skip_counts=skip_counts, seg_hist=seg,
                         default_dir=e0.sparse_default_direction,
                         dd_mask=dd, bundle_end=bend, acc_bf16=acc,
                         goss=goss, goss_seed=jnp.int32(e0.seed),
-                        chain_ids=jnp.arange(s0, s1, dtype=jnp.int32),
-                        round_offset=jnp.int32(n_rounds)))
+                        chain_ids=jnp.arange(S, dtype=jnp.int32),
+                        round_offset=jnp.int32(n_rounds))
+            else:
+                parts = []
+                for s0 in range(0, S, chunk):
+                    s1 = min(s0 + chunk, S)
+                    with launch("gbt_chain_rounds"):
+                        parts.append(_gbt_chain_rounds_jit(
+                            binned, yj, Wj[s0:s1], Fm[s0:s1], vi_arr,
+                            depth_lim[s0:s1], lams[s0:s1], mcws[s0:s1],
+                            migs[s0:s1], mins_[s0:s1], lrs[s0:s1],
+                            mgrs[s0:s1], es_chunk, heap_depth,
+                            int(e0.max_bins), obj, bf16, run_es, csr=csr,
+                            skip_counts=skip_counts, seg_hist=seg,
+                            default_dir=e0.sparse_default_direction,
+                            dd_mask=dd, bundle_end=bend, acc_bf16=acc,
+                            goss=goss, goss_seed=jnp.int32(e0.seed),
+                            chain_ids=jnp.arange(s0, s1, dtype=jnp.int32),
+                            round_offset=jnp.int32(n_rounds)))
                 Fm = jnp.concatenate([p[0] for p in parts])
                 fs = jnp.concatenate([p[1] for p in parts], axis=1)
                 ts = jnp.concatenate([p[2] for p in parts], axis=1)
@@ -1110,9 +1110,10 @@ class GBTGridGroup(TreeGridGroup):
             binned_sc = binned
         scores = []
         for s in range(S_val):
-            count_launch("gbt_chain_score")
-            raw = predict_ensemble(binned_sc, feats_all[s], threshs_all[s],
-                                   leaves_m[s], heap_depth)[:, 0]
+            with launch("gbt_chain_score"):
+                raw = predict_ensemble(
+                    binned_sc, feats_all[s], threshs_all[s], leaves_m[s],
+                    heap_depth)[:, 0]
             z = raw + base_j[s]
             scores.append(jax.nn.sigmoid(z) if obj == "binary" else z)
         scores = jnp.stack(scores).reshape(C, F, n).transpose(1, 0, 2)

@@ -678,9 +678,10 @@ class ModelSelector(PredictorEstimator):
         result to the tree group — or blocks it until ready — so there is
         no duplicated sketch work."""
         import threading
-        import time as _time
 
         from ..models.trees import _prep_tree_inputs_sparse
+        from ..obs.trace import current_span
+        from ..obs.trace import span as _span
 
         if self.mesh is not None or X.size < self._PREFETCH_MIN_ELEMS:
             return None
@@ -690,22 +691,20 @@ class ModelSelector(PredictorEstimator):
         if not bins:
             return None
 
-        from ..utils.profiling import current_collector
-        coll = current_collector()   # collector is thread-local: capture now
         cancel = threading.Event()
+        # the span stack is thread-local: the thread's spans hang under the
+        # span current HERE, where it is started
+        parent = current_span()
 
         def work():
-            t0 = _time.perf_counter()
-            for mb in bins:
-                if cancel.is_set():   # elastic teardown: stop between bins
-                    return
-                try:
-                    _prep_tree_inputs_sparse(X, mb)
-                except Exception:   # prep errors surface on the sweep path
-                    return
-            if coll is not None:
-                coll.metrics.custom_tags["prefetchTreePrepSecs"] = round(
-                    _time.perf_counter() - t0, 3)
+            with _span("tree.prep.prefetch", cat="prep", parent=parent):
+                for mb in bins:
+                    if cancel.is_set():   # elastic teardown: stop here
+                        return
+                    try:
+                        _prep_tree_inputs_sparse(X, mb)
+                    except Exception:   # prep errors surface on the sweep
+                        return
 
         t = threading.Thread(target=work, name="tree-prep-prefetch",
                              daemon=True)
@@ -746,25 +745,30 @@ class ModelSelector(PredictorEstimator):
                     features_col: FeatureColumn):
         # cost-model bucket refinement (workflow/plan.py reads it): a
         # halving sweep's wall follows a different law than a full sweep's
+        from ..obs.trace import span as _span
+
         self._cost_kind = ("fit-halving" if self.strategy == "halving"
                            else None)
-        X = self._prepare_matrix(features_col.values)
-        y = np.nan_to_num(np.asarray(label_col.values, dtype=np.float32))
-        n = len(y)
-        self._capture_class_space(y)
-        splitter = self._resolved_splitter()
-        train_idx, holdout_idx = splitter.split_indices(n, y)
-        train_mask = np.zeros(n, dtype=bool)
-        train_mask[train_idx] = True
-        base_w = splitter.train_weights(y, train_mask)
+        with _span("selector.prepare", cat="selector"):
+            X = self._prepare_matrix(features_col.values)
+            y = np.nan_to_num(np.asarray(label_col.values,
+                                         dtype=np.float32))
+            n = len(y)
+            self._capture_class_space(y)
+            splitter = self._resolved_splitter()
+            train_idx, holdout_idx = splitter.split_indices(n, y)
+            train_mask = np.zeros(n, dtype=bool)
+            train_mask[train_idx] = True
+            base_w = splitter.train_weights(y, train_mask)
 
-        # ``parallel=`` dispatch: resolve an int/"auto" request into a
-        # ("data", "grid") sweep mesh for THIS fit only (with_mesh wins,
-        # and the attribute is restored on the way out — the same scoping
-        # contract the workflow applies to with_mesh)
-        queue_width = sum(len(g) for _, g in self.models_and_params)
-        prev_mesh = self.mesh
-        self.mesh = self._resolve_parallel(n, int(X.shape[1]), queue_width)
+            # ``parallel=`` dispatch: resolve an int/"auto" request into a
+            # ("data", "grid") sweep mesh for THIS fit only (with_mesh
+            # wins, and the attribute is restored on the way out — the
+            # same scoping contract the workflow applies to with_mesh)
+            queue_width = sum(len(g) for _, g in self.models_and_params)
+            prev_mesh = self.mesh
+            self.mesh = self._resolve_parallel(n, int(X.shape[1]),
+                                               queue_width)
         try:
             return self._fit_columns_inner(
                 X, y, n, splitter, train_mask, holdout_idx, base_w)
@@ -777,6 +781,8 @@ class ModelSelector(PredictorEstimator):
 
     def _fit_columns_inner(self, X, y, n, splitter, train_mask,
                            holdout_idx, base_w):
+        from ..obs.trace import span as _span
+
         # a mesh-padded device matrix (the streaming→sharded ingest
         # hand-off) carries pad rows: labels/weights pad with ZEROS so the
         # pad rows are inert through every weighted fit and metric
@@ -814,14 +820,16 @@ class ModelSelector(PredictorEstimator):
 
             candidates = self._candidates(with_groups=False)
             ckpt = self._sweep_checkpoint(candidates, n, elastic=elastic)
-            best_i, results, schedule = halving_validate(
-                self.validator, candidates, X, y_v, base_w_v,
-                eval_fn=self._metric, metric_name=self.validation_metric,
-                larger_better=self.larger_better, config=self.halving,
-                stratify=self.problem_type != "regression",
-                checkpoint=ckpt,
-                regroup=self._make_rung_regroup(candidates),
-                elastic=elastic)
+            with _span("selector.validate", cat="selector"):
+                best_i, results, schedule = halving_validate(
+                    self.validator, candidates, X, y_v, base_w_v,
+                    eval_fn=self._metric,
+                    metric_name=self.validation_metric,
+                    larger_better=self.larger_better, config=self.halving,
+                    stratify=self.problem_type != "regression",
+                    checkpoint=ckpt,
+                    regroup=self._make_rung_regroup(candidates),
+                    elastic=elastic)
             if ckpt is not None:
                 ckpt.finish()
             self.metadata["halving_schedule"] = schedule
@@ -832,11 +840,13 @@ class ModelSelector(PredictorEstimator):
             self._start_tree_prep_prefetch(X)
             candidates = self._candidates()
             ckpt = self._sweep_checkpoint(candidates, n, elastic=elastic)
-            best_i, results = self.validator.validate(
-                candidates, X, y_v, base_w_v,
-                eval_fn=self._metric, metric_name=self.validation_metric,
-                larger_better=self.larger_better, checkpoint=ckpt,
-                elastic=elastic)
+            with _span("selector.validate", cat="selector"):
+                best_i, results = self.validator.validate(
+                    candidates, X, y_v, base_w_v,
+                    eval_fn=self._metric,
+                    metric_name=self.validation_metric,
+                    larger_better=self.larger_better, checkpoint=ckpt,
+                    elastic=elastic)
             if ckpt is not None:
                 ckpt.finish()
             best_name, best_params, *rest = candidates[best_i]
@@ -859,41 +869,46 @@ class ModelSelector(PredictorEstimator):
         # the fitters; nothing outside the winner's family shares its
         # growth program).
         best_model = None
-        if best_group is not None and not elastic.groups_invalid:
-            # (a mid-sweep mesh shrink invalidates group refit artifacts —
-            # their device arrays target the dead mesh; refit sequentially)
-            try:
-                row = best_group.grid_points.index(best_params)
-            except ValueError:
-                row = None
-            if row is not None:
-                best_model = best_group.refit_model(row)
-        if best_model is None:
-            best_proto = next(p for p, _ in self.models_and_params
-                              if type(p).__name__ == best_name)
-            best_est = best_proto.copy(**best_params)
-            if self.mesh is not None and hasattr(best_est, "with_mesh"):
-                best_est.with_mesh(self.mesh)
-            best_model = best_est.fit_raw(X, y_v, base_w_v)
+        with _span("selector.refit", cat="selector", model=best_name):
+            if best_group is not None and not elastic.groups_invalid:
+                # (a mid-sweep mesh shrink invalidates group refit
+                # artifacts — their device arrays target the dead mesh;
+                # refit sequentially)
+                try:
+                    row = best_group.grid_points.index(best_params)
+                except ValueError:
+                    row = None
+                if row is not None:
+                    best_model = best_group.refit_model(row)
+            if best_model is None:
+                best_proto = next(p for p, _ in self.models_and_params
+                                  if type(p).__name__ == best_name)
+                best_est = best_proto.copy(**best_params)
+                if self.mesh is not None and hasattr(best_est, "with_mesh"):
+                    best_est.with_mesh(self.mesh)
+                best_model = best_est.fit_raw(X, y_v, base_w_v)
 
         # ONE batched predict over the full matrix (hits the sweep's binning
         # and upload memos) — slicing rows first would re-bin and re-upload
         # a fresh holdout matrix per metric set
-        full_batch = best_model.predict_batch(X)
-        train_metrics = self._full_metrics(full_batch, y, train_mask)
-        holdout_metrics = (
-            self._full_metrics(full_batch, y, ~train_mask)
-            if len(holdout_idx) else {})
+        with _span("selector.predict", cat="selector"):
+            full_batch = best_model.predict_batch(X)
+        with _span("selector.metrics", cat="selector"):
+            train_metrics = self._full_metrics(full_batch, y, train_mask)
+            holdout_metrics = (
+                self._full_metrics(full_batch, y, ~train_mask)
+                if len(holdout_idx) else {})
 
-        summary = ModelSelectorSummary(
-            validation_results=results, best_model_name=best_name,
-            best_params=best_params,
-            validation_type=type(self.validator).__name__,
-            holdout_metrics=holdout_metrics, train_metrics=train_metrics,
-            splitter_summary=(splitter.summary.to_json()
-                              if splitter.summary else None),
-            problem_type=self.problem_type)
-        self.metadata["model_selector_summary"] = summary.to_json()
+            summary = ModelSelectorSummary(
+                validation_results=results, best_model_name=best_name,
+                best_params=best_params,
+                validation_type=type(self.validator).__name__,
+                holdout_metrics=holdout_metrics,
+                train_metrics=train_metrics,
+                splitter_summary=(splitter.summary.to_json()
+                                  if splitter.summary else None),
+                problem_type=self.problem_type)
+            self.metadata["model_selector_summary"] = summary.to_json()
         selected = SelectedModel(inner=best_model, best_name=best_name,
                                  best_params=best_params)
         return selected

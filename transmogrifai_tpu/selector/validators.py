@@ -513,9 +513,14 @@ class SweepWorkQueue:
             for k in range(i, j):
                 faults.fire("device.loss", index=self.units[k].index,
                             tag=self.units[k].name)
+            # the second span carries the estimator family in its NAME:
+            # a reader that is handed names and intervals only can then
+            # tell the XGB group from the RF group
             with _span(f"sweep.group[{i}:{j}]", cat="sweep",
                        group=type(group).__name__, units=j - i,
-                       mesh=_mesh_attr(elastic)):
+                       mesh=_mesh_attr(elastic)), \
+                    _span(f"sweep.group:{type(group.proto).__name__}",
+                          cat="sweep"):
                 return self._run_group(group)
         except Exception as e:  # noqa: BLE001 - fall back per-candidate,
             # routed through the shared device-loss classifier

@@ -20,10 +20,13 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from ..obs.trace import begin_span, end_span
+
 __all__ = ["OpStep", "MetricsCollector", "AppMetrics", "StepMetrics",
            "with_job_group", "current_collector", "install_collector",
            "profile_to", "RunCounters", "COUNTERS", "reset_counters",
            "count_upload", "count_fetch", "count_drain", "count_launch",
+           "launch", "count_memo",
            "fetch_timed", "StageProfile", "PlanProfiler",
            "IngestPass", "IngestProfiler", "LintSnapshot", "backend_name",
            "mesh_desc"]
@@ -200,6 +203,12 @@ class RunCounters:
     drain_tags: Dict[str, float] = field(default_factory=dict)
     launches: int = 0
     launch_tags: Dict[str, int] = field(default_factory=dict)
+    #: sweep-memo accounting (``models.trees._memo``): per memo kind
+    #: (``edges``, ``bins``, ``efb``, the ``_dev_memo`` tags, ...) how many
+    #: probes found the value (``hits``), built it (``builds``) or waited
+    #: for a build in flight on another thread (``waits``) — the work a
+    #: train redoes, as a count
+    memo_tags: Dict[str, Dict[str, int]] = field(default_factory=dict)
     #: elastic-sweep accounting (parallel/elastic.py mirrors its per-sweep
     #: ElasticCounters here): retries / mesh_shrinks / mesh_repacks /
     #: quarantined / watchdog_fires / device_losses
@@ -223,6 +232,7 @@ class RunCounters:
             "drainTags": {k: round(v, 3) for k, v in self.drain_tags.items()},
             "launches": self.launches,
             "launchTags": dict(self.launch_tags),
+            "memoTags": {k: dict(v) for k, v in self.memo_tags.items()},
             "elastic": dict(self.elastic),
             "refresh": dict(self.refresh),
         }
@@ -285,6 +295,28 @@ def count_launch(tag: str, n: int = 1) -> None:
     with _COUNTERS_LOCK:
         COUNTERS.launches += n
         COUNTERS.launch_tags[tag] = COUNTERS.launch_tags.get(tag, 0) + n
+
+
+@contextlib.contextmanager
+def launch(tag: str):
+    """``count_launch(tag)`` and, while a tracer is armed, a ``launch:<tag>``
+    span round the dispatching call in the block: the host seconds it takes
+    to enqueue the program (which hold a ``jax.jit`` built anew)."""
+    count_launch(tag)
+    sp = begin_span(f"launch:{tag}", cat="launch")
+    try:
+        yield
+    finally:
+        end_span(sp)
+
+
+def count_memo(kind: str, outcome: str) -> None:
+    """One probe of the sweep memo: ``outcome`` is ``hits``, ``builds`` or
+    ``waits``."""
+    with _COUNTERS_LOCK:
+        tags = COUNTERS.memo_tags.setdefault(
+            kind, {"hits": 0, "builds": 0, "waits": 0})
+        tags[outcome] += 1
 
 
 def count_elastic(kind: str, n: int = 1) -> None:
