@@ -38,7 +38,7 @@ REACHED = [
     "sweep.group:OpXGBoostClassifier",
     "sweep.group:OpRandomForestClassifier",
     "tree.prep.hash", "tree.prep.sketch", "tree.prep.bin",
-    "tree.prep.upload", "tree.prep.bundle",
+    "tree.prep.upload", "tree.prep.bundle", "launch:device_bin",
     "launch:gbt_chain_rounds", "launch:gbt_chain_score",
     "launch:rf_grid_chunk", "launch:gbt_rounds"]
 

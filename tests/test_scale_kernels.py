@@ -1,4 +1,4 @@
-"""Scale-path kernels: streamed (row-blocked) histograms, host binning.
+"""Scale-path kernels: streamed (row-blocked) histograms, device binning.
 
 SURVEY §7 step 9 / hard part (a): the histogram build must stream rows once
 data outgrows the hoisted one-hot (1M×500×32 bins = 64 GB if materialized).
@@ -7,9 +7,11 @@ import numpy as np
 import pytest
 
 import transmogrifai_tpu.models.gbdt_kernels as gk
+from transmogrifai_tpu.models import trees as tr
 from transmogrifai_tpu.models.trees import (
-    _host_bins, _prep_tree_inputs, OpRandomForestClassifier,
+    _prep_tree_inputs, OpRandomForestClassifier,
 )
+from transmogrifai_tpu.utils import profiling
 
 
 @pytest.fixture
@@ -92,22 +94,146 @@ class TestSiblingSubtraction:
         assert np.max(np.abs(l_sib - l_dir)) < 1e-5
 
 
-class TestHostBinning:
-    def test_host_equals_device_binning(self):
-        import jax.numpy as jnp
+def _searchsorted_bins(X, edges):
+    """The reference, kept here: per column, ``searchsorted`` (left) of the
+    f32 values in the sorted f32 edges, NaN pinned to bin 0."""
+    X = np.asarray(X, np.float32)
+    edges = np.asarray(edges, np.float32)
+    out = np.empty(X.shape, np.int64)
+    for j in range(X.shape[1]):
+        b = np.searchsorted(np.sort(edges[j]), X[:, j], side="left")
+        out[:, j] = np.where(np.isnan(X[:, j]), 0, b)
+    return out
 
-        rng = np.random.default_rng(0)
-        X = rng.normal(size=(2000, 5)).astype(np.float32)
-        X[:, 2] = np.round(X[:, 2])            # duplicate edges -> +inf
-        edges = gk.quantile_bins(X, 32)
-        dev = np.asarray(gk.apply_bins(jnp.asarray(X),
-                                       jnp.asarray(edges, np.float32)))
-        host = _host_bins(X, edges)
-        assert (dev == host.astype(np.int32)).all()
 
-    def test_prep_switches_to_int8_for_big_input(self, monkeypatch):
-        from transmogrifai_tpu.models import trees as tr
-        monkeypatch.setattr(tr, "_HOST_BIN_ELEMS", 100)
+def _awkward_matrix(n, d=6, seed=0):
+    """Normal draws with a low-cardinality column (duplicate quantiles ->
+    ``+inf`` sentinel edges), NaN, both infinities, and values that sit
+    exactly on an edge; returns the matrix and its 32-bin edges."""
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, d)).astype(np.float32)
+    X[:, 2] = np.round(X[:, 2])
+    edges = gk.quantile_bins(X, 32)
+    assert np.isinf(edges[2]).any()            # the sentinels are there
+    X[1::97, 0] = np.nan
+    X[2::89, 1] = np.inf
+    X[3::83, 1] = -np.inf
+    for k in range(0, edges.shape[1], 3):      # exactly on an edge
+        X[(5 + 7 * k) % n, 4] = edges[4, k]
+    X[n - 1, 5] = np.nan                       # the very last row, too
+    return X, edges
+
+
+class TestDeviceBinning:
+    """``trees._device_bins``: the one binning path, on the device, walked
+    in row blocks (ISSUE 25)."""
+
+    @pytest.fixture(autouse=True)
+    def _fresh(self):
+        tr.clear_sweep_caches()
+        tr._bin_block_into._clear_cache()
+        yield
+        tr.clear_sweep_caches()
+        tr._bin_block_into._clear_cache()
+
+    @pytest.mark.parametrize("n,block_rows,launches", [
+        (1000, 32768, 1),       # one block, the matrix itself
+        (1024, 128, 8),         # several, the rows a multiple of the block
+        (1000, 128, 8),         # a tail of 104 rows: the last block overlaps
+        (1000, 999, 2),         # a tail of one row
+        (129, 128, 2),
+        (128, 128, 1),
+        (1, 128, 1),
+    ])
+    def test_blocks_equal_searchsorted(self, monkeypatch, n, block_rows,
+                                       launches):
+        import jax
+
+        monkeypatch.setattr(gk, "ROW_BLOCK", block_rows)
+        X, edges = _awkward_matrix(max(n, 200))
+        X = np.ascontiguousarray(X[:n])
+        profiling.reset_counters()
+        got = tr._device_bins(X, edges)
+        assert isinstance(got, jax.Array)      # the result is on the device
+        assert got.dtype == np.int8 and got.shape == X.shape
+        np.testing.assert_array_equal(np.asarray(got).astype(np.int64),
+                                      _searchsorted_bins(X, edges))
+        tags = profiling.COUNTERS.to_json()["launchTags"]
+        assert tags == {"device_bin": launches}
+        # ONE program for all the blocks of a shape
+        assert tr._bin_block_into._cache_size() == 1
+
+    @pytest.mark.parametrize("value,column,want", [
+        ("nan", 0, "zero"), ("neginf", 0, "zero"), ("posinf", 0, "finite"),
+        # the low-cardinality column's +inf sentinels never trigger, not
+        # even for +inf itself
+        ("posinf", 2, "finite"), ("below_all", 3, "zero"),
+        ("above_all", 3, "finite"),
+    ])
+    def test_special_values(self, value, column, want):
+        X, edges = _awkward_matrix(500)
+        x = {"nan": np.nan, "neginf": -np.inf, "posinf": np.inf,
+             "below_all": -1e30, "above_all": 1e30}[value]
+        X[7, column] = x
+        got = np.asarray(tr._device_bins(X, edges))
+        n_finite = int(np.isfinite(edges[column]).sum())
+        assert got[7, column] == (0 if want == "zero" else n_finite)
+        assert got.max() <= edges.shape[1]
+
+    def test_value_on_an_edge_takes_the_bin_below_it(self):
+        """Count of edges STRICTLY below x: x == edges[k] lands in bin k,
+        the next f32 above it in bin k + 1."""
+        X, edges = _awkward_matrix(500)
+        k = 4
+        X[0, 4] = edges[4, k]
+        X[1, 4] = np.nextafter(edges[4, k], np.float32(np.inf))
+        got = np.asarray(tr._device_bins(X, edges))
+        assert (got[0, 4], got[1, 4]) == (k, k + 1)
+
+    def test_int32_result_from_127_edges_up(self, monkeypatch):
+        monkeypatch.setattr(gk, "ROW_BLOCK", 64)
+        rng = np.random.default_rng(5)
+        X = rng.normal(size=(300, 3)).astype(np.float32)
+        edges = gk.quantile_bins(X, 200)
+        got = tr._device_bins(X, edges)
+        assert got.dtype == np.int32
+        assert np.asarray(got).max() > 127
+        np.testing.assert_array_equal(np.asarray(got),
+                                      _searchsorted_bins(X, edges))
+
+    def test_empty_matrix(self):
+        _, edges = _awkward_matrix(200)
+        got = tr._device_bins(np.zeros((0, 6), np.float32), edges)
+        assert got.shape == (0, 6) and got.dtype == np.int8
+
+    def test_second_build_of_a_shape_builds_no_program(self, monkeypatch):
+        """What the warm-up train built serves the window's trains: the
+        memo is dropped between trains, the programs are not."""
+        from transmogrifai_tpu import obs
+
+        monkeypatch.setattr(gk, "ROW_BLOCK", 128)
+        X, edges = _awkward_matrix(1000)
+        first = np.asarray(_prep_tree_inputs(X, 32)[1])
+        tr.clear_sweep_caches()
+        profiling.reset_counters()
+        with obs.tracing(capture_hlo=False) as tracer:
+            again = np.asarray(_prep_tree_inputs(X, 32)[1])
+        np.testing.assert_array_equal(first, again)
+        spans = tracer.snapshot()
+        assert not [s.name for s in spans
+                    if s.name.startswith(("jit.lower:", "jit.compile:"))]
+        assert profiling.COUNTERS.to_json()["memoTags"]["bins"] == {
+            "hits": 0, "builds": 1, "waits": 0}
+        # the block launches and uploads are children of the build's span
+        (build,) = [s for s in spans if s.name == "tree.prep.bin"]
+        launches = [s for s in spans if s.name == "launch:device_bin"]
+        assert len(launches) == 8
+        assert all(s.parent_id == build.span_id for s in launches)
+        uploads = [s for s in spans if s.name == "tree.prep.upload"
+                   and s.parent_id == build.span_id]
+        assert len(uploads) == 8
+
+    def test_prep_bins_int8_at_any_size_and_trains(self):
         rng = np.random.default_rng(2)
         X = rng.normal(size=(300, 4)).astype(np.float32)
         _, binned = _prep_tree_inputs(X, 32)
@@ -117,6 +243,37 @@ class TestHostBinning:
         m = OpRandomForestClassifier(num_trees=5, max_depth=3,
                                      seed=3).fit_raw(X, y)
         assert np.isfinite(np.asarray(m.predict_batch(X).probability)).all()
+
+    def test_resident_f32_matrix_is_binned_where_it_lies(self):
+        """The exact ``X_f32`` upload of the sweep is binned in one launch,
+        without a second upload, to the same bins."""
+        X, edges = _awkward_matrix(700)
+        tr._dev_f32(X)
+        profiling.reset_counters()
+        got = tr._binned_for_edges(X, edges)
+        counters = profiling.COUNTERS.to_json()
+        assert counters["launchTags"] == {"device_bin": 1}
+        assert counters["uploadBytes"] == 0
+        assert got.dtype == np.int8
+        np.testing.assert_array_equal(np.asarray(got).astype(np.int64),
+                                      _searchsorted_bins(X, edges))
+
+    def test_a_bf16_copy_is_never_binned(self):
+        """The same table bins the same whichever models share the
+        selector: a linear group's bf16 upload is left alone."""
+        import jax.numpy as jnp
+
+        X, edges = _awkward_matrix(700)
+        Xf = tr._as_f32(X)
+        tr._memo(("X_bf16", tr._content_hash(Xf), Xf.shape),
+                 lambda: jnp.zeros(Xf.shape, jnp.bfloat16))
+        got = tr._binned_for_edges(X, edges)
+        np.testing.assert_array_equal(np.asarray(got).astype(np.int64),
+                                      _searchsorted_bins(X, edges))
+
+    def test_no_host_binning_path_is_left(self):
+        assert not hasattr(tr, "_host_bins")
+        assert not hasattr(tr, "_HOST_BIN_ELEMS")
 
 
 class TestXGBoostGammaSemantics:

@@ -228,7 +228,7 @@ def quantile_bins_sparse_aware(X: np.ndarray, max_bins: int = 32,
     for j in range(d):
         col = X[:, j]
         # NaN entries are excluded from the sketch (the binning convention
-        # pins NaN to bin 0 — trees._host_bins); nanquantile keeps a
+        # pins NaN to bin 0 — trees._device_bins); nanquantile keeps a
         # NaN-containing feature from poisoning every edge
         nz = col[(col != 0) & ~np.isnan(col)]
         if len(nz) and 1.0 - len(nz) / n >= SPARSE_SKETCH_ZERO_FRAC:
@@ -283,7 +283,7 @@ def build_feature_csr(X: np.ndarray, edges: np.ndarray
         vals = X[idx, j].astype(np.float32)
         b = np.searchsorted(e, vals, side="left").astype(np.int8)
         # NaN entries (counted as "nonzero" by the mask) follow the dense
-        # binning convention: pinned to bin 0 (trees._host_bins) so the
+        # binning convention: pinned to bin 0 (trees._device_bins) so the
         # histogram credits them where routing actually sends them
         bins[j, :len(idx)] = np.where(np.isnan(vals), np.int8(0), b)
     zero_bin = np.asarray(

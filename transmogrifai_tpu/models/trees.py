@@ -79,7 +79,7 @@ class TreeEnsembleModel(PredictorModel):
             return native.predict_ensemble(
                 binned, np.asarray(self.feat), np.asarray(self.thresh),
                 np.asarray(self.leaf), depth)
-        # memoized binning: big matrices quantize on host and upload int8
+        # memoized binning, on the device (row blocks past ROW_BLOCK rows)
         binned = _binned_for_edges(X, self.edges)
         feat = jnp.asarray(self.feat, jnp.int32)
         thresh = jnp.asarray(self.thresh, jnp.int32)
@@ -396,11 +396,12 @@ def _dev_f32(X, tag: str = "X_f32"):
     """THE shared device upload of a host matrix.
 
     Every consumer of the full matrix (linear-model fits, device
-    standardization stats, on-device quantile binning, SanityChecker-scale
-    stats) goes through this one memo, so a selector sweep uploads the
-    GB-scale matrix exactly once per train.  Large matrices
-    (``_BF16_UPLOAD_ELEMS``) upload as bf16 and consumers upcast on
-    device; small ones stay exact f32.
+    standardization stats, SanityChecker-scale stats) goes through this one
+    memo, so a selector sweep uploads the GB-scale matrix exactly once per
+    train.  Large matrices (``_BF16_UPLOAD_ELEMS``) upload as bf16 and
+    consumers upcast on device; small ones stay exact f32.  Tree binning
+    (``_binned_cached``) is a reader of the exact f32 entry only: it bins
+    that copy where it lies, and never the bf16 one.
 
     This applies to the sweep AND to big-matrix refits/scoring of the
     winning linear model — a deliberate trade (bf16 keeps f32's exponent
@@ -443,10 +444,59 @@ def _dev_memo_sharded(arr, sharding, tag: str = "up"):
 
 @jax.jit
 def _apply_bins_i8(X: jnp.ndarray, edges: jnp.ndarray) -> jnp.ndarray:
-    """On-device quantization to int8 (B <= 127), for when the matrix is
-    already device-resident: skips the host binning pass AND the int8 upload."""
-    X = X.astype(jnp.float32)
+    """On-device quantization to int8 (B <= 127) of a matrix that is
+    already device-resident in f32: one launch, no upload."""
     return jnp.sum(X[:, :, None] > edges[None, :, :], axis=2).astype(jnp.int8)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _bin_block_into(out, x_blk, edges, start):
+    """Quantize one f32 row block and write it into ``out`` at row
+    ``start``: the compare-and-count fuses into the update of the donated
+    result, so a block costs one read of its f32 rows and one write of its
+    bins.  ``start`` is traced: ONE program serves every block of a matrix
+    shape.  Also returns one element of the block's bins, the walk's token
+    that this block's f32 rows have been consumed."""
+    b = jnp.sum(x_blk[:, :, None] > edges[None, :, :],
+                axis=2).astype(out.dtype)
+    return jax.lax.dynamic_update_slice(out, b, (start, 0)), b[0, 0]
+
+
+def _device_bins(Xf: np.ndarray, ef: np.ndarray):
+    """Quantize a HOST f32 matrix on the device, block by block.
+
+    Count of ``edges < x`` on f32 against f32: exactly
+    ``np.searchsorted(sorted edges, x, "left")``; NaN compares false and
+    lands in bin 0, ``+inf`` sentinel edges never trigger.  The matrix is
+    walked in ``gbdt_kernels.ROW_BLOCK`` rows (65 MB of f32 at 500
+    columns): each block is placed, binned by ``_bin_block_into`` and
+    dropped, with at most two blocks in flight (the next block's upload
+    overlaps this one's launch), so the f32 matrix is never resident; the
+    int8 result (int32 from 127 edges up) is.  A tail shorter than a block
+    is covered by a last block that ENDS at the last row and overlaps its
+    predecessor (the same bins twice), so no block is padded or copied on
+    the host and every block has the one shape.  The block rows depend on
+    the shape alone: a warm-up train builds every program of the walk (the
+    result's ``zeros`` and the block program)."""
+    from ..utils.profiling import launch
+    from . import gbdt_kernels
+
+    n, d = Xf.shape
+    out = jnp.zeros((n, d), jnp.int8 if ef.shape[1] < 127 else jnp.int32)
+    if n == 0:
+        return out
+    edges = jnp.asarray(ef)
+    rb = min(int(gbdt_kernels.ROW_BLOCK), n)
+    starts = list(range(0, n - rb, rb)) + [n - rb]
+    tokens = []
+    for i, s in enumerate(starts):
+        if i >= 2:
+            tokens[i - 2].block_until_ready()
+        x_blk = _upload_timed(Xf[s:s + rb])   # its own span, and the bytes
+        with launch("device_bin"):
+            out, tok = _bin_block_into(out, x_blk, edges, s)
+        tokens.append(tok)
+    return out
 
 
 def _binned_for_edges(X, edges):
@@ -460,53 +510,29 @@ def _binned_for_edges(X, edges):
 
 
 def _binned_cached(Xf: np.ndarray, hx: str, edges):
+    """THE binned matrix of (matrix content, edges content): int8 on the
+    device (int32 from 127 edges up), binned ON the device whatever the
+    size, by every tree family's fit, the grid groups and the scoring path.
+
+    A matrix that the sweep's shared upload already holds in exact f32
+    (``_dev_f32``'s ``X_f32`` memo) is binned where it lies in one launch;
+    any other is walked in row blocks (``_device_bins``).  Both count f32
+    values against f32 edges, so the bins do not depend on which models
+    share the selector: the bf16 copy that ``_dev_f32`` keeps of a large
+    matrix is never binned (a value within bf16 rounding of an edge would
+    change bins)."""
     ef = np.ascontiguousarray(np.asarray(edges, np.float32))
     key = ("bins", hx, _content_hash(ef), Xf.shape)
 
     def build():
-        with _span("tree.prep.bin", cat="prep"):
-            if not (Xf.size > _HOST_BIN_ELEMS and ef.shape[1] < 127):
-                return apply_bins(jnp.asarray(Xf), jnp.asarray(ef))
-            # reuse the sweep's shared upload when present: device binning
-            # is one launch vs a ~10 s/1M-row host pass + a second upload.
-            # (Binning the bf16 copy can flip values that sit within bf16
-            # rounding of an edge — immaterial to quantile-bin trees.)
-            # explicit None test: `or` would ask the device array for truth
-            xdev = _memo_peek(("X_bf16", hx, Xf.shape))
-            if xdev is None:
-                xdev = _memo_peek(("X_f32", hx, Xf.shape, "float32"))
-            if xdev is not None:
-                from ..utils.profiling import launch
-                with launch("device_bin"):
-                    return _apply_bins_i8(xdev, jnp.asarray(ef))
-            host = _host_bins(Xf, ef)
-        return _upload_timed(host)    # its own span: tree.prep.upload
-    return _memo(key, build)
-
-
-_HOST_BIN_ELEMS = 1 << 22
-
-
-def _host_bins(Xf: np.ndarray, edges: np.ndarray) -> np.ndarray:
-    """Host-side quantization, uploaded as int8 (B <= 127).
-
-    At 1M×500 the device path uploads ~800 MB of f32 (X for apply_bins plus
-    the int32 result paid again on fetch-free reuse); binning on host and
-    shipping int8 uploads an eighth of the bytes.  Host-vs-device binning
-    is a choice a chip measurement must re-decide (ROADMAP Queue 3).
-    """
-    n, d = Xf.shape
-    out = np.empty((n, d), np.int8)
-    for j in range(d):
-        # apply_bins counts edges < x; searchsorted(left) on sorted edges
-        # (dedup +inf sentinels sort to the end) gives the same count.
-        # NaN sorts past +inf in searchsorted but compares False against
-        # every edge on device — pin it to bin 0 to match.
-        col = Xf[:, j]
-        b = np.searchsorted(np.sort(edges[j]), col,
-                            side="left").astype(np.int8)
-        out[:, j] = np.where(np.isnan(col), np.int8(0), b)
-    return out
+        xdev = _memo_peek(("X_f32", hx, Xf.shape, "float32"))
+        if xdev is None:
+            return _device_bins(Xf, ef)
+        from ..utils.profiling import launch
+        with launch("device_bin"):
+            fn = _apply_bins_i8 if ef.shape[1] < 127 else apply_bins
+            return fn(xdev, jnp.asarray(ef))
+    return _memo(key, build, span="tree.prep.bin")
 
 
 def _prep_tree_inputs(X, max_bins):
