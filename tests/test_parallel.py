@@ -241,3 +241,177 @@ class TestShardedProfile:
         assert fills_h.keys() == fills_m.keys()
         for k in fills_h:
             assert abs(fills_h[k] - fills_m[k]) < 1e-9
+
+
+# -- one preparation per train on a mesh (ISSUE 25) ---------------------------------
+
+class TestMeshFitJoinsTheSweepsPreparation:
+    """A selector train on a mesh prepares its matrix ONCE: the winner's
+    ``fit_raw`` finds the sweep's host sketch in the memo and with it the
+    sweep's binned matrix; only a fit with no sweep before it sketches over
+    the mesh."""
+
+    @pytest.fixture(scope="class")
+    def mesh4(self):
+        from transmogrifai_tpu.parallel.mesh import make_sweep_mesh
+
+        return make_sweep_mesh(1, n_devices=4)
+
+    @pytest.fixture(scope="class")
+    def trained(self, mesh4):
+        """The second, warm train of a one-group XGB selector on the mesh,
+        under the tracer, with what each preparation returned."""
+        from transmogrifai_tpu import (FeatureBuilder, OpWorkflow, models,
+                                       obs, transmogrify)
+        from transmogrifai_tpu.models import trees
+        from transmogrifai_tpu.selector import (
+            BinaryClassificationModelSelector, grid)
+        from transmogrifai_tpu.testkit import planted_linear_frame
+        from transmogrifai_tpu.utils import profiling
+
+        # 3002 rows: the last row is a training row of the seed-1 split (a
+        # trailing reserved row is the miss case below), and the rows do
+        # not tile the data axis
+        df = planted_linear_frame(3002, 8, 5)
+        label = FeatureBuilder.RealNN("label").as_response()
+        preds = [FeatureBuilder.Real(c).as_predictor() for c in df.columns
+                 if c != "label"]
+        selector = BinaryClassificationModelSelector.with_cross_validation(
+            num_folds=2, seed=1, models_and_parameters=[
+                (models.OpXGBoostClassifier(num_round=2),
+                 grid(max_depth=[3]))])
+        prediction = selector.set_input(
+            label, transmogrify(preds)).get_output()
+        wf = (OpWorkflow().set_result_features(prediction)
+              .set_input_data(df).with_mesh(mesh4))
+        wf.train()                      # builds the programs
+        seen = {"sweep": [], "refit": []}
+        patch = pytest.MonkeyPatch()
+        for name, kind in (("_prep_tree_inputs_weighted", "sweep"),
+                           ("_prep_tree_inputs_mesh", "refit")):
+            def recorder(*a, _fn=getattr(trees, name), _kind=kind, **kw):
+                out = _fn(*a, **kw)
+                seen[_kind].append(out)
+                return out
+            patch.setattr(trees, name, recorder)
+        try:
+            profiling.reset_counters()
+            with obs.tracing(capture_hlo=False) as tracer:
+                model = wf.train()
+        finally:
+            patch.undo()
+        return {"spans": tracer.snapshot(), "seen": seen, "model": model,
+                "memo": profiling.COUNTERS.to_json()["memoTags"],
+                "launches": profiling.COUNTERS.to_json()["launchTags"]}
+
+    def test_the_winner_is_refitted_by_fit_raw_on_the_mesh(self, trained):
+        assert len(trained["seen"]["sweep"]) == 1
+        assert len(trained["seen"]["refit"]) == 1
+        assert "gbt_chain_rounds_sharded" in trained["launches"]
+
+    def test_one_sketch_and_one_binning_a_train(self, trained):
+        memo = trained["memo"]
+        assert memo.get("edges_mesh", {"builds": 0})["builds"] == 0
+        assert memo["edges"]["builds"] == 1 and memo["edges"]["hits"] >= 1
+        assert memo["bins"]["builds"] == 1 and memo["bins"]["hits"] >= 1
+        sketches = [s for s in trained["spans"]
+                    if s.name == "tree.prep.sketch"]
+        bins = [s for s in trained["spans"] if s.name == "tree.prep.bin"]
+        assert len(sketches) == 1 and len(bins) == 1
+
+    def test_the_refit_grows_on_the_sweep_s_edges_and_bins(self, trained):
+        (sweep,), (refit,) = (trained["seen"]["sweep"],
+                              trained["seen"]["refit"])
+        assert refit[0] is sweep[0]          # the memo's own edges
+        assert refit[1] is sweep[1]          # and its binned matrix
+        stage = next(s for s in trained["model"].stages
+                     if hasattr(s, "inner"))
+        np.testing.assert_array_equal(np.asarray(stage.inner.edges),
+                                      np.asarray(sweep[0]))
+
+    def test_no_program_is_built_inside_the_refit(self, trained):
+        by_id = {s.span_id: s for s in trained["spans"]}
+        (refit,) = [s for s in trained["spans"]
+                    if s.name == "selector.refit"]
+
+        def inside(s):
+            while s.parent_id in by_id:
+                s = by_id[s.parent_id]
+                if s is refit:
+                    return True
+            return False
+
+        built = [s.name for s in trained["spans"]
+                 if s.name.startswith(("jit.lower:", "jit.compile:"))
+                 and inside(s)]
+        assert built == []
+        assert not [s.name for s in trained["spans"] if inside(s)
+                    and s.name in ("tree.prep.sketch", "tree.prep.bin")]
+
+    @pytest.mark.parametrize("family", ["xgb", "rf"])
+    def test_a_stand_alone_mesh_fit_sketches_over_the_mesh(self, mesh4,
+                                                           family):
+        from transmogrifai_tpu import models
+        from transmogrifai_tpu.models import trees
+        from transmogrifai_tpu.parallel.sharded import quantile_bins_sharded
+        from transmogrifai_tpu.utils import profiling
+
+        rng = np.random.default_rng(8)
+        X = rng.normal(size=(1001, 6)).astype(np.float32)
+        y = (X[:, 0] + X[:, 1] > 0).astype(np.float32)
+        est = (models.OpXGBoostClassifier(num_round=2, max_depth=3)
+               if family == "xgb" else
+               models.OpRandomForestClassifier(num_trees=2, max_depth=3))
+        trees.clear_sweep_caches()
+        profiling.reset_counters()
+        model = est.with_mesh(mesh4).fit_raw(X, y)
+        memo = profiling.COUNTERS.to_json()["memoTags"]
+        assert memo["edges_mesh"]["builds"] == 1 and "edges" not in memo
+        assert memo["bins"]["builds"] == 1
+        np.testing.assert_array_equal(
+            np.asarray(model.edges),
+            quantile_bins_sharded(X, mesh4, est.max_bins))
+        trees.clear_sweep_caches()
+
+    def test_a_sweep_that_sketched_a_truncated_matrix_is_a_miss(self, mesh4):
+        """Trailing zero-weight rows make the sweep sketch the rows before
+        them, under that matrix's hash: the mesh fit of the whole matrix
+        does not find it and sketches over the mesh, as before."""
+        from transmogrifai_tpu.models import trees
+        from transmogrifai_tpu.utils import profiling
+
+        rng = np.random.default_rng(10)
+        X = rng.normal(size=(1001, 6)).astype(np.float32)
+        w = np.ones(1001, np.float32)
+        w[-3:] = 0.0
+        trees.clear_sweep_caches()
+        swept, _, _ = trees._prep_tree_inputs_weighted(X, 32, row_weight=w)
+        profiling.reset_counters()
+        edges, _ = trees._prep_tree_inputs_mesh(X, 32, mesh4)
+        memo = profiling.COUNTERS.to_json()["memoTags"]
+        assert memo["edges_mesh"]["builds"] == 1 and "edges" not in memo
+        assert edges is not swept
+        trees.clear_sweep_caches()
+
+    def test_a_host_sketch_in_the_memo_is_what_a_mesh_fit_takes(self,
+                                                                mesh4):
+        """What the code observes is the memo's content: the same fit
+        after a host preparation of the same matrix takes that one."""
+        from transmogrifai_tpu import models
+        from transmogrifai_tpu.models import trees
+        from transmogrifai_tpu.utils import profiling
+
+        rng = np.random.default_rng(9)
+        X = rng.normal(size=(1001, 6)).astype(np.float32)
+        y = (X[:, 0] > 0).astype(np.float32)
+        trees.clear_sweep_caches()
+        edges, binned, _ = trees._prep_tree_inputs_weighted(X, 32)
+        profiling.reset_counters()
+        model = (models.OpXGBoostClassifier(num_round=2, max_depth=3)
+                 .with_mesh(mesh4).fit_raw(X, y))
+        memo = profiling.COUNTERS.to_json()["memoTags"]
+        assert "edges_mesh" not in memo
+        assert memo["edges"] == {"hits": 1, "builds": 0, "waits": 0}
+        assert memo["bins"] == {"hits": 1, "builds": 0, "waits": 0}
+        assert model.edges is edges
+        trees.clear_sweep_caches()

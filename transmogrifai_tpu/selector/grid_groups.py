@@ -370,17 +370,14 @@ class TreeGridGroup(GridGroup):
         return (int(mesh.shape[mesh.axis_names[0]]),
                 int(mesh.shape[mesh.axis_names[1]]))
 
-    def _sharded_matrix(self, binned, tag: str):
+    def _sharded_matrix(self, binned):
         """Row-pad a (device or host) binned matrix to tile the data axis
-        and commit it ``P("data", None)`` — content-memoized like every
-        other sweep upload."""
-        from ..models.trees import _dev_memo_sharded
-        from ..parallel.mesh import pad_to_multiple, sweep_matrix_sharding
+        and commit it ``P("data", None)`` — content-memoized under ONE tag,
+        so groups that hold the same binned matrix (and the winner's mesh
+        refit) share one placement."""
+        from ..models.trees import _binned_sharded
 
-        ndata, _ = self._mesh_axes()
-        host, _pad = pad_to_multiple(np.asarray(binned), ndata, axis=0)
-        return (_dev_memo_sharded(host, sweep_matrix_sharding(self.mesh),
-                                  tag), host.shape[0])
+        return _binned_sharded(binned, self.mesh)
 
     def _record_grid_observation(self, wall_s: float, rows: int,
                                  cols: int) -> None:
@@ -654,7 +651,7 @@ class RFGridGroup(TreeGridGroup):
         ndata, _g = self._mesh_axes()
         n = int(np.asarray(W_tr).shape[1])
         d = int(binned.shape[1])
-        binned_dev, _n_pad = self._sharded_matrix(binned, "rf_grid_binned")
+        binned_dev, _n_pad = self._sharded_matrix(binned)
         Y_p, _ = pad_to_multiple(np.asarray(Y, np.float32), ndata, axis=0)
         Wtr_p, _ = pad_to_multiple(
             np.ascontiguousarray(np.asarray(W_tr, np.float32)), ndata,
@@ -977,8 +974,7 @@ class GBTGridGroup(TreeGridGroup):
                 return np.concatenate([a, np.repeat(a[-1:], c_pad,
                                                     axis=0)])
 
-            binned_sh, n_pad = self._sharded_matrix(binned,
-                                                    "gbt_grid_binned")
+            binned_sh, n_pad = self._sharded_matrix(binned)
             y_p, _ = pad_to_multiple(y, ndata)
             y_sh = _dev_memo_sharded(y_p, data_sharding(mesh),
                                      "gbt_grid_y")
