@@ -157,7 +157,7 @@ import threading
 from collections import OrderedDict
 
 from ..obs.trace import span as _span
-from ..utils.profiling import count_memo
+from ..utils.profiling import count_memo, launch
 
 _BIN_CACHE: "OrderedDict" = OrderedDict()
 _BIN_CACHE_CAPACITY = 32
@@ -494,7 +494,6 @@ def _device_bins(Xf: np.ndarray, ef: np.ndarray):
     the host and every block has the one shape.  The block rows depend on
     the shape alone: a warm-up train builds every program of the walk (the
     result's ``zeros`` and the block program)."""
-    from ..utils.profiling import launch
     from . import gbdt_kernels
 
     n, d = Xf.shape
@@ -544,7 +543,6 @@ def _binned_cached(Xf: np.ndarray, hx: str, edges):
         xdev = _memo_peek(("X_f32", hx, Xf.shape, "float32"))
         if xdev is None:
             return _device_bins(Xf, ef)
-        from ..utils.profiling import launch
         with launch("device_bin"):
             fn = _apply_bins_i8 if ef.shape[1] < 127 else apply_bins
             return fn(xdev, jnp.asarray(ef))

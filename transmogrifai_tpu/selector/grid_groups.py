@@ -370,15 +370,6 @@ class TreeGridGroup(GridGroup):
         return (int(mesh.shape[mesh.axis_names[0]]),
                 int(mesh.shape[mesh.axis_names[1]]))
 
-    def _sharded_matrix(self, binned):
-        """Row-pad a (device or host) binned matrix to tile the data axis
-        and commit it ``P("data", None)`` — content-memoized under ONE tag,
-        so groups that hold the same binned matrix (and the winner's mesh
-        refit) share one placement."""
-        from ..models.trees import _binned_sharded
-
-        return _binned_sharded(binned, self.mesh)
-
     def _record_grid_observation(self, wall_s: float, rows: int,
                                  cols: int) -> None:
         """Append a ``<family>:fit-grid`` stage observation to the shared
@@ -643,7 +634,7 @@ class RFGridGroup(TreeGridGroup):
         on-device single-chip generator (``rf_bags_and_features``)."""
         from ..models.gbdt_kernels import (_resolve_compile_depth,
                                            rf_bags_and_features)
-        from ..models.trees import _dev_memo_sharded
+        from ..models.trees import _binned_sharded, _dev_memo_sharded
         from ..parallel.mesh import fold_weight_sharding, pad_to_multiple
         from ..parallel.sharded import grow_rf_grid_sharded
 
@@ -651,7 +642,7 @@ class RFGridGroup(TreeGridGroup):
         ndata, _g = self._mesh_axes()
         n = int(np.asarray(W_tr).shape[1])
         d = int(binned.shape[1])
-        binned_dev, _n_pad = self._sharded_matrix(binned)
+        binned_dev, _n_pad = _binned_sharded(binned, self.mesh)
         Y_p, _ = pad_to_multiple(np.asarray(Y, np.float32), ndata, axis=0)
         Wtr_p, _ = pad_to_multiple(
             np.ascontiguousarray(np.asarray(W_tr, np.float32)), ndata,
@@ -961,7 +952,7 @@ class GBTGridGroup(TreeGridGroup):
             from ..parallel.mesh import (chain_sharding, data_sharding,
                                          pad_to_multiple)
             from ..parallel.sharded import gbt_chain_rounds_sharded
-            from ..models.trees import _dev_memo_sharded
+            from ..models.trees import _binned_sharded, _dev_memo_sharded
 
             mesh = self.mesh
             ndata, g_ax = self._mesh_axes()
@@ -974,7 +965,7 @@ class GBTGridGroup(TreeGridGroup):
                 return np.concatenate([a, np.repeat(a[-1:], c_pad,
                                                     axis=0)])
 
-            binned_sh, n_pad = self._sharded_matrix(binned)
+            binned_sh, n_pad = _binned_sharded(binned, self.mesh)
             y_p, _ = pad_to_multiple(y, ndata)
             y_sh = _dev_memo_sharded(y_p, data_sharding(mesh),
                                      "gbt_grid_y")
