@@ -3,11 +3,15 @@
 They run once, in set-up, on the model of the warm-up train; the CV half of
 the quality band is applied again to every train of the window:
 
-oracle        the planted weights score the hold-out in float64 NumPy
-              (``reference/oracle.py``).  No model may come out above the
-              oracle by more than ``oracle_slack``; a traffic file that
-              gives ``oracle_gap_max`` also holds the winner within that
-              much BELOW it.
+oracle        the planted model scores the hold-out in float64 NumPy: the
+              generator's own ``oracle_score(frame, planted)`` where it
+              defines one (a planted model that is not linear in the raw
+              columns, a frame with strings or nulls), else ``X @ beta`` on
+              the float32 matrix (``reference/oracle.py``, which holds the
+              one AuPR for both).  No model may come out above the oracle
+              by more than ``oracle_slack``; a traffic file that gives
+              ``oracle_gap_max`` also holds the winner within that much
+              BELOW it.
 tree scorer   a tree winner's ``(feat, thresh, leaf)`` arrays, walked by
               the float64 NumPy walker of ``reference/tree_walker.py`` on
               the first ``tree_scorer_rows`` hold-out rows, agree with
@@ -92,6 +96,21 @@ def candidate_band_problems(ctx, candidates: list) -> list:
     return problems
 
 
+def compared_cv(ctx, trains: list) -> dict:
+    """``{"cv_aupr.<Estimator>": [[lowest, highest], band]}`` over the
+    candidates of ``trains`` (records of ``train_loop.one_train``), where
+    ``candidate_band_problems`` holds them to a band."""
+    band = ctx.traffic["checks"].get("quality_band")
+    if ctx.rehearsal_shape or band is None:
+        return {}
+    seen: dict = {}
+    for rec in trains:
+        for c in rec["candidates"]:
+            seen.setdefault(c["model"], []).append(c["cv"])
+    return {f"cv_aupr.{m}": [[min(v), max(v)], band["cv_aupr"][m]]
+            for m, v in seen.items()}
+
+
 def check_model(ctx, model, candidates: list, checked) -> dict:
     """``candidates`` are the warm-up train's, as ``train_loop.one_train``
     records them (``model``, ``params``, ``cv``)."""
@@ -103,9 +122,16 @@ def check_model(ctx, model, candidates: list, checked) -> dict:
     problems = []
 
     aupr = holdout_aupr(model, hold)
-    Xh = hold.drop(columns=[label]).to_numpy(np.float32)
     yh = hold[label].to_numpy()
-    best = oracle.oracle_aupr(Xh, yh, ctx.beta)
+    oracle_score = getattr(ctx.generator, "oracle_score", None)
+    if oracle_score is None:
+        Xh = hold.drop(columns=[label]).to_numpy(np.float32)
+        best = oracle.oracle_aupr(Xh, yh, ctx.planted)
+    else:
+        best = oracle.aupr(yh, oracle_score(hold, ctx.planted))
+    # each number compared goes beside its limit into ``compared``, for the
+    # run's last lines
+    compared = {"aupr_over_oracle": [aupr - best, spec["oracle_slack"]]}
     if not math.isfinite(aupr):
         problems.append("hold-out AuPR is not finite")
     if aupr > best + spec["oracle_slack"]:
@@ -115,6 +141,9 @@ def check_model(ctx, model, candidates: list, checked) -> dict:
     walker = check_tree_scorer(model, hold, checked,
                                spec["tree_scorer_rows"],
                                spec["tree_scorer_atol"])
+    if walker["max_abs_diff"] is not None:
+        compared["tree_scorer_diff"] = [walker["max_abs_diff"],
+                                        spec["tree_scorer_atol"]]
     if not walker["ok"]:
         problems.append(f"model.score and the NumPy tree walker differ by "
                         f"{walker['max_abs_diff']:.3g} > "
@@ -123,18 +152,23 @@ def check_model(ctx, model, candidates: list, checked) -> dict:
     banded = not ctx.rehearsal_shape
     if banded:
         gap = spec.get("oracle_gap_max")
-        if gap is not None and aupr < best - gap:
-            problems.append(f"hold-out AuPR {aupr:.4f} is more than {gap} "
-                            f"below the oracle's {best:.4f}")
+        if gap is not None:
+            compared["aupr_under_oracle"] = [best - aupr, gap]
+            if aupr < best - gap:
+                problems.append(f"hold-out AuPR {aupr:.4f} is more than "
+                                f"{gap} below the oracle's {best:.4f}")
         problems += candidate_band_problems(ctx, candidates)
         band = spec.get("quality_band")
         if band is not None:
             lo, hi = band["holdout_aupr"]
+            compared["holdout_aupr"] = [aupr, [lo, hi]]
             if not lo <= aupr <= hi:
                 problems.append(f"hold-out AuPR {aupr:.4f} outside "
                                 f"[{lo}, {hi}]")
     verdict = {"holdout_aupr": aupr, "oracle_aupr": best,
+               "oracle_from": ("X @ beta" if oracle_score is None
+                               else "oracle_score"),
                "tree_scorer": walker, "banded": banded,
-               "problems": problems}
+               "compared": compared, "problems": problems}
     ctx.say("checks", **verdict)
     return verdict

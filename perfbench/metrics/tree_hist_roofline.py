@@ -10,7 +10,12 @@ knows: the levels of a tree, the share of the training rows a level reads
 (GOSS keeps 0.2 + 0.2 of them; a forest reads all) and the constructor
 argument that holds the trees of one fit.  The trees are counted here from
 the mix itself, so they cannot drift from it: (grid points x folds, + 1 for
-the refit if this estimator won) x trees of one fit.  The time is
+the refit if this estimator won) x trees of one fit.  ``cols`` is the width
+of the vector the selector was given (``selector_cols``, which the mode
+records from the fitted train: vectorizers, pivots and null flags in,
+SanityChecker's drops out), not the raw column count and not the width after
+the program's own feature bundling: the bytes are those of the work, whatever
+implements it.  A mode that records none leaves the raw count.  The time is
 ``tree_device_s``: ALL tree device time, so this is a lower bound on the
 histogram kernel's own share.  (ISSUE 22 called this metric
 ``hist_hbm_share``; the contract names a roofline share
@@ -54,7 +59,7 @@ def read(sources: dict):
     if not seconds or "roofline" not in traffic:
         return None
     moved = histogram_bytes(
-        cell["rows"], cell["cols"], traffic,
+        cell["rows"], sources.get("selector_cols", cell["cols"]), traffic,
         cell["config"]["validator"]["num_folds"], sources["winner"][0])
     peak = peaks.chip_peaks(sources["device_kind"])["hbm_gbs"] * 1e9
     return 100.0 * moved / seconds / peak

@@ -48,6 +48,35 @@ class CellFailure(RuntimeError):
 # the workflow, from the configuration and the traffic file
 # ---------------------------------------------------------------------------
 
+def predictor_types(columns: list, predictors: dict) -> dict:
+    """``{frame column: FeatureBuilder type name}`` from
+    ``schema.predictors``: either ``{"type": T, "count": N}`` (every column
+    is a ``T``) or ``{"count": N, "columns": [family, ...]}``, a family being
+    ``{"type": T, "names": [...]}`` or ``{"type": T, "prefix": P, "count":
+    K}`` (``P0 .. P<K-1>``).  The families must name the frame's columns
+    exactly, each once, and ``count`` of them."""
+    if "columns" not in predictors:
+        return dict.fromkeys(columns, predictors["type"])
+    types, names = {}, []
+    for family in predictors["columns"]:
+        own = family.get("names") or [
+            f"{family['prefix']}{j}" for j in range(family["count"])]
+        names += own
+        types.update(dict.fromkeys(own, family["type"]))
+    if len(types) != len(names):
+        twice = sorted(n for n in types if names.count(n) > 1)
+        raise CellFailure(f"schema.predictors names {twice} twice")
+    if len(types) != predictors["count"]:
+        raise CellFailure(f"schema.predictors' families name {len(types)} "
+                          f"columns, its count says {predictors['count']}")
+    if set(types) != set(columns):
+        raise CellFailure(
+            f"schema.predictors does not cover the frame's columns: "
+            f"{sorted(set(columns) - set(types))} have no family, "
+            f"{sorted(set(types) - set(columns))} are not in the frame")
+    return types
+
+
 def feature_graph(df, config: dict):
     from transmogrifai_tpu import FeatureBuilder, transmogrify
     from transmogrifai_tpu.preparators import SanityChecker
@@ -55,9 +84,10 @@ def feature_graph(df, config: dict):
     schema = config["schema"]
     label = getattr(FeatureBuilder, schema["label"]["type"])(
         schema["label"]["name"]).as_response()
-    make = getattr(FeatureBuilder, schema["predictors"]["type"])
-    preds = [make(c).as_predictor() for c in df.columns
-             if c != schema["label"]["name"]]
+    columns = [c for c in df.columns if c != schema["label"]["name"]]
+    types = predictor_types(columns, schema["predictors"])
+    preds = [getattr(FeatureBuilder, types[c])(c).as_predictor()
+             for c in columns]
     checker = SanityChecker(**config["feature_graph"]["sanity_checker"])
     checked = checker.set_input(label, transmogrify(preds)).get_output()
     return label, checked
@@ -82,8 +112,11 @@ def selector_workflow(df, label, checked, config: dict, traffic: dict,
     from transmogrifai_tpu.selector import BinaryClassificationModelSelector
 
     if config["problem"] != "binary":
-        raise CellFailure(f"train_loop builds binary selectors only, the "
-                          f"configuration says {config['problem']!r}")
+        raise CellFailure(
+            f"train_loop builds binary selectors only, the configuration "
+            f"says {config['problem']!r}: a multi-class or regression cell "
+            f"needs a quality metric beside holdout_aupr (and its selector) "
+            f"first, which is a benchmark issue of its own")
     models = build_models(traffic)
     val = config["validator"]
     selector = BinaryClassificationModelSelector.with_cross_validation(
@@ -130,8 +163,11 @@ def one_train(ctx, wf, selector, n_candidates: int, leg: str):
            or not all(math.isfinite(v) for v in r["foldValues"])]
     elastic = profiling.elastic_snapshot()
     compiled = ctx.meter.since(mark)
+    # the width of the vector the selector was given (its second input, the
+    # SanityChecker's output): what the tree kernels' histograms span
+    vector = model.train_data[selector.input_features[1].name].values
     rec = {
-        "leg": leg, "wall_s": wall,
+        "leg": leg, "wall_s": wall, "selector_cols": int(vector.shape[1]),
         "winner": [summ["bestModelType"], summ["bestModelParams"]],
         "candidates": [{"model": r["modelType"], "params": r["params"],
                         "cv": r["metricValue"], "folds": r["foldValues"],
@@ -167,7 +203,8 @@ def one_train(ctx, wf, selector, n_candidates: int, leg: str):
             stages_s={k: round(v, 3) for k, v in stage_s.items()},
             drain_s=counters["drainSecs"], fetch_s=counters["fetchSecs"],
             upload_mb=round(counters["uploadBytes"] / 2**20, 1),
-            launches=counters["launchTags"], compile=compiled,
+            launches=counters["launchTags"], memo=counters["memoTags"],
+            selector_cols=rec["selector_cols"], compile=compiled,
             problems=rec["problems"])
     return rec, model
 
@@ -354,8 +391,12 @@ def run(ctx) -> dict:
     if busy:
         end_to_end["train_device_s"] = statistics.median(busy)
     last = trains[-1]
+    compared = dict(verdict["compared"],
+                    **checks.compared_cv(ctx, [warm] + trains))
+    compared["window_programs"] = [
+        max(rec["new_programs"] for rec in trains), allowed]
     return {
-        "correct": not problems, "problems": problems,
+        "correct": not problems, "problems": problems, "compared": compared,
         "attempted": sum(rec["attempted"] for rec in trains),
         "failed": sum(rec["failed"] for rec in trains),
         "end_to_end": end_to_end,
@@ -364,5 +405,6 @@ def run(ctx) -> dict:
                     "compile": setup_compile, "trace": last.get("trace"),
                     "traced_wall_s": last["wall_s"],
                     "window_programs": last["new_programs"],
+                    "selector_cols": last["selector_cols"],
                     "winner": last["winner"], "checks": verdict},
     }

@@ -6,7 +6,9 @@
 A new process: set-up (environment, device, data from ``--seed``, the
 mode's own set-up with ONE warm-up), then the measured window, then one
 JSON object as the last line of stdout: ``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` and, traced, ``breakdown``.  With
+``failed``, ``metrics``, ``device``, traced ``breakdown``, and last
+``compared`` (each number the checks compared, beside its limit; the same
+on the last lines of stderr).  With
 ``--trace 0`` the metrics are the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics.  Everything else worth keeping goes
 on earlier lines prefixed ``[perfbench]`` and into
@@ -64,7 +66,11 @@ class Run:
         self.split: dict = {}
         self.setup_s = None
         self.meter = None
-        self.df = self.hold = self.beta = None
+        #: the generator module, and what its ``generate`` returned: the
+        #: frame split in two, and second the planted model (weights, or
+        #: whatever the generator's own ``oracle_score`` reads)
+        self.generator = None
+        self.df = self.hold = self.planted = None
 
     def say(self, leg: str, **fields) -> None:
         line = f"[perfbench] {leg} " + " ".join(
@@ -188,7 +194,8 @@ def main(argv=None) -> int:
                 else cfg["schema"]["predictors"]["count"])
         hold_rows = cfg["holdout_rows"]
         t0 = time.perf_counter()
-        frame, ctx.beta = generator.generate(
+        ctx.generator = generator
+        frame, ctx.planted = generator.generate(
             rows + hold_rows, cols, args.seed, **cfg["generator"]["params"])
         ctx.df = frame.iloc[:rows].reset_index(drop=True)
         ctx.hold = frame.iloc[rows:].reset_index(drop=True)
@@ -261,6 +268,12 @@ def main(argv=None) -> int:
             ctx.say("problems", problems=out["problems"])
         if ctx.rehearsal_shape or devices[0].platform != "tpu":
             result["rehearsal"] = True
+        # each number the checks compared, beside its limit: the last lines
+        # of stderr, and the last key of the result's line
+        result["compared"] = out.get("compared", {})
+        for name, (value, limit) in result["compared"].items():
+            print(f"perfbench: compared {name} = {value!r}, limit {limit!r}",
+                  file=sys.stderr, flush=True)
         code = 0
     except Exception:
         traceback.print_exc()

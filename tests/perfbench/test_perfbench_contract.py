@@ -24,6 +24,13 @@ METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
 #: what a `reduced` key may never name (the contract's widths)
 WIDTH_WORDS = ("hidden", "intermediate", "latent", "state", "proj", "head",
                "expansion", "experts_per")
+#: the accepted cells and their chips, by name: neither can vanish.  Later
+#: cells enter beside them and are held by the rules below, not by a list.
+ACCEPTED = {"dense500-xgb": 1, "mesh4-trees": 4}
+#: the accepted configurations' sizes, by name (columns, rows, hold-out
+#: rows): a later configuration is held by the rules of `test_config_entry`
+PINNED_SIZES = {"dense500-binary": (500, 250_000, 20_000),
+                "dense500-binary-mesh4": (500, 250_000, 20_000)}
 
 
 def test_top_level_keys_and_limits():
@@ -71,12 +78,31 @@ def test_config_entry(entry):
         # every cut is a key of the file and says what the source has
         assert key in cfg and key in cfg["reduced"], key
         assert {"source", "here", "why"} <= set(cfg["reduced"][key])
-    # never cut: the published width, bins, folds
-    assert cfg["schema"]["predictors"]["count"] == 500
-    assert cfg["max_bins"] == 32
-    assert cfg["validator"]["num_folds"] == 3
-    assert cfg["rows"] == 250_000 and cfg["holdout_rows"] == 20_000
+    # the file states what its source publishes; never cut: the published
+    # width, bins, folds.  Rows are cut only as `reduced` says, from the
+    # published count; the hold-out is the benchmark's own and says so
+    published = cfg["published"]
+    assert {"columns", "rows", "max_bins", "num_folds"} <= set(published)
+    assert cfg["schema"]["predictors"]["count"] == published["columns"]
+    assert cfg["max_bins"] == published["max_bins"]
+    assert cfg["validator"]["num_folds"] == published["num_folds"]
+    if "rows" in entry["reduced"]:
+        assert cfg["reduced"]["rows"]["source"] == published["rows"]
+        assert cfg["rows"] < published["rows"]
+    else:
+        assert cfg["rows"] == published["rows"]
+    assert cfg["holdout_rows"] > 0 and "holdout_rows" in cfg["assumed"]
+    if entry["name"] in PINNED_SIZES:
+        assert (cfg["schema"]["predictors"]["count"], cfg["rows"],
+                cfg["holdout_rows"]) == PINNED_SIZES[entry["name"]]
+        assert (published["columns"], published["rows"],
+                published["max_bins"], published["num_folds"]) == (
+                    500, 1_000_000, 32, 3)
     assert entry["name"] in {w["config"] for w in BENCH["workloads"]}
+
+
+def test_the_accepted_configurations_are_there():
+    assert set(PINNED_SIZES) <= {c["name"] for c in BENCH["configs"]}
 
 
 def test_config_files_are_not_shared():
@@ -110,10 +136,11 @@ def test_cell_entry_and_files(cell):
 
 
 def test_cells_and_chips():
-    assert {w["name"]: w["chips"] for w in BENCH["workloads"]} == {
-        "dense500-xgb": 1, "mesh4-trees": 4}
-    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
-    assert four <= max(1, len(BENCH["workloads"]) // 4)
+    chips = {w["name"]: w["chips"] for w in BENCH["workloads"]}
+    assert set(chips.values()) <= {1, 4}
+    assert ACCEPTED.items() <= chips.items()
+    four = sum(c == 4 for c in chips.values())
+    assert four <= max(1, len(chips) // 4)
 
 
 #: ISSUE 22's two cells that wait under PERF.md's Open questions; their files
@@ -265,6 +292,49 @@ def test_traffic_files_are_data():
         assert f.endswith((".json", ".jsonl", ".toml", ".txt", ".csv")), f
 
 
+# -- a typed schema, as data --------------------------------------------------
+
+_FRAME = ["r0", "r1", "amount", "c0"]
+_FAMILIES = [{"type": "Real", "prefix": "r", "count": 2},
+             {"type": "Integral", "names": ["amount"]},
+             {"type": "PickList", "prefix": "c", "count": 1}]
+
+
+@pytest.mark.parametrize("predictors,want", [
+    # the accepted configurations' form: every column is one type
+    ({"type": "Real", "count": 4}, dict.fromkeys(_FRAME, "Real")),
+    ({"count": 4, "columns": _FAMILIES},
+     {"r0": "Real", "r1": "Real", "amount": "Integral", "c0": "PickList"}),
+    # families that do not sum to `count`, leave a frame column out, name
+    # one the frame lacks, or name one twice
+    ({"count": 5, "columns": _FAMILIES}, "count says 5"),
+    ({"count": 3, "columns": _FAMILIES[:2]}, "['c0'] have no family"),
+    ({"count": 5, "columns": _FAMILIES + [{"type": "Text", "names": ["t"]}]},
+     "['t'] are not in the frame"),
+    ({"count": 4, "columns": _FAMILIES + [{"type": "Real", "names": ["r1"]}]},
+     "twice")], ids=["one-type", "families", "count", "uncovered", "absent",
+                     "twice"])
+def test_schema_predictors_is_one_type_or_families_that_cover_the_frame(
+        predictors, want):
+    from perfbench.modes import train_loop
+
+    if isinstance(want, dict):
+        assert train_loop.predictor_types(_FRAME, predictors) == want
+        return
+    with pytest.raises(train_loop.CellFailure) as failure:
+        train_loop.predictor_types(_FRAME, predictors)
+    assert want in str(failure.value)
+
+
+def test_train_loop_is_binary_only_and_says_what_another_label_needs():
+    from perfbench.modes import train_loop
+
+    with pytest.raises(train_loop.CellFailure) as failure:
+        train_loop.selector_workflow(None, None, None,
+                                     {"problem": "regression"}, {}, 1)
+    assert "quality metric beside holdout_aupr" in str(failure.value)
+
+
 # -- the generator copy -----------------------------------------------------
 
 def _frame_hash(df) -> str:
@@ -336,6 +406,26 @@ def test_cv_bands_hold_the_candidates_of_every_train():
     assert "OpRandomForestClassifier" in problem and "0.4000" in problem
     ctx.rehearsal_shape = True
     assert checks.candidate_band_problems(ctx, [xgb, rf]) == []
+
+
+def test_the_last_line_gives_each_cv_band_beside_what_the_trains_read():
+    from types import SimpleNamespace
+
+    from perfbench import checks
+
+    mix = spec.load_cell("mesh4-trees")["traffic"]
+    ctx = SimpleNamespace(traffic=mix, rehearsal_shape=False)
+    trains = [{"candidates": [
+        {"model": "OpXGBoostClassifier", "cv": cv},
+        {"model": "OpRandomForestClassifier", "cv": cv - 0.3}]}
+        for cv in (0.861, 0.858, 0.866)]
+    got = checks.compared_cv(ctx, trains)
+    band = mix["checks"]["quality_band"]["cv_aupr"]
+    assert got["cv_aupr.OpXGBoostClassifier"] == [
+        [0.858, 0.866], band["OpXGBoostClassifier"]]
+    assert set(got) == {f"cv_aupr.{m}" for m in band}
+    ctx.rehearsal_shape = True          # no band under a rehearsal shape
+    assert checks.compared_cv(ctx, trains) == {}
 
 
 # -- programs built inside the window ----------------------------------------

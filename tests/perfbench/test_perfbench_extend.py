@@ -8,12 +8,14 @@ import hashlib
 import json
 import os
 import shutil
+import subprocess
 import sys
 
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from _child import RESULT_KEYS, ROOT, TINY, run_cell  # noqa: E402
+from _child import (RESULT_KEYS, ROOT, TINY, child_env,  # noqa: E402
+                    run_cell)
 
 NEW_MODE = '''"""Mode ``matmul_loop``: a stand-in for a later PR's mode (say an
 open-loop server): it never trains, it runs one jitted product per step."""
@@ -81,9 +83,11 @@ def _hashes(root):
 
 
 def _copy_of_the_benchmark(tmp_path):
+    """BENCHMARK.json and both directories of its ``paths``."""
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
-    shutil.copytree(os.path.join(ROOT, "perfbench"), tmp_path / "perfbench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
+    for path in ("perfbench", os.path.join("tests", "perfbench")):
+        shutil.copytree(os.path.join(ROOT, path), tmp_path / path,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     # the program beside the copy, as in a checkout
     os.symlink(os.path.join(ROOT, "transmogrifai_tpu"),
                tmp_path / "transmogrifai_tpu")
@@ -161,6 +165,125 @@ def test_a_later_cell_is_a_data_file_and_an_entry(cell, traffic, tmp_path):
         names = [m["name"] for m in spec.load_cell(
             cell, root=str(tmp_path))["per_layer"]]
         assert "linear_device_s" in names
+    after = _hashes(tmp_path)
+    assert [k for k in before if after.get(k) != before[k]] == [
+        "BENCHMARK.json"]
+
+
+def test_the_typed_generator_plants_one_model_and_owns_its_oracle():
+    import importlib.util
+
+    import numpy as np
+
+    file = importlib.util.spec_from_file_location(
+        "typed_planted", os.path.join(LATER, "typed_planted.py"))
+    typed = importlib.util.module_from_spec(file)
+    file.loader.exec_module(typed)
+    sys.path.insert(0, ROOT)
+    from perfbench.reference import oracle
+
+    a, planted_a = typed.generate(20_000, 15, 3)
+    b, planted_b = typed.generate(20_000, 15, 3)
+    c, planted_c = typed.generate(500, 15, 2147483900)
+    assert a.equals(b) and not a.head(500).equals(c)
+    assert planted_a["intercept"] == planted_c["intercept"]   # one model
+    assert all((x == y).all() for x, y in zip(planted_a["cats"],
+                                              planted_c["cats"]))
+    assert list(a.columns) == ["label"] + [f"r{j}" for j in range(6)] + [
+        f"i{j}" for j in range(4)] + [f"c{j}" for j in range(5)]
+    assert 0.04 <= a["label"].mean() <= 0.06
+    # numerics missing in blocks, never-null counts, pick-lists with nulls
+    block = a[["r0", "r1", "r2"]].isna()
+    assert 0.27 <= block["r0"].mean() <= 0.33
+    assert (block["r0"] == block["r1"]).all() and (block["r1"]
+                                                   == block["r2"]).all()
+    assert a["i3"].dtype.kind == "i" and not a["i3"].isna().any()
+    assert [a[f"c{j}"].nunique() <= card for j, card in
+            enumerate((3, 8, 40, 300, 5000))] == [True] * 5
+    assert a["c4"].nunique() > 300 and 0.03 <= a["c4"].isna().mean() <= 0.07
+    # X @ beta has no matrix to take: the frame holds strings and NaN
+    with pytest.raises(ValueError):
+        a.drop(columns=["label"]).to_numpy(np.float32)
+    z = typed.oracle_score(a, planted_a)
+    assert z.dtype == np.float64 and z.shape == (20_000,)
+    assert np.isfinite(z).all()
+    # the planted logit ranks far above chance (AuPR = the positives' share)
+    assert oracle.aupr(a["label"].to_numpy(), z) > 5 * a["label"].mean()
+    with pytest.raises(ValueError):
+        typed.generate(100, 16, 3)
+
+
+def test_a_configuration_with_another_schema_is_new_files_and_entries(
+        tmp_path):
+    """``typed-probe``: nullable ``Real``, ``Integral`` and ``PickList``
+    columns, its own rows, columns and hold-out, a planted model that is not
+    linear in the raw columns (``later/typed_planted.py`` and its
+    ``oracle_score``).  It enters the copy as three new files and entries;
+    every rule of the contract's test file then holds on the copy, and the
+    cell rehearses ``correct`` on the CPU."""
+    config = json.loads(_later("typed-probe.json"))
+    cell = "typed-probe-xgb"
+
+    def edit(bench):
+        bench["configs"].append({
+            "name": "typed-probe", "source": config["source"],
+            "file": "perfbench/configs/typed-probe.json",
+            "reduced": sorted(config["reduced"]),
+            "why": "entered by a later PR: another schema, size and model"})
+        bench["workloads"].append({
+            "name": cell, "config": "typed-probe", "traffic": "xgb-typed",
+            "chips": 1, "why": "entered by a later PR"})
+        _list_cell(bench, cell, "train_device_s", "tree_device_s",
+                   "tree_hist_roofline", "peak_hbm_gib")
+
+    before = _enter(tmp_path, edit, {
+        "perfbench/generators/typed_planted.py": _later("typed_planted.py"),
+        "perfbench/configs/typed-probe.json": _later("typed-probe.json"),
+        "perfbench/traffic/xgb-typed.json": _later("xgb-typed.json")})
+    assert any(k.startswith("tests/perfbench/") for k in before)
+
+    # (a) the contract's rules, as its own test file states them, on the copy
+    cache = tmp_path / "jax_cache"
+    rules = subprocess.run(
+        [sys.executable, "-m", "pytest", "-v", "-p", "no:cacheprovider",
+         "-p", "no:xdist", os.path.join("tests", "perfbench",
+                                        "test_perfbench_contract.py")],
+        capture_output=True, text=True, timeout=600, cwd=tmp_path,
+        env=child_env(cache))
+    assert rules.returncode == 0, rules.stdout[-3000:] + rules.stderr[-2000:]
+    for held in ("test_config_entry[typed-probe]",
+                 f"test_cell_entry_and_files[{cell}]",
+                 "test_the_cuts_a_configuration_states_are_the_mix_s_own_"
+                 f"values[{cell}]",
+                 "test_config_entry[dense500-binary]", "test_cells_and_chips",
+                 "test_every_file_of_the_benchmark_serves_a_cell"):
+        assert f"{held} PASSED" in rules.stdout, held
+    assert " failed" not in rules.stdout and " error" not in rules.stdout
+
+    # (b) the cell, rehearsed: typed columns in, the generator's own oracle
+    out, last = run_cell(cell, "--allow-cpu", "--rows", "3000", trace="1",
+                         root=str(tmp_path), cache_dir=cache)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert last["correct"] is True and last["rehearsal"] is True
+    assert last["failed"] == 0 and last["attempted"] == 4
+    assert set(last["metrics"]) == {"compile_s", "programs", "peak_host_gib"}
+    assert 'oracle_from="oracle_score"' in out.stdout
+    assert last["compared"]["aupr_over_oracle"][0] < 0.01
+    assert last["compared"]["tree_scorer_diff"][0] <= 1e-5
+    # the typed columns went through their own fitted vectorizers
+    for stage in ("RealVectorizer", "IntegralVectorizer", "OneHotVectorizer"):
+        assert f'"{stage}:substitute"' in out.stdout
+    (traced,) = [ln for ln in out.stdout.splitlines()
+                 if ln.startswith("[perfbench] traced wall_s=")]
+    memo = json.loads(traced.split(" memo=")[1].split(" selector_cols=")[0])
+    # the boosted group made its bundling plan (a plan that declines counts
+    # too; the bundled width itself has no counter yet: PERF.md, section 7)
+    assert memo["efb"]["builds"] >= 1
+    # the roofline's width is the selector's input vector (pivots and null
+    # flags in), not the 15 raw columns
+    width = int(traced.split(" selector_cols=")[1].split(" ")[0])
+    assert 60 <= width <= 15 * 22
+
     after = _hashes(tmp_path)
     assert [k for k in before if after.get(k) != before[k]] == [
         "BENCHMARK.json"]

@@ -31,9 +31,16 @@ def cache_dir(tmp_path_factory):
 
 def _check_last_line(last, cell: str, traced: bool, devices: int = 1):
     assert last is not None, "the last line of stdout is no JSON object"
-    want = RESULT_KEYS | {"rehearsal"} | ({"breakdown"} if traced else set())
+    want = (RESULT_KEYS | {"rehearsal", "compared"}
+            | ({"breakdown"} if traced else set()))
     assert set(last) == want
     assert last["rehearsal"] is True
+    # each number compared beside its limit, as the line's last key
+    assert list(last)[-1] == "compared"
+    assert {"aupr_over_oracle", "tree_scorer_diff",
+            "window_programs"} <= set(last["compared"])
+    for value, limit in last["compared"].values():
+        assert value is not None and limit is not None
     assert last["correct"] is True and last["failed"] == 0
     assert last["attempted"] > 0
     device = last["device"]
@@ -68,6 +75,8 @@ def test_cell_runs_tiny_on_cpu_and_prints_the_contract_s_line(cell, cache_dir):
     assert "x64=false" in out.stdout
     assert f'cache_dir="{cache_dir}"' in out.stdout
     assert "[perfbench] window trains=" in out.stdout
+    # planted_linear defines no oracle_score: the float32 matrix by beta
+    assert 'oracle_from="X @ beta"' in out.stdout
 
 
 def test_traced_run_prints_per_layer_metrics_and_a_breakdown(cache_dir):

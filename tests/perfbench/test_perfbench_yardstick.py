@@ -157,6 +157,21 @@ def test_a_reader_that_raises_is_a_problem_and_not_a_silent_gap(reduced):
     assert "tree_hist_roofline" in problem and "TPU v9" in problem
 
 
+@pytest.mark.parametrize("recorded,width", [({}, 32),
+                                            ({"selector_cols": 95}, 95)])
+def test_the_roofline_reads_the_width_the_selector_was_given(
+        reduced, recorded, width):
+    """A typed table's vector (pivots and null flags in, SanityChecker's
+    drops out) is not its raw column count; a mode that records no width
+    leaves the raw count."""
+    from perfbench.metrics import tree_hist_roofline
+
+    sources, _ = _xgb_sources(reduced, "TPU v5 lite")
+    sources.update(recorded)
+    assert tree_hist_roofline.read(sources) == pytest.approx(
+        100 * 32 * 10 * 1600 * (width + 8) / 0.161393847 / 819e9)
+
+
 METER_CHILD = """
 import sys
 import jax, jax.numpy as jnp
