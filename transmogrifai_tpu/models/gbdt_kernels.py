@@ -98,8 +98,11 @@ def _accel_bf16() -> bool:
 
 
 #: rows per histogram block in the streamed build; the per-block bins
-#: one-hot is ROW_BLOCK × B·D f32 per tree under vmap — 2.1 GB at 500
-#: features × 32 bins, 0.4 GB at 100 features (forest_chunk_size budgets it)
+#: one-hot is ROW_BLOCK × B·D values per tree under vmap — on the chip
+#: (bf16 operands) 1.05 GB at 500 features × 32 bins, 0.2 GB at 100
+#: features, twice that in f32.  forest_chunk_size and gbt_chain_chunk
+#: budget it; since PR 29 the full-width form is fused into the histogram
+#: dot on the chip and holds none of it in HBM (the budgets are unchanged)
 ROW_BLOCK = 32768
 
 #: engage sibling subtraction (left-child histograms only; right = parent −
@@ -893,12 +896,21 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
     * **Tile-friendly layout**: per-channel histograms are shaped
       ``(slots, bins, features)`` so the minor axis is the wide feature
       dimension (pads to the 128-lane tile at ~1.2×), not the 32-bin axis
-      (which pads 4×, and OOMed a 6-tree chunk at depth 12).
-    * **MXU histograms**: the histogram is two one-hot matmuls —
-      ``(slots, N) @ (N, bins·features)`` — instead of a scatter-add.  XLA
-      lowers TPU scatters to sorts (measured ~5 ms per (N, D) scatter; ~1800
-      of them per 50-tree depth-12 fit ≈ 8 s), while the matmul form rides
-      the systolic array and the bin one-hot is built once per chunk.
+      (which pads 4×, and OOMed a 6-tree chunk at depth 12).  The bins
+      one-hot that feeds them is ``(bins, features, rows)``: rows, the
+      axis the dot contracts, on the lanes, bins and features as two major
+      axes that are never merged (``bins_onehot`` below says what the
+      merged form cost).
+    * **MXU histograms**: the histogram is a one-hot matmul —
+      ``(slots, N) · (bins, features, N)`` contracted over N, all channels
+      in one dot — instead of a scatter-add.  XLA lowers TPU scatters to
+      sorts (measured ~5 ms per (N, D) scatter; ~1800 of them per 50-tree
+      depth-12 fit ≈ 8 s), while the matmul form rides the systolic array
+      and the full-width bins one-hot is a broadcast-compare of the int8
+      block that the compiler fuses into the dot, so it is built in
+      registers and never reaches HBM (a level of a 32,768-row block of two
+      500-column chains: 27 ms before, 1.4 ms at one slot and 13 ms at 512
+      slots a channel after, where the MXU bounds it; one v5e, PR 29).
     """
     # Feature-subset fast path (RF's featureSubsetStrategy): when the tree
     # uses only ``msub`` of D features, build histograms at width msub
@@ -982,21 +994,49 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
     # the row-block scan carry); f32 unless the TM028-gated opt-in is on
     adt = jnp.bfloat16 if acc_bf16 else jnp.float32
 
-    # Row-blocked histogram build: the bins one-hot is (rows, B·D) — at
-    # 1M×500×32 bins that is 64 GB f32 if materialized whole, so rows stream
-    # through in blocks with the (M, B·D) accumulators carried by lax.scan.
-    # Small inputs keep the single hoisted one-hot (no scan overhead).
+    # Row-blocked histogram build: the bins one-hot is B·D values a row — at
+    # 1M×500×32 bins that is 32 GB in bf16 if materialized whole, so rows
+    # stream through in blocks with the (nchan, M, B, D) accumulators carried
+    # by lax.scan.  Small inputs keep the single hoisted one-hot (no scan
+    # overhead).
     def bins_onehot(rows_b):
-        """Flat (rows, B·d) bins one-hot for a block of full-width rows.
+        """Bins one-hot of a block of rows, in the layout the histogram dot
+        contracts.
+
+        Full width: the one-hot of the (rows, d) block is (B, d, rows) —
+        rows on the minor (lane) axis, the axis the dot contracts, and B
+        and d left as two major axes that nothing merges (the transpose
+        costs nothing in the loop: the compiler lays the scanned blocks out
+        rows-minor, one copy a tree).  The former flat form, ``(rows, B, d) -> (rows,
+        B·d)``, is no bitcast in tiled memory unless d fills whole tiles:
+        at d = 500 the chip wrote the bins broadcast over B (int32, 4.2 GB
+        a call of two chains), copied all of it into the flat layout
+        (9.2 ms, 0.44 s a level a train) and only then read it in the dot.
+        Written this way it is a pure broadcast-compare of the int8
+        block, which XLA fuses INTO the dot: the one-hot never reaches HBM
+        (PERF.md §6, PR 29).
 
         Subset path: one gather from the (well-tiled) full-width matrix
-        straight into the flat layout — no msub-minor intermediate.  Full-
-        width path: the reshape form (minor axis d is already >= a lane
-        tile for the wide matrices this kernel targets)."""
+        straight into the flat (rows, B·msub) layout — no msub-minor
+        intermediate."""
         if col_idx is not None:
             return (rows_b[:, col_idx] == bin_vec[None, :]).astype(hdt)
-        return (rows_b[:, None, :] == jnp.arange(B)[None, :, None]
-                ).astype(hdt).reshape(rows_b.shape[0], B * d)
+        return (rows_b.T[None, :, :]
+                == jnp.arange(B, dtype=rows_b.dtype)[:, None, None]
+                ).astype(hdt)
+
+    # per-slot histogram layout of the dots below: (B, d) straight from the
+    # full-width dot's two free axes, flat B·msub on the subset path (its
+    # minor axis msub would pad every (slot, bin) row to the 128-lane tile)
+    hist_dims = (B, d) if col_idx is None else (B * d,)
+
+    def hist_dot(wnode, oh_bins):
+        """(nchan·Mh, *hist_dims): every (channel, slot) column of ``wnode``
+        (rows, nchan·Mh) against the bins one-hot, contracted over rows."""
+        rows_axis = 2 if col_idx is None else 0
+        return jax.lax.dot_general(
+            wnode.T, oh_bins, (((1,), (rows_axis,)), ((), ())),
+            precision=dot_prec, preferred_element_type=adt)
 
     # segmented (sort-by-node) histogram path: resolved statically by the
     # callers (seg_hist_auto); engages per level at Mh <= SEG_MAX_SLOTS
@@ -1019,7 +1059,7 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
         chans_blk = jnp.pad(jnp.stack(chans, 1), ((0, pad), (0, 0))).reshape(
             n_blocks, ROW_BLOCK, nchan)
     else:
-        # (N, B·d) one-hot, minor axis flat (128-lane tile friendly)
+        # hoisted: (B, d, N), or flat (N, B·msub) on the subset path
         onehot_bins = bins_onehot(binned_full)
 
     node = jnp.zeros(n, jnp.int32)
@@ -1088,32 +1128,29 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
 
                 def hist_block(acc, xs):
                     slot_b, binned_b, ch_b = xs
-                    oh_bins = bins_onehot(binned_b)            # (RB, B·d)
+                    oh_bins = bins_onehot(binned_b)
                     oh_node = node_onehot(slot_b, ROW_BLOCK)   # (RB, Mh)
                     ch_h = ch_b.astype(hdt)
                     # all channels in ONE dot: separate per-channel dots
-                    # re-read the (RB, B·D) bins one-hot — the stream that
-                    # IS the kernel's bandwidth floor — nchan times from HBM
+                    # build (or, on the subset path, re-read from HBM) the
+                    # block's bins one-hot nchan times
                     wnode = jnp.concatenate(
                         [oh_node * ch_h[:, c][:, None] for c in range(nchan)],
                         axis=1)                            # (RB, nchan·Mh)
-                    part = jax.lax.dot(wnode.T, oh_bins,
-                                       precision=dot_prec,
-                                       preferred_element_type=adt)
-                    return acc + part.reshape(nchan, Mh, B * d), None
+                    part = hist_dot(wnode, oh_bins)
+                    return acc + part.reshape((nchan, Mh) + hist_dims), None
 
-                acc0 = jnp.zeros((nchan, Mh, B * d), adt)
+                acc0 = jnp.zeros((nchan, Mh) + hist_dims, adt)
                 hist_stack, _ = lax.scan(
                     hist_block, acc0, (slot_blk, binned_blk, chans_blk))
-                hists = [hist_stack[c].reshape(Mh, B, d) for c in range(nchan)]
+                hists = [hist_stack[c].reshape(Mh, B, d)
+                         for c in range(nchan)]
             else:
                 onehot_node = node_onehot(slot, n)            # (N, Mh)
                 wnode = jnp.concatenate(
                     [onehot_node * ch.astype(hdt)[:, None] for ch in chans],
                     axis=1)                               # (N, nchan·Mh)
-                hist_all = jax.lax.dot(
-                    wnode.T, onehot_bins, precision=dot_prec,
-                    preferred_element_type=adt)           # (nchan·Mh, B·D)
+                hist_all = hist_dot(wnode, onehot_bins)   # (nchan·Mh, ...)
                 hists = [hist_all[c * Mh:(c + 1) * Mh].reshape(Mh, B, d)
                          for c in range(nchan)]           # 2K+1 × (Mh, B, D)
             if acc_bf16:
