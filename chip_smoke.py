@@ -14,9 +14,8 @@ Legs, each of which raises on failure (no leg records an error and goes on):
          8 rounds x 2 min_child_weight (6 chains -> GOSS over the dense
          shared-one-hot histogram); cold train() then warm train()
   gbt    single-model workflow, OpGBTClassifier depth 5 x 8 rounds: one
-         chain, depth < 8, >= 250k rows -> the segmented Pallas histogram;
-         its lowered program must hold a Mosaic custom call and its trees
-         must match the dense-path fit
+         chain, depth < 8 (no GOSS), all rows -> the one-hot dot histogram
+         of every tree program; its lowered program holds no custom call
   serve  LR-winner model saved and served with device programs + AOT store
          (1/8/64-row requests, once over HTTP); a second program set on the
          same store must load every bucket; then the sweep's own winner on
@@ -28,9 +27,9 @@ Usage:
                                     mesh of four chips, parity vs one chip
   JAX_PLATFORMS=cpu python chip_smoke.py --rows 4000 --cols 32
                                     tiny CPU run: --rows/--cols relax ONLY
-                                    the platform and Mosaic assertions (and
-                                    the AuPR band, which is a property of
-                                    the 500-column configuration)
+                                    the platform assertion (and the AuPR
+                                    band, which is a property of the
+                                    500-column configuration)
 
 The seconds printed here are set-up evidence, not a benchmark.  The last
 line of stdout is {"ok": true, "device": {...}} with the device as JAX
@@ -58,8 +57,8 @@ FULL_COLS = 500
 #: rows of the default run.  1,000,000 fits the 1200 s contract on one v5e
 #: (864 s cold, every leg passing — my chip run, PR 21, ``--rows
 #: 1000000``) but peaks at 38.0 GiB of host RSS on a 40 GiB machine, so
-#: the default is the next size down.  Never below 250,000: SEG_MIN_ROWS
-#: and _BF16_UPLOAD_ELEMS select the code paths scale runs take.
+#: the default is the next size down.  Never below 250,000:
+#: _BF16_UPLOAD_ELEMS selects the code path scale runs take.
 FULL_ROWS = 500_000
 #: rows generated past the training rows and scored as the holdout
 HOLD_ROWS = 20_000
@@ -321,18 +320,6 @@ def train_timed(leg: str, wf, meter: CompileMeter):
     return model, wall
 
 
-def tree_stage(model):
-    """The fitted tree ensemble of a workflow model (unwrapping the
-    selector's SelectedModel)."""
-    from transmogrifai_tpu.models.trees import TreeEnsembleModel
-
-    for s in model.stages:
-        inner = getattr(s, "inner", s)
-        if isinstance(inner, TreeEnsembleModel):
-            return inner
-    raise SmokeFailure("no TreeEnsembleModel stage in the trained model")
-
-
 # ---------------------------------------------------------------------------
 # legs
 # ---------------------------------------------------------------------------
@@ -365,9 +352,10 @@ def leg_sweep(ctx, parallel=None, warm=True):
                                  "gbt_chain_rounds")
         goss = any("top_k" in p for p in progs)
         say(f"{leg}.xgb", programs=len(progs), goss=goss,
-            mosaic=any("tpu_custom_call" in p for p in progs),
-            hist=("dense one-hot per chain over a GOSS row gather" if goss
-                  else "dense one-hot shared across chains"))
+            hist=("one-hot dot per chain over a GOSS row gather" if goss
+                  else "one-hot dot shared across chains"))
+        check(not any("tpu_custom_call" in p for p in progs),
+              f"{leg}: a tree program holds a tpu_custom_call")
         if goss_plan(len(df), OpXGBoostClassifier().max_depth) is not None:
             check(goss, f"{leg}: the XGB group's program holds no top_k — "
                         f"GOSS did not run over {len(df)} rows")
@@ -431,65 +419,27 @@ def leg_mesh_parity(ctx, summ1: dict, summ4: dict) -> None:
 
 
 def leg_gbt(ctx, fitted) -> None:
-    """Single-chain GBT through OpWorkflow.train(): the Pallas segmented
-    histogram must go through Mosaic and agree with the dense path."""
-    import numpy as np
-
+    """Single-chain GBT through OpWorkflow.train(): all rows (no GOSS at
+    depth 5), the same one-hot dot histogram as every tree program."""
     from transmogrifai_tpu import OpWorkflow
     from transmogrifai_tpu.models import OpGBTClassifier
 
     df, label, checked = ctx["df"], ctx["label"], ctx["checked"]
-
-    def workflow():
-        # f32 histogram operands on both sides: the tolerance below is the
-        # one tests/test_seg_hist.py pins for seg-vs-dense at f32
-        est = OpGBTClassifier(max_iter=8, hist_precision="f32")
-        pred = est.set_input(label, checked).get_output()
-        return (OpWorkflow().set_result_features(pred).set_input_data(df)
-                .with_model_stages(fitted))
-
-    model, _ = train_timed("gbt.seg", workflow(), ctx["meter"])
+    pred = OpGBTClassifier(max_iter=8).set_input(label, checked).get_output()
+    wf = (OpWorkflow().set_result_features(pred).set_input_data(df)
+          .with_model_stages(fitted))
+    model, _ = train_timed("gbt", wf, ctx["meter"])
     progs = lowered_programs(ctx["ir_dir"], ctx["ir_seen"],
                              "gbt_chain_rounds")
-    mosaic = any("tpu_custom_call" in p for p in progs)
     goss = any("top_k" in p for p in progs)
-    say("gbt", programs=len(progs), mosaic=mosaic, goss=goss,
-        hist="segmented pallas (mosaic)" if mosaic else "no mosaic call")
+    custom = any("tpu_custom_call" in p for p in progs)
     check(progs, "gbt: no gbt_chain_rounds program was lowered")
     check(not goss, "gbt: GOSS ran at depth 5")
-    if not ctx["relaxed"]:
-        check(mosaic, "gbt: the single-chain GBT program holds no "
-                      "tpu_custom_call — the segmented histogram did not "
-                      "go through Mosaic")
+    check(not custom, "gbt: a tree program holds a tpu_custom_call")
     aupr = holdout_aupr(model, ctx["hold"])
     check_aupr("gbt", aupr, banded=False)
-
-    prev = os.environ.get("TMOG_SEG_HIST")
-    os.environ["TMOG_SEG_HIST"] = "0"
-    try:
-        dense, _ = train_timed("gbt.dense", workflow(), ctx["meter"])
-    finally:
-        if prev is None:
-            del os.environ["TMOG_SEG_HIST"]
-        else:
-            os.environ["TMOG_SEG_HIST"] = prev
-    progs_d = lowered_programs(ctx["ir_dir"], ctx["ir_seen"],
-                               "gbt_chain_rounds")
-    check(not any("tpu_custom_call" in p for p in progs_d),
-          "gbt: the dense reference fit lowered a Mosaic call")
-    ts, td = tree_stage(model), tree_stage(dense)
-    feat_eq = bool(np.array_equal(np.asarray(ts.feat), np.asarray(td.feat)))
-    thr_eq = bool(np.array_equal(np.asarray(ts.thresh),
-                                 np.asarray(td.thresh)))
-    leaf_diff = float(np.max(np.abs(np.asarray(ts.leaf)
-                                    - np.asarray(td.leaf))))
-    say("gbt", holdout_aupr=round(aupr, 4), feat_equal=feat_eq,
-        thresh_equal=thr_eq, leaf_max_abs_diff=leaf_diff,
-        trees=int(np.asarray(ts.feat).shape[0]))
-    check(feat_eq and thr_eq, "gbt: segmented and dense fits grew "
-                              "different trees")
-    np.testing.assert_allclose(np.asarray(ts.leaf), np.asarray(td.leaf),
-                               rtol=1e-4, atol=1e-5)
+    say("gbt", programs=len(progs), goss=goss, custom_call=custom,
+        holdout_aupr=round(aupr, 4))
 
 
 def _rows_of(frame, n: int):
@@ -674,7 +624,7 @@ def leg_serve_winner(ctx, model, summ) -> None:
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rows", type=int, default=None,
-                    help="training rows (relaxes the platform, Mosaic and "
+                    help="training rows (relaxes the platform and "
                          "AuPR-band assertions)")
     ap.add_argument("--cols", type=int, default=None,
                     help="Real columns (relaxes the same assertions)")

@@ -1,6 +1,6 @@
 """chip_smoke.py and the compile-cache contract it prints.
 
-The smoke's real assertions (platform, Mosaic, HBM) only mean something on
+The smoke's real assertions (platform, HBM) only mean something on
 the chip; what tier-1 can pin on CPU is that the script still runs end to
 end through the public entry points at a tiny size, in 32-bit mode like the
 chip, that it REFUSES a CPU at full-size arguments, and that the compile
@@ -53,8 +53,8 @@ class TestChipSmoke:
         assert "x64=false" in text
         assert f'[smoke:cache] dir="{cache}" from_env=true' in text
         assert "[smoke:serve.aot] buckets=7 aot_loads=7 aot_misses=0" in text
-        for leg in ("sweep.cold", "sweep.warm", "gbt.seg", "gbt.dense",
-                    "serve.lr", "serve.winner", "done"):
+        for leg in ("sweep.cold", "sweep.warm", "gbt", "serve.lr",
+                    "serve.winner", "done"):
             assert f"[smoke:{leg}]" in text, leg
 
     def test_refuses_a_cpu_at_full_size(self, tmp_path):

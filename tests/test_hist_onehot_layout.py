@@ -13,8 +13,16 @@ histogram sum is exact in f32 and in f64 alike: two candidates' gains then
 differ by far more than f32 rounding or are exactly equal in both (and both
 break ties by the lowest threshold, then the lowest column), which is what
 lets features and thresholds be compared with ``==``.
+
+It is the ONLY formulation since PR 30 (the segmented Pallas form and the
+CSR form lost every measurement and were deleted): ``LONE_TREES`` holds it,
+to the same two references, on the shapes their parity tests used, one fit
+of ``OpGBTClassifier`` is held to the reference's boosted trees, and the
+last test pins the ``TMOG_*`` names the package reads, so that the next
+switch is a visible diff.
 """
 import os
+import re
 import sys
 
 import jax
@@ -241,3 +249,150 @@ def test_dense_histograms_and_trees_match_the_references(case, row_block):
         Hs = np.bincount(node, h, 2 ** DEPTH)
         np.testing.assert_allclose(leaf[c, :, 0], -LR * Gs / (Hs + LAM),
                                    rtol=0, atol=3e-7)
+
+
+# -- single trees on the shapes the deleted forms' parity tests used ---------
+
+def _mostly_zero(n, d, seed):
+    """One exponential value a row in a random column: 96 % zeros."""
+    rng = np.random.default_rng(seed)
+    X = np.zeros((n, d), np.float32)
+    X[np.arange(n), rng.integers(0, d, n)] = rng.exponential(1.0, n)
+    return X
+
+
+def _lone_tree(case):
+    """``(binned, g, h, depth, min_instances, gamma, ROW_BLOCK or None)``."""
+    if case.startswith("mostly-zero"):
+        # sparse-aware edges (a pinned 0.0 edge, the other bins on the
+        # nonzero values): what the CSR parity test held the kernel to
+        X = _mostly_zero(3000, 24, seed=5)
+        edges = gk.quantile_bins_sparse_aware(X, B)
+        assert (edges[:, 0] == 0.0).all()
+        G, H = _gradients(X, 1, seed=24)
+        depth, block = (3, None) if case.endswith("d3") else (6, 1024)
+        return (hist_gbt.bin_matrix(X, edges), G[0, :, 0], H[0, :, 0],
+                depth, 5.0, 0.0, block)
+    if case == "empty-slots-d4":
+        # no gradient where column 0 is low: the root splits there and its
+        # left child cannot split, so the level below has an EMPTY slot
+        X, binned, _ = _table(2000, 16, False, seed=7)
+        low = binned[:, 0] <= 15
+        g = np.where(low, 0.0, 1 + np.round(np.tanh(X[:, 1]) * 32) / 64)
+        return binned, g, np.full(2000, 0.25), 4, 1.0, GAMMA, None
+    assert case == "n4000-d24-depth5"
+    rng = np.random.default_rng(3)
+    X = rng.normal(size=(4000, 24)).astype(np.float32)
+    y = X[:, 0] - 0.5 * X[:, 3] + 0.3 * rng.normal(size=4000) > 0
+    binned = hist_gbt.bin_matrix(X, hist_gbt.quantile_edges(X, B))
+    return binned, 0.5 - y, np.full(4000, 0.25), 5, 1.0, 0.0, None
+
+
+LONE_TREES = ["mostly-zero-d3", "mostly-zero-d6-blocked", "empty-slots-d4",
+              "n4000-d24-depth5"]
+
+
+@pytest.mark.parametrize("case", LONE_TREES)
+def test_lone_tree_histograms_and_splits_match_the_references(case,
+                                                              row_block):
+    binned, g, h, depth, min_inst, gamma, block = _lone_tree(case)
+    row_block(block)
+    n, d = binned.shape
+    c = np.ones(n)
+    bj = jnp.asarray(binned.astype(np.int8))
+    gj, hj = (jnp.asarray(a, jnp.float32)[:, None] for a in (g, h))
+    taken = []
+
+    def record(x):
+        jax.debug.callback(lambda a: taken.append(np.asarray(a)), x,
+                           ordered=True)
+        return x
+
+    f0, t0, _, _ = jax.jit(lambda b, gg, hh, cc: gk._grow_tree_traced(
+        b, gg, hh, cc, jnp.ones(d, bool), jnp.int32(depth), max_depth=depth,
+        n_bins=B, lam=jnp.float32(LAM), min_child_weight=jnp.float32(MCW),
+        min_info_gain=jnp.float32(0.0), min_instances=jnp.float32(min_inst),
+        newton_leaf=jnp.bool_(True), learning_rate=jnp.float32(LR),
+        min_gain_raw=jnp.float32(gamma), all_reduce=record))(
+            bj, gj, hj, jnp.ones(n, jnp.float32))
+    jax.effects_barrier()
+    f0, t0 = np.asarray(f0), np.asarray(t0)
+    assert len(taken) == 3 * depth + 3
+    empty = 0
+    for level in range(depth):
+        node = _route(binned, f0, t0, level)
+        rows_in = np.bincount(node, minlength=2 ** level)
+        for got, w in zip(taken[3 * level:3 * level + 3], (g, h, c)):
+            np.testing.assert_allclose(
+                got, _scatter_hist(binned, node, 2 ** level, w),
+                rtol=0, atol=1e-5)
+            assert (got[rows_in == 0] == 0).all()       # exact zeros
+        empty += int((rows_in == 0).sum())
+    if case == "empty-slots-d4":
+        assert t0[0] < B and t0[1] == B and t0[2] < B and empty >= 3
+
+    # the same tree through the entry the fitters use, against the reference
+    feat, thresh, leaf = gk.grow_tree(
+        bj, gj, hj, jnp.ones(n, jnp.float32), max_depth=depth, n_bins=B,
+        lam=LAM, min_child_weight=MCW, min_instances=min_inst,
+        learning_rate=LR, min_gain_raw=gamma)
+    np.testing.assert_array_equal(np.asarray(feat), f0)
+    np.testing.assert_array_equal(np.asarray(thresh), t0)
+    want_f, want_t, node = hist_gbt.grow_tree(
+        binned, np.asarray(g, float)[:, None], np.asarray(h, float)[:, None],
+        c, depth, B, LAM, min_child_weight=MCW, gamma=gamma,
+        min_instances=min_inst)
+    assert (want_t < B).sum() >= 3, "a tree that hardly splits"
+    np.testing.assert_array_equal(f0, want_f)
+    np.testing.assert_array_equal(t0, want_t)
+    Gs = np.bincount(node, g, 2 ** depth)
+    Hs = np.bincount(node, h, 2 ** depth)
+    np.testing.assert_allclose(np.asarray(leaf)[:, 0], -LR * Gs / (Hs + LAM),
+                               rtol=0, atol=3e-7)
+
+
+def test_gbt_fit_grows_the_reference_s_boosted_trees():
+    """Six rounds of depth 4 on 3,000 x 16 through ``fit_raw`` (one chain
+    of ``_gbt_chain_rounds_jit``, f32 operands): the reference's features
+    and thresholds, round by round."""
+    from transmogrifai_tpu.models import OpGBTClassifier
+
+    rng = np.random.default_rng(5)
+    X = rng.normal(size=(3000, 16)).astype(np.float32)
+    y = (X @ rng.normal(size=16) > 0).astype(np.float32)
+    model = OpGBTClassifier(max_iter=6, max_depth=4, step_size=0.3,
+                            hist_precision="f32").fit_raw(X, y)
+    edges, base, trees = hist_gbt.fit_gbt(X, y, depth=4, rounds=6, eta=0.3,
+                                          lam=1.0, min_child_weight=1.0)
+    assert np.array_equal(np.asarray(model.edges), edges)
+    for t, (feat, thresh, leaf) in enumerate(trees):
+        assert np.array_equal(np.asarray(model.feat)[t], feat), t
+        assert np.array_equal(np.asarray(model.thresh)[t], thresh), t
+        np.testing.assert_allclose(np.asarray(model.leaf)[t, :, 0], leaf,
+                                   atol=1e-5)
+
+
+#: every ``TMOG_*`` environment variable the package names.  A new one is
+#: a new configuration for tests and benchmarks to cover: add it here in
+#: the PR that adds it, with the two callers that need different values.
+TMOG_NAMES = {
+    "TMOG_AOT_CACHE_DIR", "TMOG_BLOCK_KERNELS", "TMOG_CHECK",
+    "TMOG_COLLECTIVE_TIMEOUT", "TMOG_COST_HISTORY", "TMOG_DISABLE_NATIVE",
+    "TMOG_EFB", "TMOG_FAULTS", "TMOG_GOSS", "TMOG_HOST_BUDGET_MB",
+    "TMOG_MATRIX_PRECISION", "TMOG_PLAN_PARALLEL_MIN_ROWS",
+    "TMOG_PLAN_WORKERS", "TMOG_POD_COORDINATOR", "TMOG_POD_LOCAL_DEVICES",
+    "TMOG_POD_NUM_PROCESSES", "TMOG_POD_PROCESS_ID",
+    "TMOG_SEQUENTIAL_EXECUTOR", "TMOG_STREAM_RETAIN_MB", "TMOG_SYNC_SWEEP",
+}
+
+
+def test_the_package_names_no_other_switch():
+    pkg = os.path.dirname(os.path.dirname(gk.__file__))
+    found = set()
+    for root, _, files in os.walk(pkg):
+        for fn in files:
+            if fn.endswith(".py"):
+                with open(os.path.join(root, fn), encoding="utf-8") as f:
+                    found |= set(re.findall(r"TMOG_[A-Z0-9_]+", f.read()))
+    found.discard("TMOG_POD_")          # the prose's "TMOG_POD_*"
+    assert found == TMOG_NAMES

@@ -385,7 +385,7 @@ class TestMeshFitJoinsTheSweepsPreparation:
         w = np.ones(1001, np.float32)
         w[-3:] = 0.0
         trees.clear_sweep_caches()
-        swept, _, _ = trees._prep_tree_inputs_weighted(X, 32, row_weight=w)
+        swept, _ = trees._prep_tree_inputs_weighted(X, 32, row_weight=w)
         profiling.reset_counters()
         edges, _ = trees._prep_tree_inputs_mesh(X, 32, mesh4)
         memo = profiling.COUNTERS.to_json()["memoTags"]
@@ -405,7 +405,7 @@ class TestMeshFitJoinsTheSweepsPreparation:
         X = rng.normal(size=(1001, 6)).astype(np.float32)
         y = (X[:, 0] > 0).astype(np.float32)
         trees.clear_sweep_caches()
-        edges, binned, _ = trees._prep_tree_inputs_weighted(X, 32)
+        edges, binned = trees._prep_tree_inputs_weighted(X, 32)
         profiling.reset_counters()
         model = (models.OpXGBoostClassifier(num_round=2, max_depth=3)
                  .with_mesh(mesh4).fit_raw(X, y))

@@ -1,22 +1,17 @@
-"""Sparse histogram path (round 4) — XGBoost-core sparsity parity.
+"""Sparse-aware quantile sketch — XGBoost-core sparsity parity.
 
 The reference's only native component (xgboost4j's C++ hist core,
-OpXGBoostClassifier.scala:47) is sparsity-aware twice over: the quantile
-sketch runs on present values, and histogram accumulation touches only
-stored entries.  This suite pins both TPU-native equivalents:
-
- * ``quantile_bins_sparse_aware`` — mostly-zero features spend their bins
-   on the nonzeros (an all-values sketch collapses to ~2 usable bins);
- * ``build_feature_csr`` + ``_sparse_level_hists`` — per-feature CSR
-   histogram build over the ~density·N·D nonzero entries with the zero
-   bin reconstructed analytically (zero-bin = node totals − nonzero sums),
-   verified against the dense kernel on identical edges.
+OpXGBoostClassifier.scala:47) runs its quantile sketch on present values.
+``quantile_bins_sparse_aware`` is the equivalent here: mostly-zero features
+spend their bins on the nonzeros (an all-values sketch collapses to ~2
+usable bins), with an edge pinned at 0.0.  The histograms of such a matrix
+are built like any other's (tests/test_hist_onehot_layout.py holds them to
+the references on sparse-aware edges).
 """
 import numpy as np
-import pytest
 
 from transmogrifai_tpu.models.gbdt_kernels import (
-    build_feature_csr, grow_tree, quantile_bins, quantile_bins_sparse_aware,
+    quantile_bins, quantile_bins_sparse_aware,
 )
 
 
@@ -51,77 +46,27 @@ class TestSparseSketch:
                                    quantile_bins(X, 16), atol=1e-6)
 
 
-class TestCsrBuild:
-    def test_entries_and_zero_bin(self):
-        X, _ = _sparse_data(2000, 12)
-        edges = quantile_bins_sparse_aware(X, 16)
-        rows, bins, zero_bin = build_feature_csr(X, edges)
-        n, d = X.shape
-        for j in range(d):
-            idx = np.nonzero(X[:, j])[0]
-            assert (rows[j, :len(idx)] == idx).all()
-            assert (rows[j, len(idx):] == n).all()        # sentinel padding
-            # bins match the dense quantizer on those entries
-            e = np.sort(edges[j])
-            expect = np.searchsorted(e, X[idx, j], side="left")
-            np.testing.assert_array_equal(bins[j, :len(idx)], expect)
-            assert zero_bin[j] == np.searchsorted(e, 0.0, side="left")
-
-    def test_declines_dense_and_outlier_matrices(self):
-        rng = np.random.default_rng(1)
-        dense = rng.normal(size=(500, 8)).astype(np.float32)
-        assert build_feature_csr(dense, quantile_bins(dense, 8)) is None
-        X, _ = _sparse_data(2000, 12)
-        X[:, 0] = 1.0                                     # one dense column
-        assert build_feature_csr(
-            X, quantile_bins_sparse_aware(X, 8)) is None
-
-
-class TestSparseKernelParity:
-    @pytest.mark.parametrize("depth", [3, 6])
-    def test_sparse_tree_equals_dense_kernel(self, depth):
-        """Identical edges + identical channels: the CSR build with
-        analytic zero-bin must reproduce the dense kernel's tree."""
-        import jax.numpy as jnp
-
-        from transmogrifai_tpu.models.gbdt_kernels import apply_bins
-
-        X, y = _sparse_data(3000, 24)
-        edges = quantile_bins_sparse_aware(X, 16)
-        binned = apply_bins(jnp.asarray(X), jnp.asarray(edges))
-        rows, bins, zero_bin = build_feature_csr(X, edges)
-        csr = (jnp.asarray(rows), jnp.asarray(bins),
-               jnp.asarray(np.eye(16, dtype=np.float32)[zero_bin]))
-        G = jnp.asarray((0.5 - y)[:, None])
-        H = jnp.asarray(np.full((len(y), 1), 0.25, np.float32))
-        C = jnp.asarray(np.ones(len(y), np.float32))
-        kw = dict(max_depth=depth, n_bins=16, lam=1.0,
-                  min_instances=5.0, newton_leaf=True)
-        f_d, t_d, l_d = grow_tree(binned, G, H, C, **kw)
-        f_s, t_s, l_s = grow_tree(binned, G, H, C, csr=csr, **kw)
-        np.testing.assert_array_equal(np.asarray(f_s), np.asarray(f_d))
-        np.testing.assert_array_equal(np.asarray(t_s), np.asarray(t_d))
-        np.testing.assert_allclose(np.asarray(l_s), np.asarray(l_d),
-                                   atol=1e-5)
-
-
 class TestSparseEndToEnd:
     def test_xgb_sparse_fit_engages_and_learns(self, monkeypatch):
-        """A wide mostly-zero fit takes the CSR path end to end (prep
-        detection -> scan-chunk rounds) and still learns the signal."""
+        """A wide mostly-zero fit takes the sparse-aware sketch end to end
+        (prep detection -> ``edges_sp`` memo -> scan-chunk rounds) and
+        learns the signal."""
         import transmogrifai_tpu.models.trees as trees_mod
         from transmogrifai_tpu.evaluators.metrics import aupr
         from transmogrifai_tpu.models.trees import OpXGBoostClassifier
+        from transmogrifai_tpu.utils import profiling
 
-        # drop the size floor so the small test matrix qualifies; opt into
-        # the CSR histogram path (default off — see _prep_tree_inputs_sparse)
+        # drop the size floor so the small test matrix qualifies
         monkeypatch.setattr(trees_mod, "_SPARSE_MIN_ELEMS", 1)
-        monkeypatch.setenv("TMOG_SPARSE_HIST", "1")
         X, y = _sparse_data(6000, 50, density=0.08, seed=9)
-        edges, binned, csr = trees_mod._prep_tree_inputs_sparse(X, 32)
-        assert csr is not None, "sparse path should engage on 92%-zero data"
+        trees_mod.clear_sweep_caches()
+        profiling.reset_counters()
         est = OpXGBoostClassifier(num_round=15, eta=0.3, max_depth=4,
                                   gamma=0.0, early_stopping_rounds=0)
         model = est.fit_raw(X, y)
+        memo = profiling.COUNTERS.to_json()["memoTags"]
+        assert memo["edges_sp"]["builds"] == 1 and "edges" not in memo, memo
+        assert (np.asarray(model.edges) == 0.0).any(axis=1).all(), \
+            "an edge pinned at 0.0 in every mostly-zero feature"
         score = model.predict_batch(X).probability[:, 1]
         assert float(aupr(y, score)) > 0.80

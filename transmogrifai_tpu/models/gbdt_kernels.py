@@ -10,9 +10,10 @@ Design (gpu_hist-style, adapted to XLA):
  * features pre-quantized to ``max_bins`` integer bins (quantile sketch on a
    sample, host-side; binned matrix lives in HBM as int8/int32)
  * trees grow level-wise; every level is one jitted kernel:
-     - histogram: scatter-add of [grad(K), hess(K), count] into
-       (nodes, D, B, 2K+1) via one flattened ``.at[].add`` — XLA lowers this
-       to an efficient sort/segment pattern on TPU
+     - histogram: [grad(K), hess(K), count] summed into (nodes, B, D) by
+       ONE formulation, a one-hot ``dot`` over rows (node one-hot times
+       channels against the bins one-hot; ``_grow_tree_traced``), hoisted
+       or row-blocked, full width or feature subset
      - split search: cumulative sums over bins -> best (feature, bin) per
        node by the standard gain formula  GL²/(HL+λ) + GR²/(HR+λ) − G²/(H+λ)
      - partition: rows move to ``2*node + go_right`` (no data movement — just
@@ -246,53 +247,6 @@ def quantile_bins_sparse_aware(X: np.ndarray, max_bins: int = 32,
         edges[j, :len(e)] = e[:max_bins - 1]
         # keep strictly increasing (dedup collapsed to +inf tail already)
     return edges
-
-
-def build_feature_csr(X: np.ndarray, edges: np.ndarray
-                      ) -> Optional[Tuple[np.ndarray, np.ndarray,
-                                          np.ndarray]]:
-    """Per-feature padded CSR of the NONZERO entries, for the sparse
-    histogram path: returns (rows (D, NZ) int32, bins (D, NZ) int8,
-    zero_bin (D,) int8) or None when the matrix doesn't qualify.
-
-    ``rows`` is padded with the sentinel N (gathers index a zero-padded
-    channel row, so pad entries contribute nothing); ``zero_bin[j]`` is the
-    bin value 0.0 falls in — the kernel reconstructs that bin's row
-    analytically (zero-bin = node totals − nonzero sums), so the histogram
-    build touches only the ~5% nonzero entries (VERDICT r3 Missing #4).
-
-    Qualification: overall density ≤ 0.25 and no near-dense outlier column
-    (max nnz ≤ 4× mean) — one dense column would pad every feature's CSR
-    to its length.
-    """
-    X = np.asarray(X)
-    n, d = X.shape
-    if edges.shape[1] + 1 > 127:
-        return None   # bins/zero_bin are int8; decline rather than wrap
-    mask = X != 0
-    nnz = mask.sum(axis=0)
-    total = int(nnz.sum())
-    if total == 0 or total / (n * d) > 0.25:
-        return None
-    nz_max = int(nnz.max())
-    if nz_max > max(4.0 * total / d, 64.0):
-        return None
-    rows = np.full((d, nz_max), n, np.int32)
-    bins = np.zeros((d, nz_max), np.int8)
-    for j in range(d):
-        idx = np.nonzero(mask[:, j])[0]
-        rows[j, :len(idx)] = idx
-        e = np.sort(edges[j])
-        vals = X[idx, j].astype(np.float32)
-        b = np.searchsorted(e, vals, side="left").astype(np.int8)
-        # NaN entries (counted as "nonzero" by the mask) follow the dense
-        # binning convention: pinned to bin 0 (trees._device_bins) so the
-        # histogram credits them where routing actually sends them
-        bins[j, :len(idx)] = np.where(np.isnan(vals), np.int8(0), b)
-    zero_bin = np.asarray(
-        [np.searchsorted(np.sort(edges[j]), 0.0, side="left")
-         for j in range(d)], np.int8)
-    return rows, bins, zero_bin
 
 
 # ---------------------------------------------------------------------------
@@ -580,239 +534,6 @@ def _goss_select(ga, key, k_top: int, k_rest: int):
     return idx, mult
 
 
-# ---------------------------------------------------------------------------
-# Segmented (sort-by-node) histogram accumulation — the Pallas VMEM path
-# ---------------------------------------------------------------------------
-#
-# The dense formulation pays 2·N·nchan·M·B·D dot FLOPs per level (every row
-# multiplied against every node slot) and streams an (N, B·D) one-hot
-# through HBM — measured ~50x above the HLO bytes floor (VERDICT r4 #2).
-# Here rows are SORTED by node slot and each slot's run padded to a
-# multiple of ``SEG_ROW_BLOCK``, so every row block belongs to exactly one
-# slot: a Pallas grid step builds its block's bins one-hot in VMEM (never
-# HBM) and reduces it straight into that single slot's histogram row — no
-# M factor in the FLOPs, no one-hot materialization.
-#
-# Dense amortizes its (rows, B·D) one-hot across vmapped chains; seg pays
-# a per-chain sort + row gather (``_seg_align``) with nothing to share.
-# Hence auto engages only for LOW-chain-count programs at large N (single
-# GBT/XGB fits, config-5-class shapes, budget-chunked launches); wide
-# lockstep sweeps keep the dense shared-one-hot formulation.  The kernel
-# goes through Mosaic on the v5e and matches the dense path at 500
-# columns (chip_smoke.py's GBT leg); the gate's thresholds below were set
-# from measurements on an earlier installation and have not been
-# re-measured on this one (ROADMAP Queue 3 item 4 decides them).
-
-#: rows per Pallas grid step == slot-run padding alignment
-SEG_ROW_BLOCK = 128
-#: feature-axis tile (B * SEG_D_BLOCK columns of one-hot per step in VMEM)
-SEG_D_BLOCK = 512
-#: auto mode: segmented path from this many rows
-SEG_MIN_ROWS = 250_000
-#: auto mode: dense's cross-chain one-hot sharing takes over above this
-#: many chains per launch
-SEG_MAX_CHAINS = 2
-#: histogram slots above which the padding overhead (M * SEG_ROW_BLOCK
-#: rows) stops paying — depth <= 10 chains stay under this
-SEG_MAX_SLOTS = 512
-
-
-def seg_hist_auto(n_rows: int, n_chains: int = 1) -> bool:
-    """Resolve the segmented-histogram flag for a program growing
-    ``n_chains`` trees per launch over ``n_rows`` rows (called by the
-    non-jitted fitters so the choice is a static jit-cache-key arg).
-    ``TMOG_SEG_HIST``: '1' force on, '0' force off, 'auto' (default)."""
-    import os
-
-    v = os.environ.get("TMOG_SEG_HIST", "auto")
-    if v == "1":
-        return True
-    if v == "0":
-        return False
-    # TPU only: the kernel uses pltpu grid specs (interpret-mode runs
-    # cover CPU tests; other accelerators would fail to lower)
-    return (n_rows >= SEG_MIN_ROWS and n_chains <= SEG_MAX_CHAINS
-            and jax.default_backend() == "tpu")
-
-
-def _seg_kernel(bs_ref, binned_ref, ch_ref, out_ref, *, n_bins: int,
-                d_blk: int, nchan: int):
-    """One grid step: reduce an (A, B·d_blk) bins one-hot (built in VMEM)
-    into this block's slot's histogram row.  Out block is selected by the
-    scalar-prefetched block->slot map; consecutive blocks of one slot
-    accumulate in VMEM and flush once on slot change."""
-    import jax.experimental.pallas as pl
-
-    i_r = pl.program_id(1)
-
-    @pl.when((i_r == 0) | (bs_ref[i_r] != bs_ref[jnp.maximum(i_r - 1, 0)]))
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    rows = binned_ref[...].astype(jnp.int32)            # (A, d_blk)
-    ch = ch_ref[...]                                    # (A, nchan)
-    b_iota = jax.lax.broadcasted_iota(
-        jnp.int32, (SEG_ROW_BLOCK, n_bins, d_blk), 1)
-    oh = rows[:, None, :] == b_iota                     # (A, B, d_blk)
-    parts = []
-    for c in range(nchan):
-        w = ch[:, c][:, None, None]
-        parts.append(jnp.sum(jnp.where(oh, w, 0.0), axis=0))  # (B, d_blk)
-    out_ref[0] = out_ref[0] + jnp.concatenate(parts, axis=0)
-
-
-def _seg_align(slot, binned_pad_cols, chans, M: int):
-    """Sort rows by slot and pad each slot's run to a SEG_ROW_BLOCK
-    multiple.  Returns (block_slots (n_blocks,) int32, binned (N', d)
-    reordered, ch (N', nchan) reordered; padded rows carry zero channel
-    weight so they contribute nothing to their block's slot."""
-    A = SEG_ROW_BLOCK
-    n = slot.shape[0]
-    ch = jnp.stack(chans, axis=1)
-    perm = jnp.argsort(slot)
-    ss = slot[perm]
-    sl_ids = jnp.arange(M, dtype=ss.dtype)
-    starts = jnp.searchsorted(ss, sl_ids, side="left",
-                              method="compare_all").astype(jnp.int32)
-    ends = jnp.searchsorted(ss, sl_ids, side="right",
-                            method="compare_all").astype(jnp.int32)
-    counts = ends - starts
-    # every slot gets AT LEAST one (all-padding) block: an empty slot with
-    # no block would never be visited by the kernel grid, leaving its
-    # output row UNINITIALIZED HBM (empty nodes are routine — a no-split
-    # node routes every row left, emptying the right child).  The padding
-    # block's zeroed channels write exact zeros, matching the dense path.
-    padded = jnp.maximum(-(-counts // A), 1) * A
-    pad_off = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), jnp.cumsum(padded)[:-1].astype(jnp.int32)])
-    n_pad = (-(-n // A) + M) * A
-    # per-slot quantities resolve at BLOCK granularity then broadcast to
-    # rows: a positionwise searchsorted lowers to a sequential scan over
-    # MB-scale vectors (~110 ms/level at 1M — measured)
-    blk_start = pad_off // A
-    bi = jnp.arange(n_pad // A, dtype=jnp.int32)
-    bs_blk = (jnp.searchsorted(blk_start, bi, side="right",
-                               method="compare_all").astype(jnp.int32) - 1)
-    bs_blk = jnp.clip(bs_blk, 0, M - 1)
-
-    def widen(v_blk):
-        return jnp.broadcast_to(v_blk[:, None], (n_pad // A, A)).reshape(-1)
-
-    p = jnp.arange(n_pad, dtype=jnp.int32)
-    off = p - widen(pad_off[bs_blk])
-    valid = off < widen(counts[bs_blk])
-    src_sorted = jnp.where(valid, widen(starts[bs_blk]) + off, 0)
-    src = perm[src_sorted]
-    # padding rows alias row perm[0]'s bins but carry ZERO channel weight —
-    # they contribute nothing to their block's slot, so only the channel
-    # matrix needs masking (a masked rewrite of the (N', d) binned copy
-    # cost a full extra memory pass)
-    binned_sorted = binned_pad_cols[src]
-    ch_sorted = jnp.where(valid[:, None], ch[src], 0.0)
-    return bs_blk, binned_sorted, ch_sorted
-
-
-def _seg_level_hists(binned_seg, slot, chans, M: int, B: int, d: int):
-    """One level's per-channel histograms [(M, B, d)] via the segmented
-    Pallas kernel.  ``binned_seg`` is the full-width matrix with its
-    feature axis pre-padded to a SEG_D_BLOCK multiple (hoisted out of the
-    level loop by the caller); accumulation is f32 (the one-hot never
-    materializes, so there is no bf16 stream to halve — hist_bf16 is a
-    no-op on this path)."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    A = SEG_ROW_BLOCK
-    nchan = len(chans)
-    d_pad = binned_seg.shape[1]
-    bs, bp, cp = _seg_align(slot, binned_seg, chans, M)
-    n_rb = bp.shape[0] // A
-    n_db = d_pad // SEG_D_BLOCK
-    out = pl.pallas_call(
-        functools.partial(_seg_kernel, n_bins=B, d_blk=SEG_D_BLOCK,
-                          nchan=nchan),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_db, n_rb),
-            in_specs=[
-                pl.BlockSpec((A, SEG_D_BLOCK),
-                             lambda i_d, i_r, bs: (i_r, i_d)),
-                pl.BlockSpec((A, nchan), lambda i_d, i_r, bs: (i_r, 0)),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, nchan * B, SEG_D_BLOCK),
-                lambda i_d, i_r, bs: (bs[i_r], 0, i_d)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((M, nchan * B, d_pad), jnp.float32),
-        interpret=jax.default_backend() != "tpu",
-    )(bs, bp, cp)
-    return [out[:, c * B:(c + 1) * B, :d] for c in range(nchan)]
-
-
-#: sparse-path entry block: bounds the transient (D, Eb, M) slot one-hot
-SPARSE_ENTRY_BLOCK_ELEMS = 1 << 28
-#: above this many slots the (entries, M) one-hot exceeds the dense bins
-#: stream (breakeven ~ density·(M + B·nchan) vs ~2.5·B) — fall back dense
-SPARSE_MAX_SLOTS = 2048
-
-
-def _sparse_level_hists(csr_rows, csr_bins, zero_b_oh, slot, chans,
-                        Mh: int, B: int, hdt, dot_prec):
-    """One level's histograms from the nonzero entries only.
-
-    ``hist[c][m, b, j] = Σ_e ch_c[row(j,e)]·1[slot=m]·1[bin=b]`` as a
-    feature-batched matmul ``(D, M, E)@(D, E, B·nchan)`` — the plain slot
-    one-hot is the big operand (E·M), the channel values ride the SMALL
-    bins one-hot (E·B·nchan) — with the zero-bin row reconstructed
-    analytically: zero-bin = per-slot channel totals (one tiny scatter-add
-    over rows) − the nonzero sums.  Touches ~density·N·D entries instead
-    of the full N·B·D one-hot stream.
-    """
-    n = slot.shape[0]
-    d, nz = csr_rows.shape
-    nchan = len(chans)
-    # sentinel row n -> zero-padded channel row (pad entries contribute 0)
-    slot_pad = jnp.concatenate([slot, jnp.zeros(1, jnp.int32)])
-    ch_pad = jnp.concatenate(
-        [jnp.stack(chans, axis=1),
-         jnp.zeros((1, nchan), chans[0].dtype)])          # (N+1, nchan)
-
-    eb = max(1, min(nz, SPARSE_ENTRY_BLOCK_ELEMS // max(d * Mh, 1)))
-    n_blocks = -(-nz // eb)
-    pad = n_blocks * eb - nz
-    rows_b = jnp.pad(csr_rows, ((0, 0), (0, pad)),
-                     constant_values=n).reshape(d, n_blocks, eb)
-    bins_b = jnp.pad(csr_bins, ((0, 0), (0, pad))).reshape(d, n_blocks, eb)
-    rows_b = jnp.swapaxes(rows_b, 0, 1)                   # (blocks, D, Eb)
-    bins_b = jnp.swapaxes(bins_b, 0, 1)
-
-    def block(acc, xs):
-        r_b, b_b = xs                                      # (D, Eb)
-        sl = slot_pad[r_b]                                 # (D, Eb)
-        oh_m = (sl[:, :, None] == jnp.arange(Mh)[None, None, :]).astype(hdt)
-        vals = ch_pad[r_b].astype(hdt)                     # (D, Eb, nchan)
-        oh_b = (b_b[:, :, None] == jnp.arange(B)[None, None, :]).astype(hdt)
-        wb = (oh_b[:, :, :, None] * vals[:, :, None, :]).reshape(
-            d, -1, B * nchan)                              # (D, Eb, B·nchan)
-        part = jax.lax.dot_general(
-            jnp.swapaxes(oh_m, 1, 2), wb,
-            (((2,), (1,)), ((0,), (0,))),                  # (D, M, B·nchan)
-            precision=dot_prec, preferred_element_type=jnp.float32)
-        return acc + part, None
-
-    acc0 = jnp.zeros((d, Mh, B * nchan), jnp.float32)
-    hist_sp, _ = lax.scan(block, acc0, (rows_b, bins_b))
-    hist_sp = hist_sp.reshape(d, Mh, B, nchan)
-    # per-slot channel totals over ALL rows: one (N, nchan) scatter-add
-    tot = jnp.zeros((Mh, nchan), jnp.float32).at[slot].add(
-        jnp.stack(chans, axis=1), mode="drop")             # (M, nchan)
-    zero_contrib = tot[None] - hist_sp.sum(axis=2)         # (D, M, nchan)
-    hist_sp = hist_sp + (zero_contrib[:, :, None, :]
-                         * zero_b_oh[:, None, :, None])
-    return [jnp.transpose(hist_sp[..., c], (1, 2, 0))      # (M, B, D)
-            for c in range(nchan)]
-
-
 def default_dir_mask(edges) -> np.ndarray:
     """(D,) bool: features whose bin 0 is a GENUINE missing/absent bucket —
     their smallest finite bin edge is the sparse-aware sketch's pinned 0.0
@@ -842,10 +563,9 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
                       learning_rate, hist_bf16: bool = False,
                       all_reduce=None, min_gain_raw=None,
                       bag_mode: str = "none", feat_idx=None,
-                      leaf_levels: Tuple[int, ...] = (), csr=None,
-                      seg_hist: bool = False, default_dir: bool = False,
-                      dd_mask=None, bundle_end=None,
-                      acc_bf16: bool = False):
+                      leaf_levels: Tuple[int, ...] = (),
+                      default_dir: bool = False, dd_mask=None,
+                      bundle_end=None, acc_bf16: bool = False):
     """One whole tree under trace: Python-unrolled loop over levels.
 
     ``bundle_end``: optional (B, D) int32 per-(threshold, feature) member
@@ -863,14 +583,6 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
     already ride ``hist_bf16``) and upcast to f32 at the level cumsum —
     the TMOG_MATRIX_PRECISION=bf16 opt-in, quality-gated by the TM028
     tolerance probe.
-
-    ``csr``: optional (rows (D, NZ) int32, bins (D, NZ) int8,
-    zero_bin_onehot (D, B)) device triple from ``build_feature_csr`` — wide
-    mostly-zero matrices then build each level's histograms from the
-    nonzero entries only (``_sparse_level_hists``), with the zero bin
-    recovered analytically.  Split search, routing, and leaves are
-    unchanged (the dense int8 matrix still routes rows).  Incompatible
-    with ``feat_idx`` and ``all_reduce`` (callers guard).
 
     ``leaf_levels``: static sorted levels at which to ALSO emit the leaf
     values of the depth-ℓ TRUNCATION of this tree (one (2^ℓ, K) array per
@@ -1038,15 +750,6 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
             wnode.T, oh_bins, (((1,), (rows_axis,)), ((), ())),
             precision=dot_prec, preferred_element_type=adt)
 
-    # segmented (sort-by-node) histogram path: resolved statically by the
-    # callers (seg_hist_auto); engages per level at Mh <= SEG_MAX_SLOTS
-    seg = (seg_hist and csr is None and feat_idx is None
-           and all_reduce is None)
-    if seg:
-        d_pad = -(-d // SEG_D_BLOCK) * SEG_D_BLOCK
-        binned_seg = (binned_full if d_pad == d
-                      else jnp.pad(binned_full, ((0, 0), (0, d_pad - d))))
-
     blocked = n > ROW_BLOCK
     if blocked:
         n_blocks = -(-n // ROW_BLOCK)
@@ -1117,12 +820,7 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
                     oh = slot_v[:, None] == jnp.arange(Mh)[None, :]
                 return oh.astype(hdt)
 
-            if seg and not sib and Mh <= SEG_MAX_SLOTS:
-                hists = _seg_level_hists(binned_seg, slot, chans, Mh, B, d)
-            elif csr is not None and not sib and Mh <= SPARSE_MAX_SLOTS:
-                hists = _sparse_level_hists(csr[0], csr[1], csr[2], slot,
-                                            chans, Mh, B, hdt, dot_prec)
-            elif blocked:
+            if blocked:
                 slot_blk = jnp.pad(slot, (0, n_pad - n)).reshape(
                     n_blocks, ROW_BLOCK)
 
@@ -1399,15 +1097,13 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
 
 @functools.partial(jax.jit,
                    static_argnames=("max_depth", "n_bins", "hist_bf16",
-                                    "seg_hist", "default_dir", "goss",
-                                    "acc_bf16"))
+                                    "default_dir", "goss", "acc_bf16"))
 def _grow_chunk(binned, G, H, C, feat_mask, depth_limit, max_depth: int,
                 n_bins: int, lam, min_child_weight, min_info_gain,
                 min_instances, newton_leaf, learning_rate,
-                hist_bf16: bool = False, min_gain_raw=0.0, csr=None,
-                seg_hist: bool = False, default_dir: bool = False,
-                dd_mask=None, bundle_end=None, acc_bf16: bool = False,
-                goss=None, goss_key=None):
+                hist_bf16: bool = False, min_gain_raw=0.0,
+                default_dir: bool = False, dd_mask=None, bundle_end=None,
+                acc_bf16: bool = False, goss=None, goss_key=None):
     """Grow a chunk of trees in one XLA program.
 
     binned (N, D) shared; G/H (T, N, K), C (T, N), feat_mask (T, D),
@@ -1415,14 +1111,14 @@ def _grow_chunk(binned, G, H, C, feat_mask, depth_limit, max_depth: int,
     Returns (feat (T, 2^d-1), thresh (T, 2^d-1), leaf (T, 2^d, K)).
     ``goss``: static (k_top, k_rest) GOSS budget — each tree then grows
     on its own gradient-selected row gather (``goss_key`` folded per
-    tree), with csr/seg paths declined by the callers.
+    tree).
     """
     kw = dict(max_depth=max_depth, n_bins=n_bins,
               lam=lam, min_child_weight=min_child_weight,
               min_info_gain=min_info_gain, min_instances=min_instances,
               newton_leaf=newton_leaf, learning_rate=learning_rate,
-              hist_bf16=hist_bf16, min_gain_raw=min_gain_raw, csr=csr,
-              seg_hist=seg_hist, default_dir=default_dir, dd_mask=dd_mask,
+              hist_bf16=hist_bf16, min_gain_raw=min_gain_raw,
+              default_dir=default_dir, dd_mask=dd_mask,
               bundle_end=bundle_end, acc_bf16=acc_bf16)
     if goss is not None:
         k_top, k_rest = goss
@@ -1841,15 +1537,15 @@ def _gbt_chain_round_jit(binned, y, W, Fm, depth_lim, lams, mcws, migs,
 @functools.partial(jax.jit, static_argnames=("n_rounds", "max_depth",
                                              "n_bins", "obj", "hist_bf16",
                                              "use_es", "skip_counts",
-                                             "seg_hist", "default_dir",
-                                             "goss", "acc_bf16"))
+                                             "default_dir", "goss",
+                                             "acc_bf16"))
 def _gbt_chain_rounds_jit(binned, y, W, Fm0, vi, depth_lim, lams, mcws,
                           migs, mins_, lrs, mgrs, n_rounds: int,
                           max_depth: int, n_bins: int, obj: str,
                           hist_bf16: bool = False, use_es: bool = False,
-                          csr=None, skip_counts: bool = False,
-                          seg_hist: bool = False, default_dir: bool = False,
-                          dd_mask=None, bundle_end=None,
+                          skip_counts: bool = False,
+                          default_dir: bool = False, dd_mask=None,
+                          bundle_end=None,
                           acc_bf16: bool = False, goss=None,
                           goss_seed=None, chain_ids=None,
                           round_offset=None):
@@ -1909,7 +1605,7 @@ def _gbt_chain_rounds_jit(binned, y, W, Fm0, vi, depth_lim, lams, mcws,
                     binned, g[:, None], h[:, None], c, mask, lim,
                     lam=lam, min_child_weight=mcw, min_info_gain=mig,
                     min_instances=mi, learning_rate=lr, min_gain_raw=mgr,
-                    csr=csr, seg_hist=seg_hist, **grow_kw)[:3]
+                    **grow_kw)[:3]
 
             f, t, lf = jax.vmap(one)(G, H, W, depth_lim, lams, mcws, migs,
                                      mins_, lrs, mgrs)
@@ -1969,7 +1665,6 @@ _chain_es_metric_jit = jax.jit(_chain_es_metric,
 
 def gbt_chain_chunk(n_chains: int, max_depth: int, d: int, n_bins: int,
                     n_rows: int, budget: int = 2 * HIST_BYTES_BUDGET,
-                    seg_hist: bool = False,
                     full_slots: bool = False,
                     goss_rows: Optional[int] = None) -> int:
     """Chains per round launch: the (ROW_BLOCK, B*D) bins one-hot is shared
@@ -1977,10 +1672,6 @@ def gbt_chain_chunk(n_chains: int, max_depth: int, d: int, n_bins: int,
     histogram accumulator.  The budget is deliberately larger than the
     forest chunker's — splitting a round across launches re-materializes
     the shared one-hot stream, the round's dominant cost.
-
-    ``seg_hist``: the segmented path has no shared one-hot, but each chain
-    transiently holds its slot-sorted padded copy of the binned matrix
-    ((N', d_pad) int8) plus the sort/align index vectors.
 
     ``full_slots``: the mesh-sharded chain path disables node compaction
     (shards must agree on the full 2^level slot layout), so its budget
@@ -1994,14 +1685,6 @@ def gbt_chain_chunk(n_chains: int, max_depth: int, d: int, n_bins: int,
     slots = 2 ** (max_depth - 1)
     if n_rows is not None and not full_slots:
         slots = min(slots, 1 << int(np.ceil(np.log2(max(n_rows, 2)))))
-    if seg_hist and slots <= SEG_MAX_SLOTS and goss_rows is None:
-        d_pad = -(-d // SEG_D_BLOCK) * SEG_D_BLOCK
-        n_pad = (-(-n_rows // SEG_ROW_BLOCK) + slots) * SEG_ROW_BLOCK
-        per_chain = int(n_pad * d_pad * 1.3          # sorted binned copy
-                        + n_pad * 8 * 4              # align index vectors
-                        + slots * n_bins * d * 3 * 4 * 1.3
-                        + n_rows * 4 * 4)
-        return int(np.clip(budget // max(per_chain, 1), 1, n_chains))
     rows = min(n_rows if goss_rows is None else goss_rows, ROW_BLOCK)
     onehot = int(rows * n_bins * d * 4 * 1.3)
     per_chain = int(slots * n_bins * d * 3 * 4 * 1.3
@@ -2022,7 +1705,6 @@ def grow_tree(binned: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
               feat_mask: Optional[jnp.ndarray] = None,
               newton_leaf: bool = True, learning_rate: float = 1.0,
               min_gain_raw: float = 0.0, hist_bf16: bool = False,
-              csr=None, seg_hist: Optional[bool] = None,
               default_dir: bool = False, dd_mask=None, bundle_end=None,
               acc_bf16: Optional[bool] = None,
               goss: Optional[Tuple[int, int]] = None, goss_key=None,
@@ -2032,21 +1714,16 @@ def grow_tree(binned: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
     ``bundle_end``: EFB member-end table — the matrix is then in bundled
     column space and the returned splits need ``unbundle_ensemble``.
     ``goss``/``goss_key``: static GOSS row budget + PRNG key (see
-    ``goss_plan``); incompatible with csr/seg (forced off here).
+    ``goss_plan``).
     """
-    n, d = binned.shape
     if feat_mask is None:
-        feat_mask = jnp.ones(d, bool)
+        feat_mask = jnp.ones(binned.shape[1], bool)
     heap_depth = _resolve_compile_depth(max_depth)
     hist_bf16 = hist_bf16 and _accel_bf16()
     if acc_bf16 is None:
         acc_bf16 = hist_accum_bf16()
-    if goss is not None:
-        csr, seg_hist = None, False
-        if goss_key is None:
-            goss_key = jax.random.PRNGKey(0)
-    if seg_hist is None:
-        seg_hist = seg_hist_auto(n)
+    if goss is not None and goss_key is None:
+        goss_key = jax.random.PRNGKey(0)
     limit = jnp.full((1,), max_depth, jnp.int32)
     f, t, lf = _grow_chunk(
         binned, G[None], H[None], C[None], feat_mask[None], limit,
@@ -2054,9 +1731,8 @@ def grow_tree(binned: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
         jnp.float32(min_info_gain), jnp.float32(min_instances),
         jnp.bool_(newton_leaf), jnp.float32(learning_rate),
         hist_bf16=hist_bf16, min_gain_raw=jnp.float32(min_gain_raw),
-        csr=csr, seg_hist=seg_hist, default_dir=default_dir,
-        dd_mask=dd_mask, bundle_end=bundle_end, acc_bf16=acc_bf16,
-        goss=goss, goss_key=goss_key)
+        default_dir=default_dir, dd_mask=dd_mask, bundle_end=bundle_end,
+        acc_bf16=acc_bf16, goss=goss, goss_key=goss_key)
     return f[0], t[0], lf[0]
 
 

@@ -560,6 +560,23 @@ def _prep_tree_inputs(X, max_bins):
     return edges, _binned_cached(Xf, hx, edges)
 
 
+#: sampled zero fraction at/above which the tree fit sketches its bin
+#: edges over the NONZERO values
+_SPARSE_ZERO_FRAC = 0.75
+#: below this element count the all-values sketch is kept
+_SPARSE_MIN_ELEMS = 1 << 24
+
+
+def _takes_sparse_sketch(Xf: np.ndarray) -> bool:
+    """THE rule that picks the sparse-aware sketch: a matrix of at least
+    ``_SPARSE_MIN_ELEMS`` elements whose sampled rows (every n/4096-th)
+    are at least ``_SPARSE_ZERO_FRAC`` zeros."""
+    if Xf.size < _SPARSE_MIN_ELEMS:
+        return False
+    step = max(1, Xf.shape[0] // 4096)
+    return float((Xf[::step] == 0).mean()) >= _SPARSE_ZERO_FRAC
+
+
 def _prep_tree_inputs_mesh(X, max_bins, mesh):
     """Quantile sketch + binning of a fit on a mesh.
 
@@ -585,12 +602,8 @@ def _prep_tree_inputs_mesh(X, max_bins, mesh):
     from ..parallel.sharded import quantile_bins_sharded
 
     Xf = _as_f32(X)
-    n = Xf.shape[0]
-    step = max(1, n // 4096)
-    if (Xf.size >= _SPARSE_MIN_ELEMS
-            and float((Xf[::step] == 0).mean()) >= _SPARSE_ZERO_FRAC):
-        e, b, _ = _prep_tree_inputs_sparse(Xf, max_bins)
-        return e, b
+    if _takes_sparse_sketch(Xf):
+        return _prep_tree_inputs_sparse(Xf, max_bins)
     hx = _content_hash(Xf)
     edges = _memo_peek(("edges", hx, Xf.shape, max_bins))
     if edges is None:
@@ -601,67 +614,26 @@ def _prep_tree_inputs_mesh(X, max_bins, mesh):
     return edges, _binned_cached(Xf, hx, edges)
 
 
-#: sampled zero fraction at/above which the tree fit takes the sparse path
-#: (nonzero-aware sketch + CSR histogram build)
-_SPARSE_ZERO_FRAC = 0.75
-#: below this element count the dense kernel is fast enough that CSR
-#: build cost isn't worth it
-_SPARSE_MIN_ELEMS = 1 << 24
-
-
 def _prep_tree_inputs_sparse(X, max_bins):
     """Like ``_prep_tree_inputs`` but detects wide mostly-zero matrices:
     their bin edges sketch over the NONZERO values
     (quantile_bins_sparse_aware) — an all-values sketch of a 95%-zero
     feature collapses to ~2 usable bins, while XGBoost's sketch is
     sparsity-aware (SURVEY §2.11); matching it measured +0.016 train AuPR
-    on the config-5 shape at the same round budget.
-
-    The third return element is the CSR device triple for the sparse
-    HISTOGRAM path (gbdt_kernels._sparse_level_hists) — opt-in via
-    ``TMOG_SPARSE_HIST=1``, default OFF: measured at 250k×1000×5% the
-    per-feature-batched CSR matmuls ((D, M, E)@(D, E, B·nchan), ~tens of
-    rows/cols per batch element) run ~2.2× SLOWER per round than the
-    dense bf16 one-hot stream at every slot width (1185-1353 ms vs 557 ms
-    per depth-10 round) — the MXU wants the dense formulation's big
-    tiles; the sparse win needs a Pallas accumulation kernel, not a
-    matmul reshuffle.  The build stays for that work (parity-tested in
-    tests/test_sparse_path.py).
+    on the config-5 shape at the same round budget.  Only the SKETCH is
+    sparse-aware: the histograms of such a matrix are built like any
+    other's.
     """
-    import os
-
-    from .gbdt_kernels import (
-        build_feature_csr, quantile_bins_sparse_aware,
-    )
+    from .gbdt_kernels import quantile_bins_sparse_aware
 
     Xf = _as_f32(X)
-    n, d = Xf.shape
-    if Xf.size < _SPARSE_MIN_ELEMS:
-        e, b = _prep_tree_inputs(Xf, max_bins)
-        return e, b, None
-    step = max(1, n // 4096)
-    if float((Xf[::step] == 0).mean()) < _SPARSE_ZERO_FRAC:
-        e, b = _prep_tree_inputs(Xf, max_bins)
-        return e, b, None
+    if not _takes_sparse_sketch(Xf):
+        return _prep_tree_inputs(Xf, max_bins)
     hx = _content_hash(Xf)
     edges = _memo(("edges_sp", hx, Xf.shape, max_bins),
                   lambda: quantile_bins_sparse_aware(Xf, max_bins),
                   span="tree.prep.sketch")
-    binned = _binned_cached(Xf, hx, edges)
-    if os.environ.get("TMOG_SPARSE_HIST", "0") != "1":
-        return edges, binned, None
-
-    def build():
-        host = build_feature_csr(Xf, edges)
-        if host is None:
-            return ()          # non-qualifying: memoized as empty, not None
-        rows, bins, zero_bin = host
-        zb_oh = np.eye(max_bins, dtype=np.float32)[zero_bin]   # (D, B)
-        return (_upload_timed(rows), _upload_timed(bins),
-                _upload_timed(zb_oh))
-    csr = _memo(("csr", hx, Xf.shape, max_bins), build,
-                span="tree.prep.csr")
-    return edges, binned, (csr if csr else None)
+    return edges, _binned_cached(Xf, hx, edges)
 
 
 def _efb_enabled() -> bool:
@@ -729,9 +701,7 @@ def _prep_tree_inputs_weighted(X, max_bins: int, row_weight=None):
         return _prep_tree_inputs_sparse(Xf, max_bins)
     Xm = np.ascontiguousarray(Xf[: nz[-1] + 1])
     hxm = _content_hash(Xm)
-    step = max(1, Xm.shape[0] // 4096)
-    if (Xm.size >= _SPARSE_MIN_ELEMS
-            and float((Xm[::step] == 0).mean()) >= _SPARSE_ZERO_FRAC):
+    if _takes_sparse_sketch(Xm):
         from .gbdt_kernels import quantile_bins_sparse_aware
 
         edges = _memo(("edges_sp", hxm, Xm.shape, max_bins),
@@ -741,7 +711,7 @@ def _prep_tree_inputs_weighted(X, max_bins: int, row_weight=None):
         edges = _memo(("edges", hxm, Xm.shape, max_bins),
                       lambda: quantile_bins(Xm, max_bins),
                       span="tree.prep.sketch")
-    return edges, _binned_cached(Xf, _content_hash(Xf), edges), None
+    return edges, _binned_cached(Xf, _content_hash(Xf), edges)
 
 
 def _feature_subset_size(strategy: str, d: int, is_classification: bool) -> int:
@@ -795,13 +765,12 @@ class _RandomForestBase(PredictorEstimator):
             edges, binned = _prep_tree_inputs_mesh(X, self.max_bins,
                                                    self.mesh)
         else:
-            # sparse-aware sketch (CSR unused — RF histograms run at
-            # feature-subset width): the SAME edges/memo keys as
+            # sparse-aware sketch: the SAME edges/memo keys as
             # RFGridGroup's sweep, so a winner refit on a qualifying sparse
             # matrix trains with the bin edges the candidate won selection
             # on (ADVICE r4 medium) and reuses the sweep's host sketch +
             # binned-matrix upload
-            edges, binned, _ = _prep_tree_inputs_sparse(X, self.max_bins)
+            edges, binned = _prep_tree_inputs_sparse(X, self.max_bins)
         base_w = (np.ones(n, np.float32) if w is None
                   else np.asarray(w, np.float32))
         if self._classification:
@@ -1024,16 +993,14 @@ class _GBTBase(PredictorEstimator):
     def fit_raw(self, X: np.ndarray, y: np.ndarray, w=None):
         n, d = X.shape
         if self.mesh is None:
-            # wide mostly-zero matrices take the sparse histogram path
-            # (nonzero-aware sketch + CSR build over the ~density·N·D
-            # nonzero entries; XGBoost-core parity, SURVEY §2.11)
-            edges, binned, csr = _prep_tree_inputs_sparse(X, self.max_bins)
+            # wide mostly-zero matrices sketch their edges over the
+            # nonzero values (XGBoost-core parity, SURVEY §2.11)
+            edges, binned = _prep_tree_inputs_sparse(X, self.max_bins)
         else:
             # the sweep's preparation where the memo holds it, else the
             # mesh-sharded sketch over ICI
             edges, binned = _prep_tree_inputs_mesh(X, self.max_bins,
                                                    self.mesh)
-            csr = None
         rng = np.random.default_rng(self.seed)
         base_w = (np.ones(n, np.float32) if w is None
                   else np.asarray(w, np.float32))
@@ -1100,7 +1067,7 @@ class _GBTBase(PredictorEstimator):
             # dispatch per chunk of rounds, not one per round
             return self._fit_scan_chunks(binned, edges, yj, twj, obj,
                                          float(base), use_es,
-                                         np.where(val)[0], csr=csr,
+                                         np.where(val)[0],
                                          integer_weights=bool(
                                              (train_w == np.floor(train_w))
                                              .all()),
@@ -1109,13 +1076,11 @@ class _GBTBase(PredictorEstimator):
         feats, threshs, leaves = [], [], []
         best_metric, best_len, stall = -np.inf, 0, 0
         val_idx = np.where(val)[0]
-        from .gbdt_kernels import default_dir_mask, seg_hist_auto
+        from .gbdt_kernels import default_dir_mask
         # default-direction eligibility from the bin edges (pinned-zero
-        # features only); segmented histograms never on the mesh path (the
-        # Pallas kernel has no GSPMD partitioning rule — code-review r5)
+        # features only)
         dd = (jnp.asarray(default_dir_mask(edges))
               if self.sparse_default_direction else None)
-        seg_seq = seg_hist_auto(n, 1) if self.mesh is None else False
         # early-stopping metrics fetch in CHUNKS: a per-round host sync
         # would stall the boosting pipeline 200 times per fit; the stall
         # decision replays per-round on host from the fetched
@@ -1154,8 +1119,7 @@ class _GBTBase(PredictorEstimator):
                 feat_mask=jnp.asarray(mask), newton_leaf=True,
                 learning_rate=self.step_size,
                 min_gain_raw=self.min_split_gain_raw,
-                hist_bf16=self._hist_bf16(), csr=csr,
-                seg_hist=seg_seq,
+                hist_bf16=self._hist_bf16(),
                 default_dir=self.sparse_default_direction, dd_mask=dd)
             from .gbdt_kernels import predict_tree
 
@@ -1198,7 +1162,7 @@ class _GBTBase(PredictorEstimator):
             n_classes=(k if obj == "multiclass" else 2))
 
     def _fit_scan_chunks(self, binned, edges, yj, twj, obj: str,
-                         base: float, use_es: bool, val_idx, csr=None,
+                         base: float, use_es: bool, val_idx,
                          integer_weights: bool = True,
                          hx: Optional[str] = None):
         """Whole-fit scan-chunked boosting: es_chunk rounds per launch via
@@ -1217,15 +1181,14 @@ class _GBTBase(PredictorEstimator):
         from .gbdt_kernels import (_gbt_chain_rounds_jit,
                                    _resolve_compile_depth, default_dir_mask,
                                    goss_plan, hist_accum_bf16,
-                                   seg_hist_auto, unbundle_ensemble)
+                                   unbundle_ensemble)
 
         n = int(binned.shape[0])
-        seg = seg_hist_auto(n, n_chains=1)
         dd_host = (default_dir_mask(edges)
                    if self.sparse_default_direction else None)
         bundles = None
         bend = None
-        if _efb_enabled() and csr is None and hx is not None:
+        if _efb_enabled() and hx is not None:
             eb = _maybe_bundle(hx, edges, binned, self.max_bins)
             if eb is not None:
                 bundles, binned, bend = eb
@@ -1233,8 +1196,6 @@ class _GBTBase(PredictorEstimator):
                     dd_host = bundles.bundled_dd_mask(dd_host)
         dd = jnp.asarray(dd_host) if dd_host is not None else None
         goss = goss_plan(n, self.max_depth)
-        if goss is not None:
-            csr, seg = None, False
         acc = hist_accum_bf16()
         # family compile-depth hint: sequential-fallback candidates of
         # differing max_depth share ONE compiled scan program (their own
@@ -1277,8 +1238,7 @@ class _GBTBase(PredictorEstimator):
                     one(self.min_instances_per_node),
                     one(self.step_size), one(self.min_split_gain_raw),
                     es_chunk, heap_depth, self.max_bins, obj,
-                    self._hist_bf16(), run_es, csr=csr,
-                    skip_counts=skip_counts, seg_hist=seg,
+                    self._hist_bf16(), run_es, skip_counts=skip_counts,
                     default_dir=self.sparse_default_direction, dd_mask=dd,
                     bundle_end=bend, acc_bf16=acc, goss=goss,
                     goss_seed=jnp.int32(self.seed),

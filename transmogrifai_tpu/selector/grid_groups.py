@@ -443,7 +443,6 @@ class RFGridGroup(TreeGridGroup):
                                           regression_metric_grid)
         from ..models.gbdt_kernels import grow_rf_grid
         from ..models.trees import (_dev_memo, _feature_subset_size,
-                                    _prep_tree_inputs_sparse,
                                     _score_ensemble_jit)
 
         cls = self.proto._classification
@@ -467,12 +466,11 @@ class RFGridGroup(TreeGridGroup):
         mb = int(self._param(self.grid_points[0], "max_bins"))
         # sparse-aware prep: same sketch/memo keys as the GBT group and
         # the selector's prefetch thread, so one host sketch serves the
-        # whole sweep (the CSR triple is unused here — RF histograms run
-        # at feature-subset width).  Weight-aware: zero-total-weight rows
+        # whole sweep.  Weight-aware: zero-total-weight rows
         # (mesh padding, balancer drops) never move the bin edges (TM024)
         from ..models.trees import _prep_tree_inputs_weighted
 
-        edges, binned, _ = _prep_tree_inputs_weighted(
+        edges, binned = _prep_tree_inputs_weighted(
             X, mb, row_weight=self._full_weights(weight_ctxs))
         n, d = X.shape
         if cls:
@@ -778,7 +776,7 @@ class GBTGridGroup(TreeGridGroup):
         from ..evaluators.metrics import (_aupr_dev, binary_metric_grid,
                                           regression_metric_grid)
         from ..models.gbdt_kernels import predict_ensemble, predict_tree
-        from ..models.trees import _dev_memo, _prep_tree_inputs_sparse
+        from ..models.trees import _dev_memo
         from ..utils.profiling import launch
 
         ests = self._chains()
@@ -805,7 +803,7 @@ class GBTGridGroup(TreeGridGroup):
         # the TM024 contract, balancer drops) must not move the bin edges
         from ..models.trees import _prep_tree_inputs_weighted
 
-        edges, binned, csr = _prep_tree_inputs_weighted(
+        edges, binned = _prep_tree_inputs_weighted(
             X, e0.max_bins, row_weight=self._full_weights(weight_ctxs))
         # EFB: pack the mutually exclusive one-hot/picklist columns into
         # shared histogram columns BEFORE any launch (both the single-chip
@@ -814,15 +812,14 @@ class GBTGridGroup(TreeGridGroup):
         binned_orig = binned
         bundles = None
         bend = None
-        if csr is None:
-            from ..models.trees import (_as_f32, _content_hash,
-                                        _efb_enabled, _maybe_bundle)
+        from ..models.trees import (_as_f32, _content_hash, _efb_enabled,
+                                    _maybe_bundle)
 
-            if _efb_enabled():
-                eb = _maybe_bundle(_content_hash(_as_f32(X)), edges,
-                                   binned, int(e0.max_bins))
-                if eb is not None:
-                    bundles, binned, bend = eb
+        if _efb_enabled():
+            eb = _maybe_bundle(_content_hash(_as_f32(X)), edges, binned,
+                               int(e0.max_bins))
+            if eb is not None:
+                bundles, binned, bend = eb
         d_hist = int(binned.shape[1])
         W_tr, W_ev = self._stack_weights(weight_ctxs)
         F = W_tr.shape[0]
@@ -892,8 +889,7 @@ class GBTGridGroup(TreeGridGroup):
         es_chunk = max(1, min(8, e0.early_stopping_rounds or 8))
         from ..models.gbdt_kernels import (_gbt_chain_rounds_jit,
                                            default_dir_mask, gbt_chain_chunk,
-                                           goss_plan, hist_accum_bf16,
-                                           seg_hist_auto)
+                                           goss_plan, hist_accum_bf16)
 
         # default-direction splits only on features whose bin 0 is a real
         # missing/zero bucket (sparse-aware pinned edge); bundle columns
@@ -910,20 +906,9 @@ class GBTGridGroup(TreeGridGroup):
                 if self.mesh is None else None)
         acc = hist_accum_bf16()
 
-        # segmented histograms at headline row counts (statically resolved
-        # so it keys the jit cache).  Chain count matters: dense shares its
-        # bins one-hot across vmapped chains, so seg only wins when the
-        # HBM budget (or the grid) leaves <= SEG_MAX_CHAINS per launch
-        chunk_dense = gbt_chain_chunk(
+        chunk = gbt_chain_chunk(
             S, heap_depth, d_hist, int(e0.max_bins), n,
             goss_rows=sum(goss) if goss is not None else None)
-        seg = seg_hist_auto(n, n_chains=min(chunk_dense, S))
-        chunk = (gbt_chain_chunk(S, heap_depth, d_hist,
-                                 int(e0.max_bins), n, seg_hist=True)
-                 if seg else chunk_dense)
-        if goss is not None:
-            csr, seg = None, False
-            chunk = chunk_dense
         run_es = use_es and vi is not None
         vi_arr = vi if vi is not None else jnp.zeros(1, jnp.int32)
         bf16 = e0._hist_bf16()   # backend-resolved: part of the jit key
@@ -1004,8 +989,8 @@ class GBTGridGroup(TreeGridGroup):
                     Fm, fs, ts, lfs, ms = _gbt_chain_rounds_jit(
                         binned, yj, Wj, Fm, vi_arr, depth_lim, lams, mcws,
                         migs, mins_, lrs, mgrs, es_chunk, heap_depth,
-                        int(e0.max_bins), obj, bf16, run_es, csr=csr,
-                        skip_counts=skip_counts, seg_hist=seg,
+                        int(e0.max_bins), obj, bf16, run_es,
+                        skip_counts=skip_counts,
                         default_dir=e0.sparse_default_direction,
                         dd_mask=dd, bundle_end=bend, acc_bf16=acc,
                         goss=goss, goss_seed=jnp.int32(e0.seed),
@@ -1021,8 +1006,8 @@ class GBTGridGroup(TreeGridGroup):
                             depth_lim[s0:s1], lams[s0:s1], mcws[s0:s1],
                             migs[s0:s1], mins_[s0:s1], lrs[s0:s1],
                             mgrs[s0:s1], es_chunk, heap_depth,
-                            int(e0.max_bins), obj, bf16, run_es, csr=csr,
-                            skip_counts=skip_counts, seg_hist=seg,
+                            int(e0.max_bins), obj, bf16, run_es,
+                            skip_counts=skip_counts,
                             default_dir=e0.sparse_default_direction,
                             dd_mask=dd, bundle_end=bend, acc_bf16=acc,
                             goss=goss, goss_seed=jnp.int32(e0.seed),

@@ -835,7 +835,7 @@ class ModelSelector(PredictorEstimator):
             self.metadata["halving_schedule"] = schedule
             best_name, best_params, *_ = candidates[best_i]
         else:
-            # host tree-prep (sketch/binning/CSR) overlaps the linear
+            # host tree-prep (sketch/binning) overlaps the linear
             # groups' async device work in a daemon thread
             self._start_tree_prep_prefetch(X)
             candidates = self._candidates()
