@@ -557,6 +557,119 @@ def _route_right(x, t):
     return (x > te) | (dr & (x == 0))
 
 
+#: a level goes by the select (``route_level``, ``leaf_by_slot``) while it
+#: has at most this many slots, and at most four a row; past that the select's
+#: M compares a row cost more than the gathers they replace.  One v5e, 8
+#: trees over 250,000 x 500 int8 (PERF.md §6, PR 31): a (slot, row) pair of
+#: the select costs 12 ps, a (row, level) of the three one-element gathers
+#: 22 ns, so a level breaks even at some 1,800 slots (depth 10: 0.048 s
+#: against 0.48 as gathers; 12: 0.19 | 0.57; 14: 0.75 | 0.67).  A 1-row
+#: call gathers one element a SLOT in the select form (200 trees of depth
+#: 12: 7.5 ms against 0.8), so there the slots are held to four a row.
+SELECT_MAX_SLOTS = 1024
+SELECT_MAX_SLOTS_A_ROW = 4
+
+#: bytes of the binned matrix's rows one step of a level's slot loop holds
+#: per tree: a level's M slots go through the select in blocks of
+#: ``ROUTE_BLOCK_BYTES // (rows x itemsize)`` slots, so that nothing of size
+#: M x rows reaches HBM (under ``vmap`` the block is held once per tree of
+#: the launch).  16 MiB, of 1 MiB ... the whole level: a chain launch of the
+#: cell 0.98 s at 4 MiB, 0.91 at 16, 0.89 at 64; a scoring call 0.095 s at 1,
+#: 0.054 at 4, 0.048 at 16, 0.061 at 64, 0.048 whole (one v5e, PR 31).
+ROUTE_BLOCK_BYTES = 16 << 20
+
+
+def _by_select(m: int, rows: int) -> bool:
+    """Does a level of ``m`` slots over ``rows`` rows go by the select?
+    Static: a function of shapes only."""
+    return m <= min(SELECT_MAX_SLOTS, SELECT_MAX_SLOTS_A_ROW * rows)
+
+
+def _over_slot_blocks(block, m: int, rows: int, pair_bytes: int, init, merge):
+    """Fold ``block(first slot, slots)`` over a level's slot blocks of
+    ``pair_bytes`` a (slot, row) (``m`` is a power of two and so is the
+    block)."""
+    sb = max(1, min(m, ROUTE_BLOCK_BYTES // max(rows * pair_bytes, 1)))
+    sb = 1 << (sb.bit_length() - 1)
+    if sb == m:
+        return merge(init, block(0, m))
+    return lax.fori_loop(
+        0, m // sb, lambda i, acc: merge(acc, block(i * sb, sb)), init)
+
+
+def _slot_ends(bundle_end, thresh_l, fid):
+    """(M,) end bin of each slot's bundled split, the owner member's (rows
+    past it belong to OTHER members of the bundle and route left); None
+    where the matrix is not bundled."""
+    if bundle_end is None:
+        return None
+    return bundle_end[jnp.clip(thresh_l, 0, bundle_end.shape[0] - 1), fid]
+
+
+def route_level(binned_T, slot, fid, thresh_l, end_l=None):
+    """Which rows go right at one level: a select over the level's slots.
+
+    ``binned_T`` (d, rows) is the binned matrix ROWS-MINOR; ``slot`` (rows,)
+    each row's slot in [0, M); ``fid`` / ``thresh_l`` (M,) the feature a
+    slot tests (a row of ``binned_T``) and its threshold; ``end_l`` (M,)
+    the owner member's end bin of a bundled split (``bundle_end``) or None.
+    Returns (rows,) bool by ``_route_right``, the one routing rule.
+
+    A row does not ask "which node am I in, which feature does it test,
+    what is my bin there" (three dependent gathers of ONE element a row:
+    22-40 ns a row and level on a v5e, whatever the table's size).  The
+    level's few slots each fetch their WHOLE feature row (``binned_T[fid]``:
+    M contiguous rows), every row is compared under every slot's threshold
+    and keeps the answer of its own slot: M byte-compares a row in place of
+    the gathers, and no element gather is left.  Past ``_by_select``'s
+    bound (a level of 2,048 slots and more, or more than four slots a row)
+    the compares cost more than the gathers, and the level asks a row at a
+    time as before."""
+    m = fid.shape[0]
+    rows = slot.shape[0]
+    if not _by_select(m, rows):
+        f = fid[slot]
+        x = jnp.take_along_axis(binned_T, f[None, :], 0)[0]
+        go = _route_right(x, thresh_l[slot])
+        return go if end_l is None else go & (x <= end_l[slot])
+    whole_rows = lax.GatherDimensionNumbers(
+        offset_dims=(1,), collapsed_slice_dims=(0,), start_index_map=(0,))
+
+    def block(s, sb):
+        f = lax.dynamic_slice_in_dim(fid, s, sb).reshape(sb, 1)
+        t = lax.dynamic_slice_in_dim(thresh_l, s, sb).reshape(sb, 1)
+        cols = lax.gather(binned_T, f, whole_rows, (1, rows),
+                          mode=lax.GatherScatterMode.CLIP)   # (sb, rows)
+        go = _route_right(cols, t)
+        if end_l is not None:
+            go = go & (cols <= lax.dynamic_slice_in_dim(
+                end_l, s, sb).reshape(sb, 1))
+        mine = slot == s + lax.broadcasted_iota(slot.dtype, (sb, rows), 0)
+        return jnp.any(go & mine, axis=0)
+
+    return _over_slot_blocks(block, m, rows, binned_T.dtype.itemsize,
+                             jnp.zeros(rows, bool), jnp.logical_or)
+
+
+def leaf_by_slot(leaf, node):
+    """``leaf[node].T``: (K, rows) from ``leaf`` (L, K) and ``node`` (rows,)
+    in [0, L).  Within ``_by_select``'s bound by a select, as
+    ``route_level`` goes: every row keeps its own leaf's value out of all L
+    (one non-zero term a sum, so the result is exact)."""
+    n_leaves, k = leaf.shape
+    rows = node.shape[0]
+    if not _by_select(n_leaves, rows):
+        return leaf[node].T
+
+    def block(s, sb):
+        v = lax.dynamic_slice_in_dim(leaf, s, sb).T.reshape(k, sb, 1)
+        mine = node == s + lax.broadcasted_iota(node.dtype, (sb, rows), 0)
+        return jnp.where(mine, v, 0.0).sum(axis=1)
+
+    return _over_slot_blocks(block, n_leaves, rows, 4 * k,
+                             jnp.zeros((k, rows), leaf.dtype), jnp.add)
+
+
 def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
                       max_depth: int, n_bins: int, lam, min_child_weight,
                       min_info_gain, min_instances, newton_leaf,
@@ -565,8 +678,14 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
                       bag_mode: str = "none", feat_idx=None,
                       leaf_levels: Tuple[int, ...] = (),
                       default_dir: bool = False, dd_mask=None,
-                      bundle_end=None, acc_bf16: bool = False):
+                      bundle_end=None, acc_bf16: bool = False,
+                      binned_T=None):
     """One whole tree under trace: Python-unrolled loop over levels.
+
+    ``binned_T``: the (d, N) transpose of ``binned`` that the level routing
+    reads (``route_level``), from a caller for whom it is loop-invariant
+    (the chain scan on all rows); taken here, once a tree, otherwise (a
+    GOSS tree's own ``binned[idx]``).
 
     ``bundle_end``: optional (B, D) int32 per-(threshold, feature) member
     END-bin table from :func:`bundle_features` — the matrix is then in
@@ -642,6 +761,8 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
     # the jit cache key; resolving it here at trace time let a CPU-traced
     # f32 executable be silently reused under a bf16 key and vice versa.)
     binned_full = binned
+    if binned_T is None:
+        binned_T = binned.T
     n = binned.shape[0]
     if feat_idx is not None:
         feat_idx = feat_idx.astype(jnp.int32)
@@ -1046,21 +1167,11 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
             heap_thresh_levels.append(seg_thresh)
 
         with jax.named_scope("tree.route"):
-            # routing reads the FULL-width matrix: subset-local split ids map
-            # through feat_idx (no msub-wide gathered copy exists anymore)
+            # routing reads the FULL-width matrix, rows-minor: subset-local
+            # split ids map through feat_idx once a SLOT
             fid = feat_idx[feat_l] if feat_idx is not None else feat_l
-            x_row = jnp.take_along_axis(binned_full, fid[slot][:, None],
-                                        1)[:, 0]
-            tv = thresh_l[slot]
-            go_right = _route_right(x_row, tv)
-            if bundle_end is not None:
-                # interval cap: rows past the owner member's end bin belong
-                # to OTHER members of the bundle and route left (flat gather:
-                # 2-D advanced indexing miscompiles at some shapes, see
-                # predict_ensemble)
-                ev = bundle_end.reshape(-1)[
-                    jnp.clip(tv, 0, B - 1) * d + fid[slot]]
-                go_right = go_right & (x_row <= ev)
+            go_right = route_level(binned_T, slot, fid, thresh_l,
+                                   _slot_ends(bundle_end, thresh_l, fid))
             node = 2 * node + go_right.astype(jnp.int32)
 
     # heap layout: level l occupies slots [2^l - 1, 2^{l+1} - 1)
@@ -1567,6 +1678,9 @@ def _gbt_chain_rounds_jit(binned, y, W, Fm0, vi, depth_lim, lams, mcws,
     (``round_offset``), so results are invariant to chunking."""
     n, d = binned.shape
     mask = jnp.ones(d, bool)
+    # rows-minor, once a launch: what the margin update of every round
+    # routes on, and the growth of chains that see all rows
+    binned_T = binned.T
     grow_kw = dict(max_depth=max_depth, n_bins=n_bins,
                    newton_leaf=jnp.bool_(True), hist_bf16=hist_bf16,
                    bag_mode="newton" if skip_counts else "none",
@@ -1605,19 +1719,13 @@ def _gbt_chain_rounds_jit(binned, y, W, Fm0, vi, depth_lim, lams, mcws,
                     binned, g[:, None], h[:, None], c, mask, lim,
                     lam=lam, min_child_weight=mcw, min_info_gain=mig,
                     min_instances=mi, learning_rate=lr, min_gain_raw=mgr,
-                    **grow_kw)[:3]
+                    binned_T=binned_T, **grow_kw)[:3]
 
             f, t, lf = jax.vmap(one)(G, H, W, depth_lim, lams, mcws, migs,
                                      mins_, lrs, mgrs)
-        with jax.named_scope("gbt.update"):
-            if bundle_end is not None:
-                inc = jax.vmap(lambda ff, tt, ll: _predict_tree_bundled(
-                    binned, ff, tt, ll, max_depth, bundle_end))(
-                        f, t, lf)[:, :, 0]
-            else:
-                inc = jax.vmap(lambda ff, tt, ll: predict_tree(
-                    binned, ff, tt, ll, max_depth))(f, t, lf)[:, :, 0]
-            Fm = Fm + inc
+        with jax.named_scope("gbt.update"), jax.named_scope("tree.predict"):
+            Fm = Fm + jax.vmap(lambda ff, tt, ll: _predict_tree_T(
+                binned_T, ff, tt, ll, max_depth, bundle_end)[0])(f, t, lf)
         with jax.named_scope("gbt.es_metric"):
             if use_es:
                 m = _chain_es_metric(Fm, y, vi, obj)
@@ -1653,10 +1761,9 @@ def _chain_es_metric_val(Z, yv, obj: str):
 @functools.partial(jax.jit, static_argnames=("max_depth",))
 def _predict_round_jit(binned, feat, thresh, leaf, max_depth: int):
     """(S, N) margin increments for one round's chain trees."""
-    out = jax.vmap(lambda f, t, lf: predict_tree(binned, f, t, lf,
-                                                 max_depth))(
-        feat, thresh, leaf)
-    return out[:, :, 0]
+    binned_T = binned.T
+    return jax.vmap(lambda f, t, lf: _predict_tree_T(
+        binned_T, f, t, lf, max_depth)[0])(feat, thresh, leaf)
 
 
 _chain_es_metric_jit = jax.jit(_chain_es_metric,
@@ -1740,109 +1847,110 @@ def grow_tree(binned: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
 # Prediction
 # ---------------------------------------------------------------------------
 
+def _predict_tree_T(binned_T, feat, thresh, leaf, max_depth: int,
+                    bundle_end=None):
+    """One tree over the ROWS-MINOR matrix ``binned_T`` (d, N): (K, N) leaf
+    values.
+
+    The loop over levels is unrolled over the static ``max_depth`` (a
+    level's slot count 2^l must be static for ``route_level``).  With
+    ``bundle_end`` the tree is in BUNDLED column space: splits are per-
+    member intervals, so routing right additionally requires the bin to sit
+    at or below the owner member's end bin (the in-launch margin updates of
+    EFB growth and the sharded chains; persisted trees are unbundled and
+    route without it)."""
+    node = jnp.zeros(binned_T.shape[1], jnp.int32)
+    for level in range(max_depth):
+        lo, m = 2 ** level - 1, 2 ** level
+        f, t = feat[lo:lo + m], thresh[lo:lo + m]
+        go = route_level(binned_T, node, f, t, _slot_ends(bundle_end, t, f))
+        node = 2 * node + go.astype(jnp.int32)
+    return leaf_by_slot(leaf, node)
+
+
 @functools.partial(jax.jit, static_argnames=("max_depth",))
 def predict_tree(binned: jnp.ndarray, feat: jnp.ndarray, thresh: jnp.ndarray,
                  leaf: jnp.ndarray, max_depth: int) -> jnp.ndarray:
     """Route rows through one tree; returns (N, K) leaf values."""
-    n = binned.shape[0]
-    node = jnp.zeros(n, jnp.int32)
-
-    def level(l, node):
-        base = 2 ** l - 1
-        heap = base + node
-        f = feat[heap]
-        t = thresh[heap]
-        x = jnp.take_along_axis(binned, f[:, None], 1)[:, 0]
-        return 2 * node + _route_right(x, t).astype(jnp.int32)
-
     with jax.named_scope("tree.predict"):
-        node = lax.fori_loop(0, max_depth, level, node)
-        return leaf[node]
+        return _predict_tree_T(binned.T, feat, thresh, leaf, max_depth).T
 
 
-def _predict_tree_bundled(binned, feat, thresh, leaf, max_depth: int,
-                          bundle_end):
-    """``predict_tree`` in BUNDLED column space: splits are per-member
-    intervals, so routing right additionally requires the bin to sit at
-    or below the owner member's end bin (``bundle_end``).  Used only for
-    the in-launch margin updates of EFB growth — persisted trees are
-    unbundled and route through the ordinary predictors."""
-    n, d = binned.shape
-    B = bundle_end.shape[0]
-    be_f = bundle_end.reshape(-1)
-    node = jnp.zeros(n, jnp.int32)
+#: bytes one step of ``predict_ensemble`` holds for its chunk of trees (a
+#: tree's slot block of ``route_level`` and its leaf ids and values): all
+#: trees at once for a 1-row request, a few dozen at 250,000 rows
+ENSEMBLE_CHUNK_BYTES = 128 << 20
 
-    def level(l, node):
-        base = 2 ** l - 1
-        heap = base + node
-        f = feat[heap]
-        t = thresh[heap]
-        x = jnp.take_along_axis(binned, f[:, None], 1)[:, 0]
-        ev = be_f[jnp.clip(t, 0, B - 1) * d + f]
-        go = _route_right(x, t) & (x <= ev)
-        return 2 * node + go.astype(jnp.int32)
 
-    node = lax.fori_loop(0, max_depth, level, node)
-    return leaf[node]
+def _add_lanes(lanes, vals, off):
+    """Add ``vals`` (c, K, N), the values of c consecutive trees, into the
+    eight ``lanes`` (8, K, N), tree i into lane i mod 8.  ``off`` is the
+    first tree's lane: 0 where c is 8 or more (whole groups of eight, then
+    the rest from lane 0), else ``off + c`` stays within the eight."""
+    c = vals.shape[0]
+    if c >= 8:
+        q = c // 8
+        lanes = lax.scan(
+            lambda a, v: (a + v, None), lanes,
+            vals[:8 * q].reshape((q, 8) + vals.shape[1:]))[0]
+        vals, c = vals[8 * q:], c - 8 * q
+        if c == 0:
+            return lanes
+    cur = lax.dynamic_slice_in_dim(lanes, off, c)
+    return lax.dynamic_update_slice_in_dim(lanes, cur + vals, off, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("max_depth",))
 def predict_ensemble(binned: jnp.ndarray, feat: jnp.ndarray,
                      thresh: jnp.ndarray, leaf: jnp.ndarray,
                      max_depth: int) -> jnp.ndarray:
-    """Sum of all trees' outputs: feat/thresh (T, 2^d-1), leaf (T, 2^d, K).
+    """Sum of all trees' outputs: feat/thresh (T, 2^d-1), leaf (T, 2^d, K);
+    returns (N, K).
 
-    All trees route in parallel — ``max_depth`` sequential steps of one
-    (T, N) gather each, instead of a scan over trees (T × depth serial
-    steps, which left the TPU idle between tiny kernels).
+    The matrix is transposed once a call; every tree then walks its levels
+    by ``route_level`` (whole feature rows compared under a level's
+    thresholds, no gather of one element a (tree, row)) and reads its leaf
+    values by ``leaf_by_slot``.  Trees go in chunks of
+    ``ENSEMBLE_CHUNK_BYTES``, each chunk one ``vmap`` and the chunks one
+    ``scan``.  On one v5e a call of 8 trees of depth 10 over 250,000 x 500
+    took 0.48 s as three element gathers a level and takes 0.048 s so
+    (random trees; PERF.md §6, PR 31).
 
-    Every gather is expressed over FLATTENED operands with explicit row/
-    tree offsets, not the 2-D advanced-indexing forms (``feat[tree,
-    heap]``, ``binned[row, f]``).  The flat form was adopted after wrong
-    routing was seen with the 2-D forms at some (T, N) shapes on an
-    earlier installation; whether this one needs it is for a chip
-    measurement to re-decide (ROADMAP Queue 3).
-    """
+    The sum over trees is a float's, so its ORDER is part of the result:
+    tree i adds into lane i mod 8 of eight partial sums, and the lanes add
+    by halves, (0+4)+(2+6) and (1+5)+(3+7).  That is the order the chip's
+    own reduce over the eight sublanes of a tile took when this function
+    summed a gathered (T, N) array, so scores stay bit-equal with those;
+    here it is written out and holds on every backend."""
     n = binned.shape[0]
-    d = binned.shape[1]
-    T, nodes = feat.shape
-    if n * d >= 2 ** 31:
-        # flat int32 gather offsets would overflow: route rows through in
-        # static chunks (shapes are trace-time constants, so this Python
-        # loop unrolls into a few sub-programs — no host round trips) and
-        # concatenate.  Keeps GB-scale predicts (e.g. 1M rows x 2200+
-        # features) working instead of hard-failing at serving time.
-        n_chunks = int(np.ceil(n * d / (2 ** 31 - 1)))
-        rows = -(-n // n_chunks)
-        return jnp.concatenate(
-            [predict_ensemble(binned[s:s + rows], feat, thresh, leaf,
-                              max_depth)
-             for s in range(0, n, rows)], axis=0)
+    T = feat.shape[0]
+    k = leaf.shape[2]
+    per_tree = (min(ROUTE_BLOCK_BYTES,
+                    2 ** (max_depth - 1) * n * binned.dtype.itemsize)
+                + n * (4 + 4 * k))
+    chunk = max(1, min(T, ENSEMBLE_CHUNK_BYTES // max(per_tree, 1)))
+    # whole groups of eight trees a chunk, or a divisor of eight
+    chunk = chunk - chunk % 8 if chunk >= 8 else 1 << (chunk.bit_length() - 1)
     with jax.named_scope("tree.predict"):
-        node = jnp.zeros((T, n), jnp.int32)
-        feat_f = feat.reshape(-1)
-        thresh_f = thresh.reshape(-1)
-        binned_f = binned.reshape(-1)
-        tree_off = (jnp.arange(T, dtype=jnp.int32) * nodes)[:, None]
-        row_off = (jnp.arange(n, dtype=jnp.int32) * jnp.int32(d))[None, :]
+        binned_T = binned.T
 
-        def level(l, node):
-            heap = (2 ** l - 1) + node + tree_off            # (T, N) flat ids
-            f = feat_f[heap]
-            t = thresh_f[heap]
-            x = binned_f[row_off + f]                        # (T, N)
-            return 2 * node + _route_right(x, t).astype(jnp.int32)
+        def add_trees(lanes, trees, first):
+            vals = jax.vmap(lambda f, t, lf: _predict_tree_T(
+                binned_T, f, t, lf, max_depth))(*trees)      # (c, K, N)
+            return _add_lanes(lanes, vals, first % 8)
 
-        node = lax.fori_loop(0, max_depth, level, node)
-        # leaf-sum in tree chunks: one (T, N, K) gather would cost T·N·K·4 bytes
-        # of HBM (4 GB for 512 trees × 1M rows); chunks bound it at ~32 MB
-        k = leaf.shape[2]
-        n_leaves = leaf.shape[1]
-        leaf_f = leaf.reshape(T * n_leaves, k)
-        leaf_off = (jnp.arange(T, dtype=jnp.int32) * n_leaves)[:, None]
-        chunk = max(1, min(T, (32 << 20) // max(n * k * 4, 1)))
-        out = jnp.zeros((n, k), jnp.float32)
-        for s in range(0, T, chunk):
-            e = min(s + chunk, T)
-            out = out + leaf_f[node[s:e] + leaf_off[s:e]].sum(axis=0)
-        return out
+        n_full = T // chunk
+        lanes = jnp.zeros((8, k, n), jnp.float32)
+        if n_full:
+            head = [a[:n_full * chunk].reshape((n_full, chunk) + a.shape[1:])
+                    for a in (feat, thresh, leaf)]
+            lanes, _ = lax.scan(
+                lambda acc, it: (add_trees(acc, it[1:], it[0] * chunk), None),
+                lanes, [jnp.arange(n_full)] + head)
+        if T % chunk:
+            lanes = add_trees(lanes, [a[n_full * chunk:]
+                                      for a in (feat, thresh, leaf)],
+                              n_full * chunk)
+        lanes = lanes[:4] + lanes[4:]
+        lanes = lanes[:2] + lanes[2:]
+        return (lanes[0] + lanes[1]).T

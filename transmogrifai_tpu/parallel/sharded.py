@@ -257,7 +257,7 @@ def gbt_chain_rounds_sharded(binned, y, W, Fm0, yv, vi, depth_lim, lams,
     """
     from ..models.gbdt_kernels import (_chain_es_metric_val,
                                        _grow_tree_traced,
-                                       _predict_tree_bundled)
+                                       _predict_tree_T)
     from .mesh import shard_map_compat
 
     data_axis, grid_axis = mesh.axis_names
@@ -275,6 +275,8 @@ def gbt_chain_rounds_sharded(binned, y, W, Fm0, yv, vi, depth_lim, lams,
             nl, d = binned_s.shape
             mask = jnp.ones(d, bool)
             lo = lax.axis_index(data_axis) * nl
+            # the shard's rows, rows-minor, once a launch (route_level)
+            binned_T = binned_s.T
 
             def round_step(Fm, _):
                 if obj == "binary":
@@ -294,14 +296,13 @@ def gbt_chain_rounds_sharded(binned, y, W, Fm0, yv, vi, depth_lim, lams,
                         learning_rate=lrr, hist_bf16=hist_bf16,
                         min_gain_raw=mgr, all_reduce=psum_d,
                         bag_mode="newton" if skip_counts else "none",
-                        bundle_end=be_r, acc_bf16=acc_bf16)[:3]
+                        bundle_end=be_r, acc_bf16=acc_bf16,
+                        binned_T=binned_T)[:3]
 
                 f, t, lf = jax.vmap(one)(G, H, W_s, dl, la, mc, mg, mi,
                                          lr_, mgr_)
-                inc = jax.vmap(lambda ff, tt, ll: _predict_tree_bundled(
-                    binned_s, ff, tt, ll, max_depth, be_r))(
-                    f, t, lf)[:, :, 0]
-                Fm = Fm + inc
+                Fm = Fm + jax.vmap(lambda ff, tt, ll: _predict_tree_T(
+                    binned_T, ff, tt, ll, max_depth, be_r)[0])(f, t, lf)
                 if use_es:
                     owned = (vi_r >= lo) & (vi_r < lo + nl)
                     lvi = jnp.clip(vi_r - lo, 0, nl - 1)

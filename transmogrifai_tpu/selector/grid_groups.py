@@ -717,7 +717,7 @@ def _score_pairs_jit(binned, feats, threshs, leaves, heap_depth: int,
                      mode: str, ptype: str):
     """Pair validation scores in memory-bounded vmapped launches (12
     separate predict+transform launches measured ~8 s at 200k x 500; a
-    single unbounded vmap OOMs on the (pairs, trees, rows) leaf gathers)."""
+    single unbounded vmap OOMs on the (pairs, trees, rows) leaf values)."""
     import functools
 
     import jax
