@@ -129,26 +129,101 @@ class TreeEnsemble(NamedTuple):
 # Quantile binning
 # ---------------------------------------------------------------------------
 
+#: columns a block of the host sketch sorts at once: 20 columns of the
+#: 200,000-row sample are 16 MB of float32, a (columns, rows) array whose
+#: rows the sort walks contiguously.  Step 0 of PR 32 (docs/performance.md,
+#: "The host sketch") read 16 to 32 columns within 0.03 s of each other on
+#: the chip's host and 50 and more a half slower, and one thread at 1.14 s,
+#: under the 1.5 s that would have asked for a pool.
+SKETCH_BLOCK_COLS = 20
+
+
+def _sorted_column_blocks(X: np.ndarray, sample_rows: int, seed: int):
+    """The sketch's sample, ``SKETCH_BLOCK_COLS`` columns at a time, as
+    ``(first column, (columns, rows) array with every row SORTED)``; NaN
+    sort last.  The sample is the rows ``default_rng(seed).choice`` draws
+    when ``X`` has more than ``sample_rows`` (taken in row order: a
+    quantile does not see their order), else every row.
+
+    ``X`` is never sorted in place and no copy of the whole sample is made:
+    the walk owns two buffers, the block's columns over all rows and over
+    the sampled rows, and every block it yields is a view of the second
+    that the next step overwrites.  (Allocating 16 MB a block instead cost
+    0.1 to 0.5 s of page faults on the chip's host, by the state of the
+    heap.)"""
+    n, d = X.shape
+    idx = None
+    if n > sample_rows:
+        idx = np.random.default_rng(seed).choice(n, sample_rows, replace=False)
+        idx.sort()
+    wide = np.empty((min(SKETCH_BLOCK_COLS, d), n), X.dtype)
+    sample = wide if idx is None else np.empty((len(wide), sample_rows),
+                                               X.dtype)
+    for j0 in range(0, d, SKETCH_BLOCK_COLS):
+        cols = X[:, j0:j0 + SKETCH_BLOCK_COLS].T
+        all_rows, block = wide[:len(cols)], sample[:len(cols)]
+        np.copyto(all_rows, cols)
+        if idx is not None:
+            # every index is in range; "clip" only spares take the
+            # buffering of ``out`` that its default mode does
+            np.take(all_rows, idx, axis=1, out=block, mode="clip")
+        block.sort(axis=1)
+        yield j0, block
+
+
+def _linear_ranks(m: int, qs: np.ndarray):
+    """Floor rank, ceiling rank and weight of the quantiles ``qs`` of ``m``
+    sorted values, as ``np.quantile``'s ``linear`` method takes them."""
+    virtual = (m - 1) * qs
+    lo = np.floor(virtual)
+    lo[virtual >= m - 1] = -1
+    hi = np.where(lo < 0, -1, lo + 1).astype(np.intp)
+    lo = lo.astype(np.intp)
+    return lo, hi, virtual - lo
+
+
+def _lerp(a: np.ndarray, b: np.ndarray, t: np.ndarray) -> np.ndarray:
+    """``np.quantile``'s interpolation, operation for operation (the
+    difference in the values' own dtype, the rest in float64, the upper
+    neighbour's form from ``t`` 0.5 up), so that the edges are bit-equal to
+    ``np.quantile(..., method="linear")``'s."""
+    diff = np.subtract(b, a)
+    out = np.add(a, diff * t)
+    np.subtract(b, diff * (1 - t), out=out, where=t >= 0.5,
+                casting="unsafe", dtype=out.dtype)
+    return out
+
+
 def quantile_bins(X: np.ndarray, max_bins: int = 32,
                   sample_rows: int = 200_000, seed: int = 7) -> np.ndarray:
     """Per-feature quantile bin edges, shape (D, max_bins-1).
 
     Host-side on a row sample (the analogue of XGBoost's sketch); edges are
-    deduplicated so constant/low-cardinality features waste no bins.
+    deduplicated so constant/low-cardinality features waste no bins.  A
+    column with a NaN has NaN for every edge.
+
+    The edges are those of ``np.quantile(sample, qs, axis=0)`` bit for bit,
+    computed a block of columns at a time: the block's sample rows gathered
+    into a (columns, rows) array, each row sorted, the two neighbours of
+    every rank ``q * (rows - 1)`` interpolated as numpy does: a fifth of
+    the time of ``np.quantile`` down the strided axis of the whole sample.
+    The span ``tree.prep.sketch`` around the callers' builds covers the
+    gather, the sorts and the interpolation.
     """
     X = np.asarray(X)
-    n, d = X.shape
-    if n > sample_rows:
-        rng = np.random.default_rng(seed)
-        X = X[rng.choice(n, sample_rows, replace=False)]
+    d = X.shape[1]
     qs = np.linspace(0, 1, max_bins + 1)[1:-1]
-    edges = np.quantile(X, qs, axis=0).T.astype(np.float32)  # (D, B-1)
+    lo, hi, t = _linear_ranks(min(X.shape[0], sample_rows), qs)
+    quantiles = np.empty((d, len(qs)), np.float64)
+    for j0, block in _sorted_column_blocks(X, sample_rows, seed):
+        q = _lerp(block[:, lo], block[:, hi], t)
+        nan = np.isnan(block[:, -1])
+        q[nan] = block[nan, -1:]
+        quantiles[j0:j0 + len(block)] = q
+    edges = quantiles.astype(np.float32)  # (D, B-1)
     # strictly increasing edges; collapse duplicates to +inf (unused bins)
-    eps = 1e-7
-    for j in range(d):
-        e = edges[j]
-        dup = np.concatenate([[False], np.diff(e) <= eps])
-        edges[j] = np.where(dup, np.inf, e)
+    dup = np.diff(edges, axis=1) <= 1e-7
+    edges[:, 1:][dup] = np.inf
     return edges
 
 
@@ -220,32 +295,38 @@ def quantile_bins_sparse_aware(X: np.ndarray, max_bins: int = 32,
     mostly zero spend their quantiles on the nonzero values (plus a pinned
     0.0 edge separating the zeros)."""
     X = np.asarray(X)
-    n, d = X.shape
-    if n > sample_rows:
-        rng = np.random.default_rng(seed)
-        X = X[rng.choice(n, sample_rows, replace=False)]
-        n = sample_rows
+    d = X.shape[1]
+    n = min(X.shape[0], sample_rows)
     edges = np.full((d, max_bins - 1), np.inf, np.float32)
     qs_dense = np.linspace(0, 1, max_bins + 1)[1:-1]
     qs_sparse = np.linspace(0, 1, max_bins)[1:-1]       # B-2 qs + the 0 edge
     eps = 1e-7
-    for j in range(d):
-        col = X[:, j]
-        # NaN entries are excluded from the sketch (the binning convention
-        # pins NaN to bin 0 — trees._device_bins); nanquantile keeps a
-        # NaN-containing feature from poisoning every edge
-        nz = col[(col != 0) & ~np.isnan(col)]
-        if len(nz) and 1.0 - len(nz) / n >= SPARSE_SKETCH_ZERO_FRAC:
-            e = np.unique(np.concatenate(
-                [[0.0], np.quantile(nz, qs_sparse)]).astype(np.float32))
-        else:
-            e = np.nanquantile(col, qs_dense).astype(np.float32)
-            e = e[np.isfinite(e)]
-            dup = np.concatenate([[False], np.diff(e) <= eps]) \
-                if len(e) else np.zeros(0, bool)
-            e = e[~dup]
-        edges[j, :len(e)] = e[:max_bins - 1]
-        # keep strictly increasing (dedup collapsed to +inf tail already)
+    floats = np.issubdtype(X.dtype, np.inexact)
+    for j0, block in _sorted_column_blocks(X, sample_rows, seed):
+        for j, col in enumerate(block, start=j0):
+            # NaN entries are excluded from the sketch (the binning
+            # convention pins NaN to bin 0 — trees._device_bins), which
+            # keeps a NaN-containing feature from poisoning every edge; in
+            # the sorted column they are the tail, and the zeros one run
+            if floats:
+                col = col[:np.searchsorted(col, np.nan)]
+            z0, z1 = np.searchsorted(col, 0), np.searchsorted(col, 0, "right")
+            n_nz = len(col) - (z1 - z0)
+            if n_nz and 1.0 - n_nz / n >= SPARSE_SKETCH_ZERO_FRAC:
+                nz = np.concatenate([col[:z0], col[z1:]])
+                lo, hi, t = _linear_ranks(n_nz, qs_sparse)
+                e = np.unique(np.concatenate(
+                    [[0.0], _lerp(nz[lo], nz[hi], t)]).astype(np.float32))
+            elif len(col):
+                lo, hi, t = _linear_ranks(len(col), qs_dense)
+                e = _lerp(col[lo], col[hi], t).astype(np.float32)
+                e = e[np.isfinite(e)]
+                if len(e):
+                    e = e[~np.concatenate([[False], np.diff(e) <= eps])]
+            else:
+                continue
+            edges[j, :len(e)] = e[:max_bins - 1]
+            # keep strictly increasing (dedup collapsed to +inf tail already)
     return edges
 
 
