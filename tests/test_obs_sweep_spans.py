@@ -177,6 +177,48 @@ def test_contiguity_copy_has_a_span_and_the_memoised_probe_has_none():
     assert [s.name for s in tracer.snapshot()] == ["tree.prep.contiguous"]
 
 
+def test_a_checker_that_drops_columns_hands_on_a_matrix_nobody_copies(
+        wf, monkeypatch):
+    """The fitted SanityChecker writes its kept columns row-major (ISSUE
+    33), so the selector's ``_as_f32`` finds nothing to undo: no
+    ``tree.prep.contiguous`` span, and what ``selector.prepare`` works on
+    IS the checker's output.  (``wf`` only so that the tree programs of
+    this shape are built already.)"""
+    from transmogrifai_tpu.preparators import SanityChecker
+
+    df = planted_linear_frame(20_000, 16, 3)
+    label = FeatureBuilder.RealNN("label").as_response()
+    vector = transmogrify([FeatureBuilder.Real(c).as_predictor()
+                           for c in df.columns if c != "label"])
+    checked = SanityChecker().set_input(label, vector).get_output()
+    selector = BinaryClassificationModelSelector.with_cross_validation(
+        num_folds=2, seed=1, models_and_parameters=[
+            (models.OpXGBoostClassifier(num_round=2), grid(max_depth=[3]))])
+    prediction = selector.set_input(label, checked).get_output()
+    seen = []
+    as_f32 = trees._as_f32
+
+    def recording(X):
+        seen.append((X, as_f32(X)))
+        return seen[-1][1]
+
+    monkeypatch.setattr(trees, "_as_f32", recording)
+    with obs.tracing(capture_hlo=False) as tracer:
+        model = (OpWorkflow().set_result_features(prediction)
+                 .set_input_data(df).train())
+    (checker,) = [s for s in model.stages
+                  if type(s).__name__ == "SanityCheckerModel"]
+    # 16 values interleaved with 16 constant null indicators: half dropped
+    assert checker.keep_indices == list(range(0, 32, 2))
+    given, prepared = seen[0]
+    assert prepared is given
+    assert given.shape == (20_000, 16) and given.flags.c_contiguous
+    assert all(out is inp for inp, out in seen)
+    names = [s.name for s in tracer.snapshot()]
+    assert "selector.prepare" in names and "tree.prep.hash" in names
+    assert "tree.prep.contiguous" not in names
+
+
 def test_a_probe_that_waits_for_a_build_in_flight_is_a_span_and_a_count():
     profiling.reset_counters()
     started, release = threading.Event(), threading.Event()

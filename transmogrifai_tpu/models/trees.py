@@ -341,7 +341,9 @@ def _as_f32(X) -> np.ndarray:
     """float32 C-contiguous view; returns X itself when already so (keeps
     object identity stable for the per-object hash cache).
 
-    A non-contiguous input (e.g. the SanityChecker's column-filtered matrix)
+    A non-contiguous input (e.g. a caller's column slice ``X[:, ::2]`` or a
+    Fortran-ordered matrix; the fitted SanityChecker writes its filtered
+    matrix row-major itself and never takes this path)
     is copied ONCE per object and memoized — the selector sweep probes with
     the same matrix for every candidate, and re-copying a GB-scale matrix
     per probe measured ~17 s of a 200k-row sweep.  A sampled digest guards

@@ -79,6 +79,27 @@ def _matrix_f32(values) -> np.ndarray:
     return np.asarray(values, dtype=np.float32)
 
 
+def _select_columns(X, keep: np.ndarray) -> np.ndarray:
+    """The columns ``keep`` (an intp index array) of the matrix ``X``, in
+    ``keep``'s order, float32 and C-contiguous, in ONE pass: the layout
+    every consumer reads (the selector's ``trees._as_f32`` copies anything
+    else).  Not ``X[:, keep].astype(np.float32)``: indexing a row-major
+    matrix with a list is numpy's slowest gather and its result is
+    Fortran-ordered, which ``astype`` copies and keeps (docs/performance.md,
+    "Handing a matrix on").
+
+    When nothing is dropped and ``X`` is row-major float32 already, ``X``
+    itself is handed on: no stage may write to a column it is given
+    (contract TM020, analysis/contracts.py), so nobody writes through the
+    alias."""
+    X = np.asarray(X)
+    if (X.dtype == np.float32 and X.flags.c_contiguous
+            and keep.size == X.shape[1]
+            and np.array_equal(keep, np.arange(keep.size))):
+        return X
+    return np.take(X, keep, axis=1).astype(np.float32, copy=False)
+
+
 class SanityChecker(BinaryEstimator):
     """Inputs: (label RealNN, features OPVector) -> cleaned OPVector."""
 
@@ -425,8 +446,19 @@ class SanityChecker(BinaryEstimator):
                               group_cv, vmeta, n, d)
 
 
-class _VmetaExtraState:
-    """Shared persistence of the filtered vector metadata (_new_vmeta)."""
+class _ColumnFilterState:
+    """What the two fitted column filters share: ``keep_indices`` with its
+    index array (built once per model, not from the list in every call) and
+    the persistence of the filtered vector metadata (_new_vmeta)."""
+
+    @property
+    def keep_indices(self) -> List[int]:
+        return self._keep_indices
+
+    @keep_indices.setter
+    def keep_indices(self, keep):
+        self._keep_indices = list(keep)
+        self._keep = np.asarray(self._keep_indices, dtype=np.intp)
 
     def extra_state(self):
         return ({"new_vmeta": self._new_vmeta.to_json()}
@@ -437,7 +469,7 @@ class _VmetaExtraState:
             self._new_vmeta = VectorMetadata.from_json(state["new_vmeta"])
 
 
-class SanityCheckerModel(_VmetaExtraState, BinaryModel):
+class SanityCheckerModel(_ColumnFilterState, BinaryModel):
     input_types = (OPNumeric, OPVector)
     label_input_positions = (0,)
 
@@ -446,17 +478,16 @@ class SanityCheckerModel(_VmetaExtraState, BinaryModel):
     def __init__(self, keep_indices: List[int], uid: Optional[str] = None):
         super().__init__(operation_name="sanityCheck", output_type=OPVector,
                          uid=uid)
-        self.keep_indices = list(keep_indices)
+        self.keep_indices = keep_indices
         self._new_vmeta: Optional[VectorMetadata] = None
 
     def transform_columns(self, label_col, features_col) -> FeatureColumn:
-        X = np.asarray(features_col.values)
-        out = X[:, self.keep_indices]
+        out = _select_columns(features_col.values, self._keep)
         vmeta = self._new_vmeta
         if vmeta is None and features_col.vmeta is not None:
             vmeta = features_col.vmeta.select(self.keep_indices)
             self._new_vmeta = vmeta
-        return FeatureColumn(OPVector, out.astype(np.float32), vmeta=vmeta)
+        return FeatureColumn(OPVector, out, vmeta=vmeta)
 
 
 class MinVarianceFilter(BinaryEstimator):
@@ -537,21 +568,21 @@ class MinVarianceFilter(BinaryEstimator):
         return model
 
 
-class MinVarianceFilterModel(_VmetaExtraState, BinaryModel):
+class MinVarianceFilterModel(_ColumnFilterState, BinaryModel):
     input_arity = (1, 2)
     label_input_positions = (0,)
 
     def __init__(self, keep_indices: List[int], uid: Optional[str] = None):
         super().__init__(operation_name="minVariance", output_type=OPVector,
                          uid=uid)
-        self.keep_indices = list(keep_indices)
+        self.keep_indices = keep_indices
         self._new_vmeta = None
 
     def transform_columns(self, *cols: FeatureColumn) -> FeatureColumn:
         features_col = cols[-1]
-        X = np.asarray(features_col.values)
         vmeta = self._new_vmeta
         if vmeta is None and features_col.vmeta is not None:
             vmeta = features_col.vmeta.select(self.keep_indices)
-        return FeatureColumn(OPVector, X[:, self.keep_indices].astype(np.float32),
+        return FeatureColumn(OPVector,
+                             _select_columns(features_col.values, self._keep),
                              vmeta=vmeta)
