@@ -1,0 +1,23 @@
+"""Device seconds, on the first chip, of what the random-forest grid does
+with its forests in the traced train: the candidate-pair scoring of
+``selector/grid_groups._score_pairs_jit`` (a ``vmap`` of
+``jit__score_ensemble_jit``, one part a depth), the winner's
+``predict_ensemble`` / ``predict_tree`` and the metric grid
+(``jit__aupr_dev`` / ``jit__auroc_dev`` under ``binary_metric_grid``).  In a
+cell whose selector holds another tree family too, that family's scoring
+modules carry the same names and are counted here as well.
+"""
+from perfbench import trace_reduce
+from perfbench.metrics import _spans
+
+PATTERN = (r"score_pairs|score_ensemble|predict_ensemble|predict_tree"
+           r"|predict_round|aupr_dev|auroc_dev|metric_grid")
+
+LAYER = "tree kernels"
+UNIT = "s"
+MOVES = "train_device_s"
+
+
+def read(sources: dict):
+    reduced = _spans.tpu_trace(sources)  # a CPU rehearsal has no device time
+    return reduced and (trace_reduce.module_seconds(reduced, PATTERN) or None)

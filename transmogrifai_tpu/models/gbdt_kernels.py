@@ -1611,8 +1611,10 @@ def grow_rf_grid(binned, Y, W_tr, seed: int, n_trees: int,
     pg = jnp.asarray(pair_min_ig, jnp.float32)
     pi = jnp.asarray(pair_min_inst, jnp.float32)
     pd_ = jnp.asarray(pair_depth, jnp.int32)
-    from ..utils.profiling import launch
+    from ..utils.profiling import count_rf_grid, launch
 
+    count_rf_grid(treesGrown=total, launches=-(-total // chunk), chunk=chunk,
+                  msub=msub, levels=heap_depth)
     feats, threshs, leaves = [], [], []
     snaps: List[list] = [[] for _ in leaf_levels]
     for s in range(0, total, chunk):

@@ -362,7 +362,7 @@ def grow_rf_grid_sharded(binned, Y, W_tr, BWr, feat_idx, pair_fold,
     """
     from ..models.gbdt_kernels import (_accel_bf16, _grow_tree_traced,
                                        forest_chunk_size)
-    from ..utils.profiling import launch
+    from ..utils.profiling import count_rf_grid, launch
     from .mesh import grid_sharding, shard_map_compat
 
     data_axis, grid_axis = mesh.axis_names
@@ -380,6 +380,8 @@ def grow_rf_grid_sharded(binned, Y, W_tr, BWr, feat_idx, pair_fold,
         n_channels=(k if onehot_targets else k + 1), d_full=d,
         onehot_bytes=2 if hist_bf16 else 4)
     chunk = max(g, (chunk // g) * g)
+    count_rf_grid(treesGrown=total, launches=-(-total // chunk), chunk=chunk,
+                  msub=msub, levels=heap_depth)
 
     key = ("rf", _mesh_cache_key(mesh), chunk, heap_depth, n_bins, msub,
            float(lam), float(min_child_weight), onehot_targets,

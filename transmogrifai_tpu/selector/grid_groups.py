@@ -444,6 +444,8 @@ class RFGridGroup(TreeGridGroup):
         from ..models.gbdt_kernels import grow_rf_grid
         from ..models.trees import (_dev_memo, _feature_subset_size,
                                     _score_ensemble_jit)
+        from ..obs.trace import span as _span
+        from ..utils.profiling import count_rf_grid
 
         cls = self.proto._classification
         n_classes = self.n_classes
@@ -532,24 +534,32 @@ class RFGridGroup(TreeGridGroup):
         pair_ig = np.repeat([k[0] for k in base_keys], F)
         pair_inst = np.repeat([k[1] for k in base_keys], F)
         pair_depth = np.repeat(base_depth, F)
+        count_rf_grid(
+            candidates=C, bases=Cb, pairs=Cb * F,
+            truncated=sum(cand_depth[ci] < base_depth[key2base[cand_key[ci]]]
+                          for ci in range(C)))
         t0 = _time.perf_counter()
         subsample = float(self._param(self.grid_points[0],
                                       "subsample_rate"))
-        if self.mesh is not None:
-            grown = self._grow_pairs_sharded(
-                binned, Y, W_tr, seed=int(proto.seed), T=T,
-                pair_fold=pair_fold, pair_ig=pair_ig, pair_inst=pair_inst,
-                pair_depth=pair_depth, msub=msub, subsample=subsample,
-                mb=mb, cls=cls, leaf_levels=leaf_levels)
-        else:
-            grown = grow_rf_grid(
-                binned, _dev_memo(Y, "rf_Y"), _dev_memo(W_tr, "rf_Wtr"),
-                seed=int(proto.seed), n_trees=T, pair_fold=pair_fold,
-                pair_min_ig=pair_ig, pair_min_inst=pair_inst,
-                pair_depth=pair_depth, msub=msub,
-                subsample_rate=subsample,
-                n_bins=int(self._param(self.grid_points[0], "max_bins")),
-                onehot_targets=cls, leaf_levels=leaf_levels)
+        # the rf.grid.* spans time the host's dispatches; the device runs on
+        # after each ends (nothing here waits for it)
+        with _span("rf.grid.grow", cat="sweep", bases=Cb, pairs=Cb * F):
+            if self.mesh is not None:
+                grown = self._grow_pairs_sharded(
+                    binned, Y, W_tr, seed=int(proto.seed), T=T,
+                    pair_fold=pair_fold, pair_ig=pair_ig,
+                    pair_inst=pair_inst, pair_depth=pair_depth, msub=msub,
+                    subsample=subsample, mb=mb, cls=cls,
+                    leaf_levels=leaf_levels)
+            else:
+                grown = grow_rf_grid(
+                    binned, _dev_memo(Y, "rf_Y"), _dev_memo(W_tr, "rf_Wtr"),
+                    seed=int(proto.seed), n_trees=T, pair_fold=pair_fold,
+                    pair_min_ig=pair_ig, pair_min_inst=pair_inst,
+                    pair_depth=pair_depth, msub=msub,
+                    subsample_rate=subsample,
+                    n_bins=int(self._param(self.grid_points[0], "max_bins")),
+                    onehot_targets=cls, leaf_levels=leaf_levels)
         self._record_grid_observation(_time.perf_counter() - t0, n, d)
         feats, threshs, leaves = grown[:3]
         snap_map = grown[3] if leaf_levels else {}
@@ -571,9 +581,11 @@ class RFGridGroup(TreeGridGroup):
         full_idx = np.where(cp_full)[0]
         if len(full_idx):
             sel = cp_base[full_idx]       # numpy: indexes device OR host
-            parts.append(_score_pairs_jit(
-                binned, feats[sel], threshs[sel], leaves[sel],
-                heap_depth, mode, ptype))
+            with _span(f"rf.grid.score:d{heap_depth}", cat="sweep",
+                       pairs=len(full_idx)):
+                parts.append(_score_pairs_jit(
+                    binned, feats[sel], threshs[sel], leaves[sel],
+                    heap_depth, mode, ptype))
             order.extend(full_idx.tolist())
         for dt in sorted(set(cp_depth[~cp_full].tolist())):
             idx = np.where(~cp_full & (cp_depth == dt))[0]
@@ -581,9 +593,10 @@ class RFGridGroup(TreeGridGroup):
             nd = 2 ** dt - 1
             # the base trees' first dt levels ARE the depth-dt candidate's
             # splits; its leaves are the level-dt histogram-total snapshot
-            parts.append(_score_pairs_jit(
-                binned, feats[sel][:, :, :nd], threshs[sel][:, :, :nd],
-                snap_map[dt][sel], dt, mode, ptype))
+            with _span(f"rf.grid.score:d{dt}", cat="sweep", pairs=len(idx)):
+                parts.append(_score_pairs_jit(
+                    binned, feats[sel][:, :, :nd], threshs[sel][:, :, :nd],
+                    snap_map[dt][sel], dt, mode, ptype))
             order.extend(idx.tolist())
         scores = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
         if order != list(range(C * F)):
@@ -612,12 +625,13 @@ class RFGridGroup(TreeGridGroup):
                 leaf_levels=leaf_levels,
                 full_w=self._full_weights(weight_ctxs),
                 seed=int(proto.seed), subsample=subsample)
-        if multiclass:
-            m = multiclass_metric_grid(y, scores, jnp.asarray(W_ev),
-                                       n_classes, self.metric)
-        else:
-            fn = binary_metric_grid if cls else regression_metric_grid
-            m = fn(y, scores, jnp.asarray(W_ev), self.metric)
+        with _span("rf.grid.metrics", cat="sweep", rows=C * F):
+            if multiclass:
+                m = multiclass_metric_grid(y, scores, jnp.asarray(W_ev),
+                                           n_classes, self.metric)
+            else:
+                fn = binary_metric_grid if cls else regression_metric_grid
+                m = fn(y, scores, jnp.asarray(W_ev), self.metric)
         if m is None:
             return None
         return m.T
@@ -683,12 +697,16 @@ class RFGridGroup(TreeGridGroup):
 
         from ..models.gbdt_kernels import compile_depth_hint, grow_rf_grid
         from ..models.trees import TreeEnsembleModel, _dev_memo
+        from ..obs.trace import span as _span
+        from ..utils.profiling import count_rf_grid
 
         key = ctx["cand_key"][row]
         bi = ctx["key2base"][key]
         dt = ctx["cand_depth"][row]
         bd = ctx["base_depth"][bi]
-        with compile_depth_hint(ctx["heap_depth"]):
+        count_rf_grid(pairs=1)
+        with _span("rf.grid.refit", cat="sweep", depth=dt, base_depth=bd), \
+                compile_depth_hint(ctx["heap_depth"]):
             grown = grow_rf_grid(
                 ctx["binned"], _dev_memo(ctx["Y"], "rf_Y"),
                 _dev_memo(ctx["full_w"][None], "rf_Wfull"),

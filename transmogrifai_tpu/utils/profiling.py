@@ -26,7 +26,7 @@ __all__ = ["OpStep", "MetricsCollector", "AppMetrics", "StepMetrics",
            "with_job_group", "current_collector", "install_collector",
            "profile_to", "RunCounters", "COUNTERS", "reset_counters",
            "count_upload", "count_fetch", "count_drain", "count_launch",
-           "launch", "count_memo",
+           "launch", "count_memo", "count_rf_grid",
            "fetch_timed", "StageProfile", "PlanProfiler",
            "IngestPass", "IngestProfiler", "LintSnapshot", "backend_name",
            "mesh_desc"]
@@ -209,6 +209,13 @@ class RunCounters:
     #: for a build in flight on another thread (``waits``) — the work a
     #: train redoes, as a count
     memo_tags: Dict[str, Dict[str, int]] = field(default_factory=dict)
+    #: random-forest grid accounting (``count_rf_grid``): what
+    #: ``RFGridGroup`` was asked for (``candidates``, of them ``truncated``
+    #: ones read off a deeper base's leaf snapshot) and what was grown for
+    #: it (``bases``, ``pairs`` = base x fold forests and each refit's one,
+    #: ``treesGrown``, ``launches`` of ``chunk`` trees at histogram width
+    #: ``msub`` and ``levels`` heap levels)
+    rf_grid: Dict[str, int] = field(default_factory=dict)
     #: elastic-sweep accounting (parallel/elastic.py mirrors its per-sweep
     #: ElasticCounters here): retries / mesh_shrinks / mesh_repacks /
     #: quarantined / watchdog_fires / device_losses
@@ -233,6 +240,7 @@ class RunCounters:
             "launches": self.launches,
             "launchTags": dict(self.launch_tags),
             "memoTags": {k: dict(v) for k, v in self.memo_tags.items()},
+            "rfGrid": dict(self.rf_grid),
             "elastic": dict(self.elastic),
             "refresh": dict(self.refresh),
         }
@@ -317,6 +325,24 @@ def count_memo(kind: str, outcome: str) -> None:
         tags = COUNTERS.memo_tags.setdefault(
             kind, {"hits": 0, "builds": 0, "waits": 0})
         tags[outcome] += 1
+
+
+#: ``rfGrid`` keys that describe a launch's shape: the largest seen is kept
+_RF_GRID_SHAPES = ("chunk", "msub", "levels")
+
+
+def count_rf_grid(**counts: int) -> None:
+    """Random-forest grid accounting (``RunCounters.rf_grid``): counts add
+    up over a run (the sweep's base pairs and the winner's refit are two
+    calls); the shape keys ``chunk``, ``msub`` and ``levels`` keep the
+    largest value seen."""
+    with _COUNTERS_LOCK:
+        tags = COUNTERS.rf_grid
+        for key, n in counts.items():
+            if key in _RF_GRID_SHAPES:
+                tags[key] = max(tags.get(key, 0), int(n))
+            else:
+                tags[key] = tags.get(key, 0) + int(n)
 
 
 def count_elastic(kind: str, n: int = 1) -> None:
