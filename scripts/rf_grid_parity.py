@@ -5,15 +5,24 @@ reference, on the chip, at the timed size of the cell ``dense500-rf-grid18``.
   chiprun -- python scripts/rf_grid_parity.py [--seed N --rows R --cols D
                                                --trees T --out FILE]
 
-For fold 0 of the base pair (min_info_gain 0.001, min_instances_per_node 10)
-it grows the program's forest once at depth 12 with leaf snapshots at levels
-3 and 6 (``gbdt_kernels.grow_rf_grid``, as ``RFGridGroup.run`` calls it for
-the cell's grid) and compares its trees (the first, and with it every other
-of the forest: one tree of 22 of 500 columns may hold three splits), each
-read at depths 3 and 6 the way the group reads a truncated candidate (sliced
-heap + snapshot leaves) and at depth 12 as grown, with
-``perfbench/reference/rf_grid.grow_tree`` grown in float64 NumPy directly at
-each depth on the same binned matrix, fold weights, bag and feature subset.
+For fold 0 and min_instances_per_node 10 it grows, in one call of
+``gbdt_kernels.grow_rf_grid`` at depth 12 with the pruning outputs on, the
+forest of each min_info_gain of the cell's grid (0.001, 0.01, 0.1).  The
+first is what ``RFGridGroup.run`` grows as the BASE of all three since PR 35
+(gate sharing); the other two are what it grew for their candidates before.
+Every candidate (gate x depth 3 / 6 / 12, each of the forest's trees: one
+tree of 22 of 500 columns may hold three splits) is then read off the base
+the way the group reads it (``gbdt_kernels.prune_rf_grid``: nodes whose gate
+ratio fails the candidate's gate cut, the heap truncated at its depth) and
+compared with
+
+ (i) the SAME candidate as the program grows it directly under its own gate
+     (sliced heap + that level's values, as the group read a truncated
+     candidate before PR 35): every heap node must be EQUAL, leaves within
+     ``leaf_atol``;
+ (ii) ``perfbench/reference/rf_grid.grow_tree`` grown in float64 NumPy
+     directly at the candidate's own gate and depth on the same binned
+     matrix, fold weights, bag and feature subset.
 
 The rows are ``planted_linear`` at ``--seed`` (the cell's generator and
 planted weights); the matrix is the raw float32 columns (what the cell's
@@ -22,9 +31,10 @@ without nulls: the 500 null indicators are constant and dropped); the row
 weights and folds are the selector's own (``DataBalancer``,
 ``make_folds``), built here as ``ModelSelector.fit_columns`` builds them.
 
-Reported for each depth: the share of heap nodes with equal (feature,
-threshold), and the largest absolute leaf difference.  Every node that
-differs where all its ancestors agree (so both sides split the same rows)
+Reported for each candidate: the heap nodes equal to direct growth's, the
+share of heap nodes with the reference's (feature, threshold), and the
+largest absolute leaf difference of each.  Every node that differs from the
+reference where all its ancestors agree (so both sides split the same rows)
 is LISTED with both choices and the float64 gain of each on that node's
 rows: a tolerance never hides a node.  What may differ, and why: on the chip
 the histogram dots take bf16 operands (``_accel_bf16()``), so a row's
@@ -57,7 +67,10 @@ sys.path.insert(0, ROOT)
 #: sums of bf16-rounded weights against f64 sums of the exact ones)
 TOLERANCE = {"gain_rtol": 2e-3, "leaf_atol": 2e-3}
 DEPTHS = (3, 6, 12)
-GATES = (0.001, 10.0)
+#: the cell's min_info_gain values (the lowest is the base) and the
+#: min_instances_per_node of the base pair compared
+GATES = (0.001, 0.01, 0.1)
+MIN_INSTANCES = 10.0
 
 
 def selector_weights(y: np.ndarray, folds: int, seed: int):
@@ -154,6 +167,7 @@ def main() -> int:
     from perfbench.reference import rf_grid
     from transmogrifai_tpu.models.gbdt_kernels import (_accel_bf16,
                                                        grow_rf_grid,
+                                                       prune_rf_grid,
                                                        rf_bags_and_features)
     from transmogrifai_tpu.models.trees import (_feature_subset_size,
                                                 _prep_tree_inputs_weighted)
@@ -173,17 +187,34 @@ def main() -> int:
     Y = np.eye(2, dtype=np.float32)[y.astype(int)]
 
     t0 = time.perf_counter()
-    feats, threshs, leaves, snaps = grow_rf_grid(
+    G = len(GATES)
+    grown = grow_rf_grid(
         binned_dev, jnp.asarray(Y), jnp.asarray(W_tr), seed=seed,
-        n_trees=a.trees, pair_fold=np.zeros(1, np.int32),
-        pair_min_ig=np.asarray([GATES[0]], np.float32),
-        pair_min_inst=np.asarray([GATES[1]], np.float32),
-        pair_depth=np.asarray([max(DEPTHS)], np.int32), msub=msub,
+        n_trees=a.trees, pair_fold=np.zeros(G, np.int32),
+        pair_min_ig=np.asarray(GATES, np.float32),
+        pair_min_inst=np.full(G, MIN_INSTANCES, np.float32),
+        pair_depth=np.full(G, max(DEPTHS), np.int32), msub=msub,
         subsample_rate=1.0, n_bins=a.bins, onehot_targets=True,
-        leaf_levels=DEPTHS[:-1])
-    pf, pt, pl = (np.asarray(v)[0] for v in (feats, threshs, leaves))
-    snaps = {lv: np.asarray(v)[0] for lv, v in snaps.items()}
+        prune_outputs=True)
+    feats, threshs, leaves, (level_values, _, _) = grown
+    jax.block_until_ready(leaves)
     grow_s = time.perf_counter() - t0
+
+    def at_level(depth):
+        return leaves if depth == max(DEPTHS) else level_values[depth]
+
+    derived, direct = {}, {}
+    for depth in DEPTHS:
+        nd = 2 ** depth - 1
+        # every gate's candidate off pair 0, the base
+        got = prune_rf_grid(
+            *grown, np.zeros(G, np.int32), np.asarray(GATES, np.float32),
+            depth=depth, n_bins=a.bins)
+        for gi, gate in enumerate(GATES):
+            derived[gate, depth] = tuple(np.asarray(v[gi]) for v in got)
+            direct[gate, depth] = (np.asarray(feats[gi, :, :nd]),
+                                   np.asarray(threshs[gi, :, :nd]),
+                                   np.asarray(at_level(depth)[gi]))
     bags, subsets = rf_bags_and_features(seed, a.trees, a.rows, a.cols, msub,
                                          1.0)
     binned = np.asarray(binned_dev)
@@ -193,56 +224,69 @@ def main() -> int:
                          "kind": device.device_kind},
               "bf16_operands": bool(_accel_bf16()), "seed": a.seed,
               "rows": a.rows, "cols": a.cols, "trees": a.trees,
-              "msub": msub, "gates": GATES, "fold": 0,
+              "msub": msub, "gates": GATES, "base_gate": GATES[0],
+              "min_instances": MIN_INSTANCES, "fold": 0,
               "row_weights": sorted(float(v) for v in np.unique(base_w)),
               "program_grow_s": round(grow_s, 3), "tolerance": TOLERANCE,
-              "depths": []}
+              "candidates": []}
     ok = True
-    for depth in DEPTHS:
-        nd = 2 ** depth - 1
-        t0 = time.perf_counter()
-        trees = []
-        for t in range(a.trees):
-            weight = W_tr[0].astype(np.float64) * bags[t]
-            leaf = pl[t] if depth == max(DEPTHS) else snaps[depth][t]
-            ref = rf_grid.grow_tree(binned, yi, weight, subsets[t], depth,
-                                    GATES[0], GATES[1], a.bins)
-            part = compare((pf[t, :nd], pt[t, :nd], leaf), ref, binned, yi,
-                           weight, a.bins)
-            part["tree"] = t
-            # a node where one side does not split has no gain to compare
-            if any(node["gain_rel_diff"] is None
-                   or node["gain_rel_diff"] > TOLERANCE["gain_rtol"]
-                   for node in part["first_differing"]):
+    for gate in GATES:
+        for depth in DEPTHS:
+            t0 = time.perf_counter()
+            df, dt_, dl = derived[gate, depth]
+            pf, pt, pl = direct[gate, depth]
+            # (i) the program's own direct growth: equal, node for node
+            same_direct = (df == pf) & (dt_ == pt)
+            direct_leaf = float(np.abs(dl - pl).max())
+            if not same_direct.all() or direct_leaf > TOLERANCE["leaf_atol"]:
                 ok = False
-            if (part["equal_share"] == 1.0
-                    and part["leaf_max_abs_diff"] > TOLERANCE["leaf_atol"]):
-                ok = False
-            trees.append(part)
-        nodes = sum(p["nodes"] for p in trees)
-        report["depths"].append({
-            "depth": depth, "first_tree": trees[0], "nodes": nodes,
-            "equal_nodes": sum(p["equal_nodes"] for p in trees),
-            "equal_share": sum(p["equal_nodes"] for p in trees) / nodes,
-            "split_nodes_reference": [p["split_nodes_reference"]
-                                      for p in trees],
-            "split_nodes_program": [p["split_nodes_program"] for p in trees],
-            "trees_equal": sum(p["equal_share"] == 1.0 for p in trees),
-            "leaf_max_abs_diff_of_equal_trees": max(
-                [p["leaf_max_abs_diff"] for p in trees
-                 if p["equal_share"] == 1.0], default=None),
-            "leaf_max_abs_diff": max(p["leaf_max_abs_diff"] for p in trees),
-            "first_differing": [dict(n, tree=p["tree"]) for p in trees
-                                for n in p["first_differing"]],
-            "reference_grow_s": round(time.perf_counter() - t0, 3)})
+            # (ii) the float64 reference at the candidate's gate and depth
+            trees = []
+            for t in range(a.trees):
+                weight = W_tr[0].astype(np.float64) * bags[t]
+                ref = rf_grid.grow_tree(binned, yi, weight, subsets[t],
+                                        depth, gate, MIN_INSTANCES, a.bins)
+                part = compare((df[t], dt_[t], dl[t]), ref, binned, yi,
+                               weight, a.bins)
+                part["tree"] = t
+                # a node where one side does not split has no gain to
+                # compare
+                if any(node["gain_rel_diff"] is None
+                       or node["gain_rel_diff"] > TOLERANCE["gain_rtol"]
+                       for node in part["first_differing"]):
+                    ok = False
+                if (part["equal_share"] == 1.0 and
+                        part["leaf_max_abs_diff"] > TOLERANCE["leaf_atol"]):
+                    ok = False
+                trees.append(part)
+            nodes = sum(p["nodes"] for p in trees)
+            report["candidates"].append({
+                "min_info_gain": gate, "depth": depth,
+                "derived_from_base": gate != GATES[0] or depth != max(DEPTHS),
+                "nodes": nodes,
+                "equal_nodes_direct": int(same_direct.sum()),
+                "leaf_max_abs_diff_direct": direct_leaf,
+                "equal_nodes": sum(p["equal_nodes"] for p in trees),
+                "equal_share": sum(p["equal_nodes"] for p in trees) / nodes,
+                "split_nodes_reference": [p["split_nodes_reference"]
+                                          for p in trees],
+                "split_nodes_program": [p["split_nodes_program"]
+                                        for p in trees],
+                "trees_equal": sum(p["equal_share"] == 1.0 for p in trees),
+                "leaf_max_abs_diff_of_equal_trees": max(
+                    [p["leaf_max_abs_diff"] for p in trees
+                     if p["equal_share"] == 1.0], default=None),
+                "leaf_max_abs_diff": max(p["leaf_max_abs_diff"]
+                                         for p in trees),
+                "first_differing": [dict(n, tree=p["tree"]) for p in trees
+                                    for n in p["first_differing"]],
+                "reference_grow_s": round(time.perf_counter() - t0, 3)})
     report["within_tolerance"] = ok
     os.makedirs(os.path.dirname(a.out), exist_ok=True)
     with open(a.out, "w") as f:
         json.dump(report, f, indent=1)
-    for part in report["depths"]:
+    for part in report["candidates"]:
         part["first_differing"] = part["first_differing"][:12]
-        part["first_tree"]["first_differing"] = (
-            part["first_tree"]["first_differing"][:12])
     print(json.dumps(report))
     return 0 if ok else 1
 

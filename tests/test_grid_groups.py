@@ -246,11 +246,10 @@ class TestGBTChainParity:
 
 
 class TestDepthTruncation:
-    """Depth-truncation sharing (round 4): one base forest per
-    (min_info_gain, min_instances) group at the group's max depth must
-    reproduce every shallower max_depth candidate EXACTLY — splits at a
-    level never depend on deeper levels, and the snapshot leaves are the
-    level's own histogram totals."""
+    """Depth-truncation sharing (round 4): one base forest at the group's
+    max depth must reproduce every shallower max_depth candidate EXACTLY —
+    splits at a level never depend on deeper levels, and the truncated
+    leaves are the level's own histogram totals."""
 
     def test_truncation_equals_native_depth_growth(self):
         import jax.numpy as jnp
@@ -273,13 +272,13 @@ class TestDepthTruncation:
             pair_min_ig=np.array([0.01, 0.01], np.float32),
             pair_min_inst=np.array([5.0, 5.0], np.float32),
             pair_depth=np.array([3, 6], np.int32), **kw)
-        # shared growth: ONE base pair at depth 6, snapshot at level 3
-        f_s, t_s, l_s, snaps = grow_rf_grid(
+        # shared growth: ONE base pair at depth 6, every level's values
+        f_s, t_s, l_s, (level_values, _, _) = grow_rf_grid(
             binned, jnp.asarray(Y), jnp.asarray(W),
             pair_fold=np.zeros(1, np.int32),
             pair_min_ig=np.array([0.01], np.float32),
             pair_min_inst=np.array([5.0], np.float32),
-            pair_depth=np.array([6], np.int32), leaf_levels=(3,), **kw)
+            pair_depth=np.array([6], np.int32), prune_outputs=True, **kw)
         # the deep pair is bit-identical to the base pair
         np.testing.assert_array_equal(np.asarray(f_s[0]), np.asarray(f_n[1]))
         np.testing.assert_array_equal(np.asarray(t_s[0]), np.asarray(t_n[1]))
@@ -289,13 +288,13 @@ class TestDepthTruncation:
                                       np.asarray(f_n[0][:, :7]))
         np.testing.assert_array_equal(np.asarray(t_s[0][:, :7]),
                                       np.asarray(t_n[0][:, :7]))
-        # truncated prediction (sliced heap + level-3 snapshot leaves)
+        # truncated prediction (sliced heap + level-3 values as leaves)
         # == the natively grown depth-3 pair's prediction (integer bag
         # weights -> exact histogram sums in both paths)
         p_native = np.asarray(predict_ensemble(
             binned, f_n[0], t_n[0], l_n[0], 6))
         p_trunc = np.asarray(predict_ensemble(
-            binned, f_s[0][:, :7], t_s[0][:, :7], snaps[3][0], 3))
+            binned, f_s[0][:, :7], t_s[0][:, :7], level_values[3][0], 3))
         np.testing.assert_allclose(p_trunc, p_native, atol=1e-6)
 
     def test_shared_group_matches_sequential_three_depths(self, monkeypatch):
@@ -318,10 +317,9 @@ class TestDepthTruncation:
                                                     abs=2e-3)
 
     def test_stump_candidate_in_depth_grid(self):
-        """max_depth=0 (stump) candidates must not be truncation-shared off
-        a deeper base: grow_rf_grid filters non-positive snapshot levels
-        out of its snap map, so the group grows stumps as their own base
-        (ADVICE r4 — this used to KeyError in the scoring loop)."""
+        """max_depth=0 (stump) candidates are not shared off a deeper
+        base: the group grows stumps as their own base (ADVICE r4 — this
+        used to KeyError in the scoring loop)."""
         X, y = _binary_data(1500, 6, seed=9)
         g = make_grid_group(OpRandomForestClassifier(num_trees=4),
                             grid(max_depth=[0, 4], min_info_gain=[0.01]),

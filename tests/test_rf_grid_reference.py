@@ -1,10 +1,12 @@
-"""``RFGridGroup``'s depth-truncation sharing against plain semantics.
+"""``RFGridGroup``'s depth and gate sharing against plain semantics.
 
-The group grows ONE base forest a (min_info_gain, min_instances) pair at the
-grid's deepest depth and reads every shallower candidate off per-level leaf
-snapshots.  ``tests/test_grid_groups.py`` ties that to the program's own
-sequential path; here it is tied to ``perfbench/reference/rf_grid.py``, a
-float64 NumPy forest that grows every candidate directly at ITS OWN depth
+The group grows ONE base forest a min_instances value at the grid's deepest
+depth and lowest min_info_gain and reads every shallower and every
+higher-gated candidate off its level values and gate ratios
+(``gbdt_kernels.prune_rf_grid``).  ``tests/test_grid_groups.py`` and
+``tests/test_rf_gate_sharing.py`` tie that to the program's own direct
+growth; here it is tied to ``perfbench/reference/rf_grid.py``, a float64
+NumPy forest that grows every candidate directly at ITS OWN depth and gate
 from the same binned matrix, bags and feature subsets: split features and
 thresholds must be EQUAL, leaves equal to f32 rounding, the CV metric rows
 those of ``reference/oracle.py`` on the reference's scores, and the winner's
@@ -125,8 +127,8 @@ def _assert_same_forest(got, want):
                          ids=lambda c: "d{max_depth}-ig{min_info_gain}-"
                          "n{min_instances_per_node}".format(**POINTS[c]))
 def test_candidate_s_trees_are_the_reference_s_at_its_own_depth(grown, c):
-    """Truncated candidates as ``run`` scores them (sliced heap + snapshot
-    leaves) and full-depth ones alike; and the CV metric row is the
+    """Truncated and pruned candidates as ``run`` scores them (read off
+    their base) and the bases alike; and the CV metric row is the
     oracle's AuPR of the reference's own scores."""
     point = POINTS[c]
     for f, (w_train, w_eval) in enumerate(grown["ctxs"]):
@@ -171,9 +173,10 @@ def test_rf_grid_counters_say_what_was_asked_for_and_what_was_grown(grown):
     got = grown["counters"]["rfGrid"]
     chunk = got.pop("chunk")
     launches = got.pop("launches")
-    sweep = 6 * FOLDS * TREES
-    assert got == {"candidates": 18, "bases": 6, "pairs": 6 * FOLDS + 2,
-                   "truncated": 12, "treesGrown": sweep + 2 * TREES,
+    sweep = 2 * FOLDS * TREES
+    assert got == {"candidates": 18, "bases": 2, "pairs": 2 * FOLDS + 2,
+                   "truncated": 12, "gateShared": 12,
+                   "treesGrown": sweep + 2 * TREES,
                    "msub": grown["msub"], "levels": BASE_DEPTH}
     # the chunker's own count: the sweep's launches and one a refit
     assert 1 <= chunk <= sweep

@@ -757,10 +757,9 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
                       learning_rate, hist_bf16: bool = False,
                       all_reduce=None, min_gain_raw=None,
                       bag_mode: str = "none", feat_idx=None,
-                      leaf_levels: Tuple[int, ...] = (),
                       default_dir: bool = False, dd_mask=None,
                       bundle_end=None, acc_bf16: bool = False,
-                      binned_T=None):
+                      binned_T=None, prune_outputs: bool = False):
     """One whole tree under trace: Python-unrolled loop over levels.
 
     ``binned_T``: the (d, N) transpose of ``binned`` that the level routing
@@ -784,15 +783,31 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
     the TMOG_MATRIX_PRECISION=bf16 opt-in, quality-gated by the TM028
     tolerance probe.
 
-    ``leaf_levels``: static sorted levels at which to ALSO emit the leaf
-    values of the depth-ℓ TRUNCATION of this tree (one (2^ℓ, K) array per
-    level, 4th return element).  For level-wise greedy growth, splits at
-    level ℓ are independent of deeper levels, so a shallower ``max_depth``
-    grid candidate is exactly this tree truncated at its depth — the
-    snapshot's per-node value sums come FREE from the level's own histogram
-    totals (Σ over bins of any feature's column), so one grown tree serves
-    every depth in a hyperparameter grid (the r3 default grid grew the
-    (min_info_gain, min_instances) × 3-depth product 3x redundantly).
+    ``prune_outputs`` (static): ALSO emit, as the 4th element (an empty
+    tuple otherwise), what reads a candidate of a lower ``max_depth`` or a
+    higher ``min_info_gain`` off this tree (``prune_rf_grid``):
+    ``(level_values, gate_ratio, unsplit_feat)``.
+
+    * ``level_values``: one (2^l, K) array a level 0..max_depth-1, the value
+      of every node of that level were it a leaf.  For level-wise greedy
+      growth, splits at level l are independent of deeper levels, so a
+      shallower ``max_depth`` candidate is exactly this tree truncated at
+      its depth; a node's value sums come FREE from the level's own
+      histogram totals (the sum over the bins of any feature's column).
+    * ``gate_ratio``: the heap of per-node ``best_gain / node_w`` in f32
+      (``-inf`` where no valid split has a positive finite gain), the very
+      number ``ok`` compares with ``min_info_gain``.  The gate takes no
+      part in choosing a node's split (``valid`` holds every other
+      constraint, the argmax is taken, only then is the ratio compared),
+      and a node that fails it keeps all its rows in its left child, which
+      sees the same histogram and fails again: the tree of a higher gate is
+      this tree with every node of a ratio under that gate cut.
+    * ``unsplit_feat``: the feature id this tree writes at a node it does
+      not split (its first subset column).
+
+    So one grown tree serves every depth and every gate of a hyperparameter
+    grid (the r3 default grid grew the 3-gate x 3-depth product 9x
+    redundantly).
 
     This is the dispatch-collapsing design: a per-level kernel approach
     costs depth×trees host dispatches and as many programs to compile;
@@ -968,8 +983,8 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
         onehot_bins = bins_onehot(binned_full)
 
     node = jnp.zeros(n, jnp.int32)
-    heap_feat_levels, heap_thresh_levels = [], []
-    leaf_snaps = []    # (2^l, K) truncation leaf values per leaf_levels entry
+    heap_feat_levels, heap_thresh_levels, heap_ratio_levels = [], [], []
+    level_values = []  # (2^l, K) a level: each node's value were it a leaf
     prev_cums = None   # previous level's per-channel bin cumsums (M, B, d)
 
     for level in range(max_depth):
@@ -1093,12 +1108,11 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
                 GLs = list(cums[:k])
                 HLs = list(cums[k:2 * k])
 
-            if level in leaf_levels:
+            if prune_outputs:
                 # depth-``level`` truncation leaves: per-node value sums are
                 # the histograms' full-bin totals (feature 0's column — every
-                # row of
-                # a node lands in exactly one bin of any feature), so the
-                # snapshot costs no extra data pass
+                # row of a node lands in exactly one bin of any feature), so
+                # the snapshot costs no extra data pass
                 # (M, K)
                 Gs_n = jnp.stack([GL[:, -1, 0] for GL in GLs], axis=1)
                 Hs_n = jnp.stack([HL[:, -1, 0] for HL in HLs], axis=1)
@@ -1109,7 +1123,7 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
                 if compact:
                     snap = jnp.zeros((level_nodes, k), jnp.float32
                                      ).at[uniq].set(snap, mode="drop")
-                leaf_snaps.append(snap)
+                level_values.append(snap)
 
             gain = 0.0
             HLmin = jnp.inf
@@ -1246,6 +1260,14 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
                 seg_feat, seg_thresh = feat_l, thresh_l
             heap_feat_levels.append(seg_feat)
             heap_thresh_levels.append(seg_thresh)
+            if prune_outputs:
+                # the operands of ``ok``'s own gate comparison
+                ratio_l = jnp.where((best_gain > 0) & jnp.isfinite(best_gain),
+                                    best_gain / node_w, -jnp.inf)
+                if compact:
+                    ratio_l = jnp.full(level_nodes, -jnp.inf, jnp.float32
+                                       ).at[uniq].set(ratio_l, mode="drop")
+                heap_ratio_levels.append(ratio_l)
 
         with jax.named_scope("tree.route"):
             # routing reads the FULL-width matrix, rows-minor: subset-local
@@ -1284,7 +1306,12 @@ def _grow_tree_traced(binned, G, H, C, feat_mask, depth_limit,
         newton_val = -learning_rate * Gs / (Hs + lam)
         mean_val = Gs / jnp.maximum(Cs, 1e-12)[:, None]
         leaf = jnp.where(newton_leaf, newton_val, mean_val)
-    return heap_feat, heap_thresh, leaf, tuple(leaf_snaps)
+    if not prune_outputs:
+        return heap_feat, heap_thresh, leaf, ()
+    unsplit_feat = feat_idx[0] if feat_idx is not None else jnp.int32(0)
+    return (heap_feat, heap_thresh, leaf,
+            (tuple(level_values), jnp.concatenate(heap_ratio_levels),
+             unsplit_feat))
 
 
 @functools.partial(jax.jit,
@@ -1526,7 +1553,7 @@ def _grow_chunk_rf(binned, Y, base_w, seed, start, n_trees, depth_limit_val,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "msub", "max_depth",
                                              "n_bins", "onehot_targets",
-                                             "t_per", "leaf_levels",
+                                             "t_per", "prune_outputs",
                                              "hist_bf16"))
 def _grow_chunk_rf_grid(binned, Y, W_tr, seed, flat_start, total,
                         pair_fold, pair_min_ig, pair_min_inst, pair_depth,
@@ -1534,7 +1561,7 @@ def _grow_chunk_rf_grid(binned, Y, W_tr, seed, flat_start, total,
                         max_depth: int, n_bins: int, lam,
                         min_child_weight, t_per: int,
                         onehot_targets: bool = False,
-                        leaf_levels: Tuple[int, ...] = (),
+                        prune_outputs: bool = False,
                         hist_bf16: bool = False):
     """RF chunk spanning the WHOLE (candidate x fold) grid.
 
@@ -1546,10 +1573,11 @@ def _grow_chunk_rf_grid(binned, Y, W_tr, seed, flat_start, total,
     candidate's forest for every fold with results identical to the
     per-candidate path (same randomness, same split masking).
 
-    ``leaf_levels`` additionally emits depth-truncation leaf snapshots per
-    tree (see ``_grow_tree_traced``), which lets the caller run only the
-    unique (min_info_gain, min_instances) × fold pairs at their max grid
-    depth and derive every shallower max_depth candidate for free.
+    ``prune_outputs`` additionally emits each tree's level values, gate
+    ratios and unsplit feature (see ``_grow_tree_traced``), which lets the
+    caller run only the unique min_instances x fold pairs at their deepest
+    grid depth and lowest min_info_gain and derive every shallower and
+    every higher-gated candidate for free (``prune_rf_grid``).
     """
     n, d = binned.shape
     flat = flat_start + jnp.arange(chunk)
@@ -1564,7 +1592,7 @@ def _grow_chunk_rf_grid(binned, Y, W_tr, seed, flat_start, total,
               min_child_weight=min_child_weight, newton_leaf=jnp.bool_(False),
               learning_rate=jnp.float32(1.0), hist_bf16=hist_bf16,
               bag_mode="onehot" if onehot_targets else "bagged",
-              leaf_levels=leaf_levels)
+              prune_outputs=prune_outputs)
 
     def one(bw_row, mig, mins, lim, fi):
         g = bw_row[:, None] * Y
@@ -1582,16 +1610,19 @@ def grow_rf_grid(binned, Y, W_tr, seed: int, n_trees: int,
                  pair_min_inst: np.ndarray, pair_depth: np.ndarray,
                  msub: int, subsample_rate: float, n_bins: int,
                  lam: float = 1e-3, min_child_weight: float = 0.0,
-                 onehot_targets: bool = False,
-                 leaf_levels: Tuple[int, ...] = ()):
+                 onehot_targets: bool = False, prune_outputs: bool = False):
     """Grow every (candidate x fold) pair's forest as one chunked launch
     stream; returns device (P, T, nodes...) stacked ensembles.
 
-    With ``leaf_levels``, additionally returns ``{level: (P, T, 2^level, K)}``
-    depth-truncation leaf snapshots — the caller then needs only the unique
-    (min_info_gain, min_instances) × fold pairs grown at their deepest grid
-    depth, deriving each shallower max_depth candidate by truncation (exact
-    for level-wise growth; splits at a level never depend on deeper ones).
+    With ``prune_outputs``, additionally returns a 4th element
+    ``(level_values, gate_ratio, unsplit_feat)``: one (P, T, 2^l, K) array a
+    level of the heap, (P, T, nodes) and (P, T).  The caller then needs only
+    the unique min_instances x fold pairs, grown at their deepest grid depth
+    and lowest min_info_gain: ``prune_rf_grid`` reads each shallower
+    max_depth candidate off them by truncation (exact for level-wise growth;
+    splits at a level never depend on deeper ones) and each candidate of a
+    higher gate by cutting the nodes whose ratio fails it (exact because the
+    gate takes no part in choosing a split).
     """
     n, d = binned.shape
     k = Y.shape[1]
@@ -1599,8 +1630,6 @@ def grow_rf_grid(binned, Y, W_tr, seed: int, n_trees: int,
     # >= 1: an all-stump grid (every max_depth <= 0) still needs one heap
     # level to emit leaf arrays (depth_limit 0 keeps the trees split-free)
     heap_depth = _resolve_compile_depth(max(int(pair_depth.max()), 1))
-    leaf_levels = tuple(sorted(set(int(v) for v in leaf_levels
-                                   if 0 < int(v) < heap_depth)))
     hist_bf16 = _accel_bf16()
     chunk = forest_chunk_size(
         n_trees * P, heap_depth, msub, n_bins, k, n_rows=n,
@@ -1615,41 +1644,89 @@ def grow_rf_grid(binned, Y, W_tr, seed: int, n_trees: int,
 
     count_rf_grid(treesGrown=total, launches=-(-total // chunk), chunk=chunk,
                   msub=msub, levels=heap_depth)
-    feats, threshs, leaves = [], [], []
-    snaps: List[list] = [[] for _ in leaf_levels]
+    parts = []
     for s in range(0, total, chunk):
         with launch("rf_grid_chunk"):
-            f, t, lf, sn = _grow_chunk_rf_grid(
+            out = _grow_chunk_rf_grid(
                 binned, Y, W_tr, jnp.int32(seed), jnp.int32(s),
                 jnp.int32(total), pf, pg, pi, pd_,
                 jnp.float32(subsample_rate), chunk, msub,
                 heap_depth, n_bins, jnp.float32(lam),
                 jnp.float32(min_child_weight), n_trees,
-                onehot_targets=onehot_targets, leaf_levels=leaf_levels,
+                onehot_targets=onehot_targets, prune_outputs=prune_outputs,
                 hist_bf16=hist_bf16)
-        e = min(s + chunk, total)
-        feats.append(f[:e - s])
-        threshs.append(t[:e - s])
-        leaves.append(lf[:e - s])
-        for li, sv in enumerate(sn):
-            snaps[li].append(sv[:e - s])
-    if len(feats) > 1:
-        feats = jnp.concatenate(feats)
-        threshs = jnp.concatenate(threshs)
-        leaves = jnp.concatenate(leaves)
-        snaps = [jnp.concatenate(sv) for sv in snaps]
-    else:
-        feats, threshs, leaves = feats[0], threshs[0], leaves[0]
-        snaps = [sv[0] for sv in snaps]
-    nodes = feats.shape[1]
-    out = (feats.reshape(P, n_trees, nodes),
-           threshs.reshape(P, n_trees, nodes),
-           leaves.reshape(P, n_trees, *leaves.shape[1:]))
-    if not leaf_levels:
-        return out
-    snap_map = {lv: sv.reshape(P, n_trees, *sv.shape[1:])
-                for lv, sv in zip(leaf_levels, snaps)}
-    return (*out, snap_map)
+        parts.append(out)
+    out = _stack_rf_grid_chunks(parts, total, n_trees)
+    return out if prune_outputs else out[:3]
+
+
+@functools.partial(jax.jit, static_argnames=("total", "n_trees"))
+def _stack_rf_grid_chunks(parts, total: int, n_trees: int):
+    """The launches' outputs as (pairs, trees, ...) arrays, the last
+    launch's padding trees dropped: ONE program for all of a sweep's output
+    arrays (a slice, a concatenation and a reshape each would be three
+    programs an array to load, and as many dispatches a train)."""
+    def stack(*chunks):
+        flat = jnp.concatenate(chunks)[:total]
+        return flat.reshape(total // n_trees, n_trees, *flat.shape[1:])
+
+    return jax.tree_util.tree_map(stack, *parts)
+
+
+@functools.partial(jax.jit, static_argnames=("depth", "n_bins"))
+def prune_rf_grid(feats, threshs, leaves, prune_outputs, sel, gates,
+                  depth: int, n_bins: int):
+    """Candidates of max_depth ``depth`` (1 to the heap's) and min_info_gain
+    ``gates`` read off base forests grown as deep or deeper and under the
+    same gate or a lower one.
+
+    The first four arguments are ``grow_rf_grid``'s four results for the P
+    base pairs; ``sel`` (S,) names each candidate's base pair, ``gates``
+    (S,) its min_info_gain.  Returns (S, T, 2^depth - 1) features and
+    thresholds and (S, T, 2^depth, K) leaves, each what
+    ``_grow_tree_traced`` writes when it grows the candidate directly, dead
+    nodes included: a node splits iff every ancestor split, the base split
+    it and its ratio passes the candidate's gate (the kernel's own f32
+    comparison); every other node holds the unsplit feature and threshold
+    ``n_bins``.  A node the base split and the gate cuts keeps all its rows
+    down its LEFT spine, so its own level's value lands on its leftmost
+    descendant at level ``depth`` and zero on the rest of its subtree;
+    everywhere else the base's own values of level ``depth`` stand, bit for
+    bit (its grown leaves, the f32 leaf sums, where ``depth`` is the heap's,
+    else that level's histogram totals): a candidate of its base's gate
+    comes out as truncation alone.
+    """
+    level_values, gate_ratio, unsplit_feat = prune_outputs
+    full = 2 ** depth - 1 == feats.shape[-1]
+    feats, threshs, values, gate_ratio, unsplit_feat = (
+        a[sel] for a in (feats, threshs,
+                         leaves if full else level_values[depth],
+                         gate_ratio, unsplit_feat))
+    S, T = unsplit_feat.shape
+    gate = gates.astype(jnp.float32)[:, None, None]
+    unsplit = unsplit_feat[:, :, None]
+    alive = jnp.ones((S, T, 1), bool)
+    cut_below = jnp.zeros((S, T, 1), bool)     # under a node the gate cut
+    carried = jnp.zeros((S, T, 1, values.shape[-1]), values.dtype)
+    out_feat, out_thresh = [], []
+    for level in range(depth):
+        seg = slice(2 ** level - 1, 2 ** (level + 1) - 1)
+        base_split = alive & (threshs[..., seg] < n_bins)
+        passes = gate_ratio[..., seg] >= gate
+        split = base_split & passes
+        cut = base_split & ~passes
+        out_feat.append(jnp.where(split, feats[..., seg], unsplit))
+        out_thresh.append(jnp.where(split, threshs[..., seg], n_bins))
+        carried = jnp.where(cut[..., None], level_values[level][sel], carried)
+        cut_below = cut_below | cut
+        # children: (left, right) interleaved; a carried value goes left
+        alive = jnp.repeat(split, 2, axis=-1)
+        cut_below = jnp.repeat(cut_below, 2, axis=-1)
+        carried = jnp.stack([carried, jnp.zeros_like(carried)],
+                            axis=3).reshape(S, T, 2 ** (level + 1), -1)
+    return (jnp.concatenate(out_feat, axis=-1),
+            jnp.concatenate(out_thresh, axis=-1),
+            jnp.where(cut_below[..., None], carried, values))
 
 
 def grow_forest_rf(binned, Y, base_w, seed: int, n_trees: int, msub: int,

@@ -345,7 +345,7 @@ def grow_rf_grid_sharded(binned, Y, W_tr, BWr, feat_idx, pair_fold,
                          n_bins: int, heap_depth: int, lam: float = 1e-3,
                          min_child_weight: float = 0.0,
                          onehot_targets: bool = False,
-                         leaf_levels=()):
+                         prune_outputs: bool = False):
     """The mesh form of ``gbdt_kernels.grow_rf_grid``: every (candidate x
     fold) pair's forest grown as chunked shard_map launches — the flat
     tree axis (pair * n_trees + t) sharded over the GRID axis, rows over
@@ -357,8 +357,9 @@ def grow_rf_grid_sharded(binned, Y, W_tr, BWr, feat_idx, pair_fold,
     both grow identical forests): ``BWr`` (T, N_pad) Poisson bags with
     zero on pad rows, committed P(None, "data") alongside the (F, N_pad)
     fold weights; ``feat_idx`` (T, msub) replicated.  Returns HOST
-    (P, T, nodes)/(P, T, leaves, K) arrays (+ the depth-truncation
-    snapshot map when ``leaf_levels``), matching ``grow_rf_grid``.
+    (P, T, nodes)/(P, T, leaves, K) arrays (+ the level values, gate
+    ratios and unsplit features when ``prune_outputs``), matching
+    ``grow_rf_grid``.
     """
     from ..models.gbdt_kernels import (_accel_bf16, _grow_tree_traced,
                                        forest_chunk_size)
@@ -373,8 +374,6 @@ def grow_rf_grid_sharded(binned, Y, W_tr, BWr, feat_idx, pair_fold,
     P_pairs = int(pair_fold.shape[0])
     total = n_trees * P_pairs
     hist_bf16 = _accel_bf16()
-    leaf_levels = tuple(sorted(set(int(v) for v in leaf_levels
-                                   if 0 < int(v) < heap_depth)))
     chunk = forest_chunk_size(
         total, heap_depth, msub, n_bins, k, n_rows=nl, compact=False,
         n_channels=(k if onehot_targets else k + 1), d_full=d,
@@ -385,7 +384,7 @@ def grow_rf_grid_sharded(binned, Y, W_tr, BWr, feat_idx, pair_fold,
 
     key = ("rf", _mesh_cache_key(mesh), chunk, heap_depth, n_bins, msub,
            float(lam), float(min_child_weight), onehot_targets,
-           leaf_levels, hist_bf16)
+           prune_outputs, hist_bf16)
     fn = _TREE_SWEEP_JITS.get(key)
     if fn is None:
         psum_d = functools.partial(lax.psum, axis_name=data_axis)
@@ -410,17 +409,19 @@ def grow_rf_grid_sharded(binned, Y, W_tr, BWr, feat_idx, pair_fold,
                     learning_rate=jnp.float32(1.0),
                     hist_bf16=hist_bf16, all_reduce=psum_d,
                     bag_mode="onehot" if onehot_targets else "bagged",
-                    feat_idx=fidx, leaf_levels=leaf_levels)
+                    feat_idx=fidx, prune_outputs=prune_outputs)
 
-            f, t, lf, snaps = jax.vmap(one)(bw, mig, mi, dep, fi_l)
-            return f, t, lf, snaps
+            return jax.vmap(one)(bw, mig, mi, dep, fi_l)
 
         # explicit out_shardings matching the shard_map out_specs — chunked
         # async launches keep a fixed grid-sharded output layout, so the
         # dispatch loop never forces a resharding between in-flight chunks
         out_specs = (P(grid_axis, None), P(grid_axis, None),
                      P(grid_axis, None, None),
-                     tuple(P(grid_axis, None, None) for _ in leaf_levels))
+                     (tuple(P(grid_axis, None, None)
+                            for _ in range(heap_depth)),
+                      P(grid_axis, None), P(grid_axis))
+                     if prune_outputs else ())
         fn = jax.jit(
             shard_map_compat(
                 shard_fn, mesh,
@@ -435,8 +436,7 @@ def grow_rf_grid_sharded(binned, Y, W_tr, BWr, feat_idx, pair_fold,
         _TREE_SWEEP_JITS[key] = fn
 
     gs = grid_sharding(mesh)
-    feats, threshs, leaves = [], [], []
-    snap_parts = [[] for _ in leaf_levels]
+    parts = []
     fi_dev = jnp.asarray(np.asarray(feat_idx, np.int32))
     for s in range(0, total, chunk):
         with launch("rf_grid_chunk_sharded"):
@@ -449,27 +449,15 @@ def grow_rf_grid_sharded(binned, Y, W_tr, BWr, feat_idx, pair_fold,
                 np.asarray(pair_min_inst, np.float32)[p_idx],
                 np.asarray(pair_depth, np.int32)[p_idx],
                 (flat < total).astype(np.int32))]
-            f, t, lf, snaps = fn(binned, Y, W_tr, BWr, fi_dev, *args)
+            out = fn(binned, Y, W_tr, BWr, fi_dev, *args)
         e = min(s + chunk, total)
-        feats.append(np.asarray(f)[: e - s])
-        threshs.append(np.asarray(t)[: e - s])
-        leaves.append(np.asarray(lf)[: e - s])
-        for li, sv in enumerate(snaps):
-            snap_parts[li].append(np.asarray(sv)[: e - s])
-    feats = np.concatenate(feats) if len(feats) > 1 else feats[0]
-    threshs = np.concatenate(threshs) if len(threshs) > 1 else threshs[0]
-    leaves = np.concatenate(leaves) if len(leaves) > 1 else leaves[0]
-    nodes = feats.shape[1]
-    out = (feats.reshape(P_pairs, n_trees, nodes),
-           threshs.reshape(P_pairs, n_trees, nodes),
-           leaves.reshape(P_pairs, n_trees, *leaves.shape[1:]))
-    if not leaf_levels:
-        return out
-    snap_map = {}
-    for lv, parts in zip(leaf_levels, snap_parts):
-        sv = np.concatenate(parts) if len(parts) > 1 else parts[0]
-        snap_map[lv] = sv.reshape(P_pairs, n_trees, *sv.shape[1:])
-    return (*out, snap_map)
+        parts.append(jax.tree_util.tree_map(
+            lambda a: np.asarray(a)[: e - s], out))
+    out = (jax.tree_util.tree_map(lambda *a: np.concatenate(a), *parts)
+           if len(parts) > 1 else parts[0])
+    out = jax.tree_util.tree_map(
+        lambda a: a.reshape(P_pairs, n_trees, *a.shape[1:]), out)
+    return out if prune_outputs else out[:3]
 
 
 # ---------------------------------------------------------------------------

@@ -407,7 +407,10 @@ class RFGridGroup(TreeGridGroup):
     """Every (candidate x fold) random-forest fit as ONE chunked tree
     stream (``gbdt_kernels.grow_rf_grid``): per-tree traced
     (min_info_gain, min_instances, depth_limit) + fold-weight selection,
-    identical randomness to the sequential per-candidate fits.  Covers
+    identical randomness to the sequential per-candidate fits.  Candidates
+    that differ only in max_depth and min_info_gain share ONE grown base
+    forest a fold (the deepest, under the lowest gate) and are read off it
+    by truncation and pruning (``gbdt_kernels.prune_rf_grid``).  Covers
     binary, multiclass (one-hot targets, argmax scores against the
     multiclass metric grid) and regression sweeps.  On a sweep mesh the
     same pair stream runs sharded (``grow_rf_grid_sharded``) with
@@ -441,7 +444,7 @@ class RFGridGroup(TreeGridGroup):
                                           binary_metric_grid,
                                           multiclass_metric_grid,
                                           regression_metric_grid)
-        from ..models.gbdt_kernels import grow_rf_grid
+        from ..models.gbdt_kernels import grow_rf_grid, prune_rf_grid
         from ..models.trees import (_dev_memo, _feature_subset_size,
                                     _score_ensemble_jit)
         from ..obs.trace import span as _span
@@ -485,35 +488,35 @@ class RFGridGroup(TreeGridGroup):
         C = len(self.grid_points)
         T = int(self._param(self.grid_points[0], "num_trees"))
 
-        # Depth-truncation sharing: candidates that differ ONLY in max_depth
-        # share bags/folds by construction (bags key on tree id), and for
-        # level-wise greedy growth a shallower candidate is exactly the
-        # deeper tree truncated at its depth (splits at level l never depend
-        # on deeper levels).  Grow ONE base forest per distinct
-        # (min_info_gain, min_instances) group at that group's max depth and
-        # read every shallower candidate off the base trees' leaf snapshots
-        # — the r3 default grid (3 depths x 6 gate combos) grew 3x the
-        # trees this needs.  The reference pays the full redundancy on its
-        # thread pool (OpCrossValidation.scala:113-138).
-        # clamp at 0: any non-positive requested depth IS a stump (and the
-        # base_depth accumulator below starts at 0, so an unclamped -1
-        # would read as "truncated below its base" and KeyError)
+        # Depth and gate sharing: candidates that differ ONLY in max_depth
+        # and min_info_gain share bags/folds by construction (bags key on
+        # tree id), and both are read off ONE grown tree exactly.  For
+        # level-wise greedy growth a shallower candidate is the deeper tree
+        # truncated at its depth (splits at level l never depend on deeper
+        # levels); and min_info_gain is a pure gate (it takes no part in
+        # choosing a node's split, and a node that fails it keeps its rows
+        # in one child, which fails again), so a candidate of a higher gate
+        # is the tree of the lower gate with the nodes that fail it cut.
+        # Grow ONE base forest per distinct min_instances at that group's
+        # max depth and min gate and read every other candidate off the
+        # base trees' level values and gate ratios — the r3 default grid
+        # (3 depths x 3 gates x 2 min_instances) grew 9x the trees this
+        # needs.  The reference pays the full redundancy on its thread
+        # pool (OpCrossValidation.scala:113-138).
+        # clamp at 0: any non-positive requested depth IS a stump
         cand_depth = [max(0, int(self._param(p, "max_depth")))
                       for p in self.grid_points]
-        # depth <= 0 (stump) candidates get their OWN base: grow_rf_grid
-        # filters non-positive levels out of its snapshot map (0 < v <
-        # heap_depth), so truncation-sharing them off a deeper base would
-        # KeyError in the scoring loop (ADVICE r4) — and a stump needs no
-        # sharing anyway (depth_limit=0 grows it directly)
-        cand_key = [(float(self._param(p, "min_info_gain")),
-                     float(self._param(p, "min_instances_per_node")))
+        cand_ig = [float(self._param(p, "min_info_gain"))
+                   for p in self.grid_points]
+        # depth <= 0 (stump) candidates get their OWN base, keyed by their
+        # gate too: a stump needs no sharing (depth_limit=0 grows it
+        # directly; ADVICE r4)
+        cand_key = [(float(self._param(p, "min_instances_per_node")),)
                     if cand_depth[i] > 0 else
-                    (float(self._param(p, "min_info_gain")),
-                     float(self._param(p, "min_instances_per_node")),
-                     cand_depth[i])
+                    (float(self._param(p, "min_instances_per_node")),
+                     cand_ig[i], cand_depth[i])
                     for i, p in enumerate(self.grid_points)]
-        # keys are (ig, inst) 2-tuples, or (ig, inst, depth) 3-tuples for
-        # stump candidates — consumers below read k[0]/k[1] only
+        # keys lead with min_instances: consumers below read k[0] only
         base_keys: List[tuple] = []
         key2base: Dict[tuple, int] = {}
         for key in cand_key:
@@ -521,23 +524,26 @@ class RFGridGroup(TreeGridGroup):
                 key2base[key] = len(base_keys)
                 base_keys.append(key)
         Cb = len(base_keys)
-        base_depth = [0] * Cb
-        for ci in range(C):
-            bi = key2base[cand_key[ci]]
-            base_depth[bi] = max(base_depth[bi], cand_depth[ci])
-        leaf_levels = tuple(sorted({
-            cand_depth[ci] for ci in range(C)
-            if cand_depth[ci] < base_depth[key2base[cand_key[ci]]]}))
+        cand_base = [key2base[key] for key in cand_key]
+        base_depth = [max(cand_depth[ci] for ci in range(C)
+                          if cand_base[ci] == bi) for bi in range(Cb)]
+        base_ig = [min(cand_ig[ci] for ci in range(C)
+                       if cand_base[ci] == bi) for bi in range(Cb)]
+        truncated = [cand_depth[ci] < base_depth[cand_base[ci]]
+                     for ci in range(C)]
+        gate_shared = [cand_ig[ci] > base_ig[cand_base[ci]]
+                       for ci in range(C)]
+        # the growth emits what pruning reads only where a candidate is
+        # not its own base
+        prune = any(truncated) or any(gate_shared)
 
         # base pair p = bi * F + f
         pair_fold = np.tile(np.arange(F, dtype=np.int32), Cb)
-        pair_ig = np.repeat([k[0] for k in base_keys], F)
-        pair_inst = np.repeat([k[1] for k in base_keys], F)
+        pair_ig = np.repeat(base_ig, F)
+        pair_inst = np.repeat([k[0] for k in base_keys], F)
         pair_depth = np.repeat(base_depth, F)
-        count_rf_grid(
-            candidates=C, bases=Cb, pairs=Cb * F,
-            truncated=sum(cand_depth[ci] < base_depth[key2base[cand_key[ci]]]
-                          for ci in range(C)))
+        count_rf_grid(candidates=C, bases=Cb, pairs=Cb * F,
+                      truncated=sum(truncated), gateShared=sum(gate_shared))
         t0 = _time.perf_counter()
         subsample = float(self._param(self.grid_points[0],
                                       "subsample_rate"))
@@ -550,53 +556,55 @@ class RFGridGroup(TreeGridGroup):
                     pair_fold=pair_fold, pair_ig=pair_ig,
                     pair_inst=pair_inst, pair_depth=pair_depth, msub=msub,
                     subsample=subsample, mb=mb, cls=cls,
-                    leaf_levels=leaf_levels)
+                    prune_outputs=prune)
             else:
                 grown = grow_rf_grid(
                     binned, _dev_memo(Y, "rf_Y"), _dev_memo(W_tr, "rf_Wtr"),
                     seed=int(proto.seed), n_trees=T, pair_fold=pair_fold,
                     pair_min_ig=pair_ig, pair_min_inst=pair_inst,
                     pair_depth=pair_depth, msub=msub,
-                    subsample_rate=subsample,
-                    n_bins=int(self._param(self.grid_points[0], "max_bins")),
-                    onehot_targets=cls, leaf_levels=leaf_levels)
+                    subsample_rate=subsample, n_bins=mb,
+                    onehot_targets=cls, prune_outputs=prune)
         self._record_grid_observation(_time.perf_counter() - t0, n, d)
         feats, threshs, leaves = grown[:3]
-        snap_map = grown[3] if leaf_levels else {}
         heap_depth = int(np.log2(feats.shape[2] + 1))
         mode = "rf_cls" if cls else "rf_reg"
         ptype = ("multiclass" if multiclass
                  else "binary" if cls else "regression")
 
-        # candidate-pair cp = c * F + f -> base pair + truncation depth
-        cp_base = np.asarray(
-            [key2base[cand_key[c]] * F + f
-             for c in range(C) for f in range(F)], np.int32)
+        # candidate-pair cp = c * F + f -> base pair, depth and gate
+        cp_base = np.repeat(cand_base, F) * F + np.tile(
+            np.arange(F, dtype=np.int32), C)
         cp_depth = np.repeat(cand_depth, F)
-        cp_full = np.asarray(
-            [cand_depth[c] == base_depth[key2base[cand_key[c]]]
-             for c in range(C) for f in range(F)], bool)
+        cp_ig = np.repeat(cand_ig, F).astype(np.float32)
+        cp_full = ~np.repeat(truncated, F)
+
+        def part_trees(idx, depth):
+            """The forests of candidate-pairs ``idx`` as heaps of ``depth``
+            levels: the base's own where no candidate of the grid is
+            derived, else read off the bases (a candidate that IS its base
+            comes out as it went in)."""
+            sel = cp_base[idx]            # numpy: indexes device OR host
+            if not prune:
+                return feats[sel], threshs[sel], leaves[sel]
+            return prune_rf_grid(*grown, sel, cp_ig[idx], depth=depth,
+                                 n_bins=mb)
+
+        # one scoring part the full-depth candidates (heaps of the
+        # program's depth, the f32 leaf sums), one a shallower depth (its
+        # leaves are the level's histogram totals)
         order: List[int] = []
         parts = []
-        full_idx = np.where(cp_full)[0]
-        if len(full_idx):
-            sel = cp_base[full_idx]       # numpy: indexes device OR host
-            with _span(f"rf.grid.score:d{heap_depth}", cat="sweep",
-                       pairs=len(full_idx)):
+        part_depths = [(np.where(cp_full)[0], heap_depth)] + [
+            (np.where(~cp_full & (cp_depth == dt))[0], dt)
+            for dt in sorted(set(cp_depth[~cp_full].tolist()))]
+        for idx, depth in part_depths:
+            if not len(idx):
+                continue
+            with _span(f"rf.grid.score:d{depth}", cat="sweep",
+                       pairs=len(idx)):
                 parts.append(_score_pairs_jit(
-                    binned, feats[sel], threshs[sel], leaves[sel],
-                    heap_depth, mode, ptype))
-            order.extend(full_idx.tolist())
-        for dt in sorted(set(cp_depth[~cp_full].tolist())):
-            idx = np.where(~cp_full & (cp_depth == dt))[0]
-            sel = cp_base[idx]
-            nd = 2 ** dt - 1
-            # the base trees' first dt levels ARE the depth-dt candidate's
-            # splits; its leaves are the level-dt histogram-total snapshot
-            with _span(f"rf.grid.score:d{dt}", cat="sweep", pairs=len(idx)):
-                parts.append(_score_pairs_jit(
-                    binned, feats[sel][:, :, :nd], threshs[sel][:, :, :nd],
-                    snap_map[dt][sel], dt, mode, ptype))
+                    binned, *part_trees(idx, depth), depth, mode, ptype))
             order.extend(idx.tolist())
         scores = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
         if order != list(range(C * F)):
@@ -608,7 +616,7 @@ class RFGridGroup(TreeGridGroup):
         # metric grid dispatches: at 1M-row sweeps the groups run back to
         # back and holding every phase's device intermediates to the end
         # of the sweep needlessly raises cumulative HBM pressure
-        del grown, feats, threshs, leaves, snap_map, parts
+        del grown, feats, threshs, leaves, parts
         # context for refit_model: the winner's full-train forest grows as
         # ONE more base pair through the same (cached) grid program, with
         # identical randomness to a sequential full fit.  Single-chip
@@ -619,10 +627,9 @@ class RFGridGroup(TreeGridGroup):
             self._refit_ctx = dict(
                 binned=binned, Y=Y, edges=edges, msub=msub, mb=mb, T=T,
                 cls=cls, k=Y.shape[1], heap_depth=heap_depth,
-                key2base=key2base, cand_key=cand_key,
-                cand_depth=cand_depth,
-                base_depth=base_depth, base_keys=base_keys,
-                leaf_levels=leaf_levels,
+                cand_base=cand_base, cand_depth=cand_depth,
+                cand_ig=cand_ig, base_depth=base_depth,
+                base_keys=base_keys, prune=prune,
                 full_w=self._full_weights(weight_ctxs),
                 seed=int(proto.seed), subsample=subsample)
         with _span("rf.grid.metrics", cat="sweep", rows=C * F):
@@ -639,7 +646,7 @@ class RFGridGroup(TreeGridGroup):
     def _grow_pairs_sharded(self, binned, Y, W_tr, *, seed: int, T: int,
                             pair_fold, pair_ig, pair_inst, pair_depth,
                             msub: int, subsample: float, mb: int,
-                            cls: bool, leaf_levels):
+                            cls: bool, prune_outputs: bool):
         """The mesh leg of ``run``: rows padded + sharded over the data
         axis, the flat (pair x tree) stream over the grid axis, bags
         pre-generated from the SAME fold_in(seed, tree_id) stream as the
@@ -676,7 +683,7 @@ class RFGridGroup(TreeGridGroup):
             binned_dev, Y_dev, Wtr_dev, BWr_dev, feat_idx,
             pair_fold, pair_ig, pair_inst, pair_depth, mesh,
             n_trees=T, msub=msub, n_bins=mb, heap_depth=heap_depth,
-            onehot_targets=cls, leaf_levels=leaf_levels)
+            onehot_targets=cls, prune_outputs=prune_outputs)
 
     def refit_model(self, row: int):
         """Full-train refit of candidate ``row`` as ONE extra base pair.
@@ -688,8 +695,10 @@ class RFGridGroup(TreeGridGroup):
         deployed forest is what ``fit_raw`` on the full split would grow,
         at ~1/(bases x folds) of the sweep's cost instead of a fresh
         sequential fit + compile (ModelSelector.scala:145-209 refits from
-        scratch).  Shallower-than-base winners come off the base pair's
-        depth-truncation snapshot (exact for level-wise growth)."""
+        scratch).  The pair grows under the winner's OWN min_info_gain (a
+        traced value: nothing to prune) at its base's depth; a
+        shallower-than-base winner comes off the pair's level values
+        (exact for level-wise growth)."""
         ctx = getattr(self, "_refit_ctx", None)
         if ctx is None:
             return None
@@ -700,8 +709,7 @@ class RFGridGroup(TreeGridGroup):
         from ..obs.trace import span as _span
         from ..utils.profiling import count_rf_grid
 
-        key = ctx["cand_key"][row]
-        bi = ctx["key2base"][key]
+        bi = ctx["cand_base"][row]
         dt = ctx["cand_depth"][row]
         bd = ctx["base_depth"][bi]
         count_rf_grid(pairs=1)
@@ -712,17 +720,18 @@ class RFGridGroup(TreeGridGroup):
                 _dev_memo(ctx["full_w"][None], "rf_Wfull"),
                 seed=ctx["seed"], n_trees=ctx["T"],
                 pair_fold=np.zeros(1, np.int32),
-                pair_min_ig=np.asarray([key[0]], np.float32),
-                pair_min_inst=np.asarray([key[1]], np.float32),
+                pair_min_ig=np.asarray([ctx["cand_ig"][row]], np.float32),
+                pair_min_inst=np.asarray([ctx["base_keys"][bi][0]],
+                                         np.float32),
                 pair_depth=np.asarray([bd], np.int32), msub=ctx["msub"],
                 subsample_rate=ctx["subsample"], n_bins=ctx["mb"],
-                onehot_targets=ctx["cls"], leaf_levels=ctx["leaf_levels"])
+                onehot_targets=ctx["cls"], prune_outputs=ctx["prune"])
         feats, threshs, leaves = grown[:3]
-        snap_map = grown[3] if ctx["leaf_levels"] else {}
         if dt < bd:
             nd = 2 ** dt - 1
+            level_values = grown[3][0]
             feat, thresh, leaf = (feats[0][:, :nd], threshs[0][:, :nd],
-                                  snap_map[dt][0])
+                                  level_values[dt][0])
         else:
             feat, thresh, leaf = feats[0], threshs[0], leaves[0]
         return TreeEnsembleModel(

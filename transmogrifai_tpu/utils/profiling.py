@@ -211,8 +211,10 @@ class RunCounters:
     memo_tags: Dict[str, Dict[str, int]] = field(default_factory=dict)
     #: random-forest grid accounting (``count_rf_grid``): what
     #: ``RFGridGroup`` was asked for (``candidates``, of them ``truncated``
-    #: ones read off a deeper base's leaf snapshot) and what was grown for
-    #: it (``bases``, ``pairs`` = base x fold forests and each refit's one,
+    #: ones read off a deeper base's level values and ``gateShared`` ones
+    #: off a base of a lower min_info_gain) and what was grown for it
+    #: (``bases`` = distinct min_instances values, ``pairs`` = base x fold
+    #: forests and each refit's one,
     #: ``treesGrown``, ``launches`` of ``chunk`` trees at histogram width
     #: ``msub`` and ``levels`` heap levels)
     rf_grid: Dict[str, int] = field(default_factory=dict)
