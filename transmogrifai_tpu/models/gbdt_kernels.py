@@ -156,9 +156,12 @@ def _sorted_column_blocks(X: np.ndarray, sample_rows: int, seed: int):
     if n > sample_rows:
         idx = np.random.default_rng(seed).choice(n, sample_rows, replace=False)
         idx.sort()
+    from ..utils.profiling import count_fresh
+
     wide = np.empty((min(SKETCH_BLOCK_COLS, d), n), X.dtype)
     sample = wide if idx is None else np.empty((len(wide), sample_rows),
                                                X.dtype)
+    count_fresh("tree.sketch.buf", wide.nbytes)
     for j0 in range(0, d, SKETCH_BLOCK_COLS):
         cols = X[:, j0:j0 + SKETCH_BLOCK_COLS].T
         all_rows, block = wide[:len(cols)], sample[:len(cols)]
@@ -1517,8 +1520,12 @@ def rf_bags_and_features(seed: int, n_trees: int, n: int, d: int, msub: int,
     gen = jax.jit(jax.vmap(
         lambda tid: _rf_bag_and_features(tid, jnp.int32(seed), n, d, msub,
                                          jnp.float32(subsample_rate))))
+    from ..utils.profiling import count_fresh
+
     BW, idx = gen(jnp.arange(n_trees))
-    return np.asarray(BW), np.asarray(idx)
+    BW = np.asarray(BW)
+    count_fresh("tree.bags", BW.nbytes)
+    return BW, np.asarray(idx)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "msub", "max_depth",

@@ -156,8 +156,9 @@ import contextlib
 import threading
 from collections import OrderedDict
 
+from ..obs.trace import phases as _phases
 from ..obs.trace import span as _span
-from ..utils.profiling import count_memo, launch
+from ..utils.profiling import count_fresh, count_hash, count_memo, launch
 
 _BIN_CACHE: "OrderedDict" = OrderedDict()
 _BIN_CACHE_CAPACITY = 32
@@ -286,6 +287,7 @@ def _full_hash(a: np.ndarray) -> str:
         import zlib
         mv = memoryview(np.ascontiguousarray(a)).cast("B")
         return f"crc{zlib.crc32(mv):08x}a{zlib.adler32(mv):08x}n{len(mv)}"
+    count_fresh("tree.hash.copy", a.nbytes)    # tobytes: a copy to hash
     return hashlib.md5(a.tobytes()).hexdigest()
 
 
@@ -311,6 +313,7 @@ def _content_hash(a: np.ndarray) -> str:
     k = id(a)
     h = _HASH_BY_ID.get(k)
     if h is None:
+        count_hash(a.nbytes)
         with _span("tree.prep.hash", cat="prep", bytes=a.nbytes):
             h = _full_hash(a)
         _HASH_BY_ID[k] = h
@@ -349,6 +352,8 @@ def _as_f32(X) -> np.ndarray:
     per probe measured ~17 s of a 200k-row sweep.  A sampled digest guards
     the cache against in-place mutation of the source."""
     Xf = np.asarray(X, np.float32)
+    if Xf is not X:
+        count_fresh("tree.f32", Xf.nbytes)     # another dtype: converted
     if Xf.flags.c_contiguous:
         return Xf
     k = id(X)
@@ -358,6 +363,7 @@ def _as_f32(X) -> np.ndarray:
         return hit[1]
     with _span("tree.prep.contiguous", cat="prep", bytes=Xf.nbytes):
         Xc = np.ascontiguousarray(Xf)
+    count_fresh("tree.contiguous", Xc.nbytes)
     _CONTIG_BY_ID[k] = (digest, Xc)
     try:
         import weakref
@@ -365,6 +371,20 @@ def _as_f32(X) -> np.ndarray:
     except TypeError:  # pragma: no cover - non-weakrefable input
         _CONTIG_BY_ID.pop(k, None)
     return Xc
+
+
+def _host_copy(x, site: str) -> np.ndarray:
+    """``np.asarray(x)``, booked under ``site`` as a fresh host array
+    (``count_fresh``) where it makes one: a ``jax.Array`` keeps the host
+    copy of its first fetch, so a later ``np.asarray`` of it hands that one
+    out again.  (A CPU backend's fetch is a view and keeps none: it is
+    booked every time.)"""
+    fresh = (not isinstance(x, np.ndarray)
+             and getattr(x, "_npy_value", None) is None)
+    host = np.asarray(x)
+    if fresh:
+        count_fresh(site, host.nbytes)
+    return host
 
 
 def _upload_timed(a):
@@ -383,7 +403,11 @@ def _dev_memo(arr, tag: str = "up"):
     """Upload a host array once per distinct content."""
     a = np.asarray(arr)
     if not a.flags.c_contiguous:
-        a = _as_f32(arr) if a.dtype == np.float32 else np.ascontiguousarray(a)
+        if a.dtype == np.float32:
+            a = _as_f32(arr)
+        else:
+            a = np.ascontiguousarray(a)
+            count_fresh("tree.contiguous", a.nbytes)
     key = (tag, _content_hash(a), a.shape, str(a.dtype))
     return _memo(key, lambda: _upload_timed(a))
 
@@ -427,7 +451,9 @@ def _dev_f32(X, tag: str = "X_f32"):
 
         def build():
             import ml_dtypes
-            return _upload_timed(Xf.astype(ml_dtypes.bfloat16))
+            Xb = Xf.astype(ml_dtypes.bfloat16)
+            count_fresh("tree.upload.bf16", Xb.nbytes)
+            return _upload_timed(Xb)
         return _memo(key, build)
     return _dev_memo(Xf, tag)
 
@@ -437,7 +463,10 @@ def _dev_memo_sharded(arr, sharding, tag: str = "up"):
     probes with the same fold matrices for every grid candidate."""
     import jax
 
-    a = np.ascontiguousarray(np.asarray(arr))
+    given = np.asarray(arr)
+    a = np.ascontiguousarray(given)
+    if a is not given:
+        count_fresh("tree.contiguous", a.nbytes)   # a strided one: copied
     key = (tag, _content_hash(a), a.shape, str(a.dtype), str(sharding))
 
     def build():
@@ -446,16 +475,28 @@ def _dev_memo_sharded(arr, sharding, tag: str = "up"):
     return _memo(key, build)
 
 
+def _binned_host_padded(binned, ndata: int) -> np.ndarray:
+    """The device's binned matrix fetched back to the host, row-padded where
+    its rows do not tile a data axis of ``ndata`` shards; fetch and padded
+    copy are booked under ``tree.pad`` where they are new arrays."""
+    from ..parallel.mesh import pad_to_multiple
+
+    host, n_pad = pad_to_multiple(_host_copy(binned, "tree.pad"), ndata,
+                                  axis=0)
+    if n_pad:
+        count_fresh("tree.pad", host.nbytes)
+    return host
+
+
 def _binned_sharded(binned, mesh):
     """The binned matrix row-padded to tile the mesh's data axis and
     committed ``P(data, None)``, with its padded row count: ONE placement
     per (content, mesh) for every grid group of the sweep and the winner's
     mesh refit alike (they hold the same binned matrix since the refit
     joins the sweep's preparation, ``_prep_tree_inputs_mesh``)."""
-    from ..parallel.mesh import pad_to_multiple, sweep_matrix_sharding
+    from ..parallel.mesh import sweep_matrix_sharding
 
-    ndata = mesh.shape[mesh.axis_names[0]]
-    host, _ = pad_to_multiple(np.asarray(binned), ndata, axis=0)
+    host = _binned_host_padded(binned, mesh.shape[mesh.axis_names[0]])
     return (_dev_memo_sharded(host, sweep_matrix_sharding(mesh),
                               "binned_sharded"), host.shape[0])
 
@@ -671,13 +712,14 @@ def _maybe_bundle(hx: str, edges, binned, max_bins: int):
 
     def build():
         with _span("tree.prep.bundle", cat="prep"):
-            host = np.asarray(binned)
+            host = _host_copy(binned, "tree.bundle.host")
             b = bundle_features(host, np.asarray(edges), max_bins,
                                 min_width_ratio=(1.0 if force
                                                  else EFB_MIN_WIDTH_RATIO))
             if b is None:
                 return ()
             bundled = bundle_matrix(b, host)
+            count_fresh("tree.bundle.packed", bundled.nbytes)
         return (b, _upload_timed(bundled), _upload_timed(b.end_bin))
 
     val = _memo(key, build)
@@ -759,6 +801,16 @@ class _RandomForestBase(PredictorEstimator):
         return self.fit_raw(X, y)
 
     def fit_raw(self, X: np.ndarray, y: np.ndarray, w=None):
+        # a traced run splits every tree fit in three: up to the first
+        # growth launch, the launches, the grown arrays' way to the model
+        with _phases("tree.fit.prepare", cat="fit") as ph:
+            return self._fit_phased(X, y, w, ph)
+
+    def _fit_phased(self, X, y, w, ph):
+        """``fit_raw``'s body; ``ph`` holds the ``tree.fit.*`` span that is
+        open (``prepare`` on entry) and moves on to ``grow`` at the first
+        growth launch and to ``fetch`` where the grown arrays become the
+        model."""
         n, d = X.shape
         if self.mesh is not None:
             # the sweep's edges and binned matrix where the memo holds
@@ -784,14 +836,16 @@ class _RandomForestBase(PredictorEstimator):
         msub = _feature_subset_size(self.feature_subset_strategy, d,
                                     self._classification)
         if self.mesh is not None:
-            f, th, lf = self._fit_sharded(binned, Y, base_w, msub)
+            f, th, lf = self._fit_sharded(binned, Y, base_w, msub, ph)
         else:
             # bootstrap bags (Poisson weights) + feature subsets generate ON
             # DEVICE from the seed (grow_forest_rf); the fold data uploads
             # once (memoized), so each candidate fit is a couple of
             # scalar-arg launches — no per-tree weights are uploaded
+            Yj, wj = _dev_memo(Y, "rf_Y"), _dev_memo(base_w, "rf_w")
+            ph.to("tree.fit.grow")
             f, th, lf = grow_forest_rf(
-                binned, _dev_memo(Y, "rf_Y"), _dev_memo(base_w, "rf_w"),
+                binned, Yj, wj,
                 seed=self.seed, n_trees=self.num_trees, msub=msub,
                 subsample_rate=self.subsample_rate,
                 max_depth=self.max_depth, n_bins=self.max_bins, lam=1e-3,
@@ -801,13 +855,14 @@ class _RandomForestBase(PredictorEstimator):
         # ensemble stays device-resident: during model selection only the
         # scores come back to host; the winning ensemble downloads lazily at
         # persistence/native-serving time (TreeEnsembleModel._raw)
+        ph.to("tree.fit.fetch")
         mode = "rf_cls" if self._classification else "rf_reg"
         return TreeEnsembleModel(
             mode=mode, edges=edges, feat=f, thresh=th, leaf=lf,
             n_classes=k if self._classification else 2)
 
 
-    def _fit_sharded(self, binned, Y, base_w, msub: int):
+    def _fit_sharded(self, binned, Y, base_w, msub: int, ph):
         """Multi-chip fit: pad rows to tile the mesh's data axis (padded
         rows carry zero bag weight) and grow with psum'd histograms.
         Bags/feature subsets come from the SAME generator as the
@@ -826,9 +881,11 @@ class _RandomForestBase(PredictorEstimator):
         masks = np.zeros((T, d), bool)
         np.put_along_axis(masks, feat_idx, True, axis=1)
         ndata = self.mesh.shape[self.mesh.axis_names[0]]
-        binned_h, _ = pad_to_multiple(np.asarray(binned), ndata, axis=0)
+        binned_h = _binned_host_padded(binned, ndata)
+        count_fresh("tree.bags", BW.nbytes)          # the weighted bags
         BW, _ = pad_to_multiple(BW, ndata, axis=1)   # zero weight on pad
         Y_h, _ = pad_to_multiple(np.asarray(Y, np.float32), ndata, axis=0)
+        ph.to("tree.fit.grow")
         return grow_forest_sharded(
             binned_h, Y_h, BW, masks, self.mesh,
             max_depth=self.max_depth, n_bins=self.max_bins, lam=1e-3,
@@ -862,7 +919,7 @@ class OpDecisionTreeClassifier(OpRandomForestClassifier):
         # single tree: no bootstrap
         self.subsample_rate = 0.0
 
-    def fit_raw(self, X, y, w=None):
+    def _fit_phased(self, X, y, w, ph):
         # bypass Poisson bagging: weight 1 everywhere
         self_copy = self
         n, d = X.shape
@@ -877,11 +934,14 @@ class OpDecisionTreeClassifier(OpRandomForestClassifier):
             Y = y[:, None].astype(np.float32)
         G = jnp.asarray(Y * base_w[:, None])
         H = jnp.asarray(np.repeat(base_w[:, None], k, axis=1))
+        wj = jnp.asarray(base_w)
+        ph.to("tree.fit.grow")
         f, th, lf = grow_tree(
-            binned, G, H, jnp.asarray(base_w), max_depth=self.max_depth,
+            binned, G, H, wj, max_depth=self.max_depth,
             n_bins=self.max_bins, lam=1e-3, min_info_gain=self.min_info_gain,
             min_instances=float(self.min_instances_per_node),
             newton_leaf=False)
+        ph.to("tree.fit.fetch")
         mode = "rf_cls" if self._classification else "rf_reg"
         return TreeEnsembleModel(
             mode=mode, edges=edges, feat=np.asarray(f)[None],
@@ -993,6 +1053,12 @@ class _GBTBase(PredictorEstimator):
         return self.fit_raw(X, y)
 
     def fit_raw(self, X: np.ndarray, y: np.ndarray, w=None):
+        with _phases("tree.fit.prepare", cat="fit") as ph:
+            return self._fit_phased(X, y, w, ph)
+
+    def _fit_phased(self, X, y, w, ph):
+        """``fit_raw``'s body, under the ``tree.fit.*`` spans as
+        ``_RandomForestBase._fit_phased`` is."""
         n, d = X.shape
         if self.mesh is None:
             # wide mostly-zero matrices sketch their edges over the
@@ -1073,7 +1139,8 @@ class _GBTBase(PredictorEstimator):
                                          integer_weights=bool(
                                              (train_w == np.floor(train_w))
                                              .all()),
-                                         hx=_content_hash(_as_f32(X)))
+                                         hx=_content_hash(_as_f32(X)),
+                                         ph=ph)
 
         feats, threshs, leaves = [], [], []
         best_metric, best_len, stall = -np.inf, 0, 0
@@ -1096,6 +1163,7 @@ class _GBTBase(PredictorEstimator):
         pending: list = []
         lagged: list = []
         stop = False
+        ph.to("tree.fit.grow")
         for it in range(self.max_iter):
             G, H = _grad_hess(obj, F, yj, Yj, twj)
             bw = twj
@@ -1155,6 +1223,7 @@ class _GBTBase(PredictorEstimator):
         if use_es and best_len:
             feats, threshs, leaves = (feats[:best_len], threshs[:best_len],
                                       leaves[:best_len])
+        ph.to("tree.fit.fetch")
         mode = {"binary": "gbdt_binary", "multiclass": "gbdt_multi",
                 "regression": "gbdt_reg"}[obj]
         return TreeEnsembleModel(
@@ -1166,7 +1235,7 @@ class _GBTBase(PredictorEstimator):
     def _fit_scan_chunks(self, binned, edges, yj, twj, obj: str,
                          base: float, use_es: bool, val_idx,
                          integer_weights: bool = True,
-                         hx: Optional[str] = None):
+                         hx: Optional[str] = None, *, ph):
         """Whole-fit scan-chunked boosting: es_chunk rounds per launch via
         ``_gbt_chain_rounds_jit`` with S=1 — the same kernel, patience rule
         and masked trimming as the batched GBT grid group, so the two paths
@@ -1231,6 +1300,7 @@ class _GBTBase(PredictorEstimator):
         stopped = np.zeros(1, bool)
         fb, tb, lb = [], [], []
         n_rounds = 0
+        ph.to("tree.fit.grow")
         for ci in range(-(-self.max_iter // es_chunk)):
             with launch("gbt_rounds"):
                 Fm, fs, ts, lfs, ms = _gbt_chain_rounds_jit(
@@ -1268,6 +1338,7 @@ class _GBTBase(PredictorEstimator):
         else:
             best_len = n_rounds
         best_len = min(best_len, self.max_iter)
+        ph.to("tree.fit.fetch")
         feat = jnp.concatenate(fb)[:best_len, 0]
         thresh = jnp.concatenate(tb)[:best_len, 0]
         leaf = jnp.concatenate(lb)[:best_len, 0]

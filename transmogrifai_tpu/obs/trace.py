@@ -39,8 +39,8 @@ from typing import Any, Dict, List, Optional
 
 __all__ = ["Span", "Tracer", "start_trace", "stop_trace", "install_tracer",
            "current_tracer", "tracing", "span", "current_span",
-           "begin_span", "end_span", "new_trace_id", "set_global_attrs",
-           "global_attrs"]
+           "begin_span", "end_span", "phases", "new_trace_id",
+           "set_global_attrs", "global_attrs"]
 
 #: attrs stamped onto EVERY span this process opens — the pod runtime
 #: sets {"process": process_index} here so the coordinator can merge the
@@ -313,3 +313,30 @@ def span(name: str, cat: str = "run", parent: Optional[Span] = None,
         yield sp
     finally:
         end_span(sp)
+
+
+class phases:
+    """Consecutive spans that tile one long function: the first opens with
+    the object, ``to(name)`` closes the open one and opens the next, and
+    leaving the ``with`` block closes the last, on a ``return`` or an
+    exception too.  For a body too long to indent under one ``with span``
+    a phase (``GBTGridGroup.run``, the tree estimators' ``fit_raw``).
+    Disabled cost: the ``None`` check of ``begin_span`` / ``end_span`` a
+    call, and no ``Span``."""
+
+    __slots__ = ("cat", "_open")
+
+    def __init__(self, first: str, cat: str = "run", **attrs):
+        self.cat = cat
+        self._open = begin_span(first, cat, **attrs)
+
+    def to(self, name: str, **attrs) -> None:
+        end_span(self._open)
+        self._open = begin_span(name, self.cat, **attrs)
+
+    def __enter__(self) -> "phases":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end_span(self._open)
+        self._open = None

@@ -22,6 +22,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs.trace import phases
 from ..stages.base import (
     SequenceEstimator, SequenceModel, SequenceTransformer,
 )
@@ -30,6 +31,7 @@ from ..types.feature_types import (
     Binary, MultiPickList, OPNumeric, OPSet, OPVector, Text, TextList,
 )
 from ..utils.hashing import murmur3_32
+from ..utils.profiling import count_fresh
 from .vector_metadata import (
     NULL_INDICATOR, OTHER_INDICATOR, VectorColumnMetadata, VectorMetadata,
 )
@@ -243,6 +245,10 @@ class RealVectorizer(SequenceEstimator):
         return RealVectorizerModel(fills=fills, track_nulls=self.track_nulls)
 
 
+#: bytes of ``RealVectorizerModel``'s transposed group buffer
+_GROUP_BUF_BYTES = 128 << 20
+
+
 class RealVectorizerModel(SequenceModel):
     input_types = (OPNumeric,)
 
@@ -263,17 +269,28 @@ class RealVectorizerModel(SequenceModel):
         # GROUP floats per row) are ~10x faster, and the buffer bounds the
         # extra peak memory to ~128 MB instead of a full second matrix.
         out = np.empty((n, width), dtype=np.float32)
-        group = int(np.clip((128 << 20) // max(n * 4, 1), 1, width))
+        group = int(np.clip(_GROUP_BUF_BYTES // max(n * 4, 1), 1, width))
         buf = np.empty((group, n), dtype=np.float32)
+        count_fresh("vectorize.out", out.nbytes)
+        count_fresh("vectorize.buf", buf.nbytes)
         meta = []
         j = 0
         flushed = 0
+        # a traced run splits the transform by column group g: the columns'
+        # own work up to the group's flush (``vectorize.fill[g]``), then the
+        # transposed write, where the result is first touched
+        # (``vectorize.flush[g]``)
+        ph = phases("vectorize.fill[0]", cat="vectorize")
 
         def flush(upto):
             nonlocal flushed
             if upto > flushed:
+                g = flushed // group
+                ph.to(f"vectorize.flush[{g}]", cols=upto - flushed)
                 out[:, flushed:upto] = buf[: upto - flushed].T
                 flushed = upto
+                if upto < width:
+                    ph.to(f"vectorize.fill[{g + 1}]")
 
         def put(row_vals):
             nonlocal j
@@ -282,20 +299,24 @@ class RealVectorizerModel(SequenceModel):
             np.copyto(buf[j - flushed], row_vals)
             j += 1
 
-        for f, fill, c in zip(self.input_features, self.fills, cols):
-            vals = np.asarray(c.values, dtype=np.float32)
-            m = np.asarray(c.mask)
-            row = np.where(m, vals, np.float32(fill))
-            # clamp non-finite survivors (producers that don't fold isfinite
-            # into the mask, or float32-cast overflow): NaN -> 0, inf -> max
-            np.nan_to_num(row, copy=False)
-            put(row)
-            meta.append(VectorColumnMetadata(f.name, f.ftype.type_name()))
-            if self.track_nulls:
-                put(~m)
-                meta.append(VectorColumnMetadata(
-                    f.name, f.ftype.type_name(), indicator_value=NULL_INDICATOR))
-        flush(j)
+        with ph:
+            for f, fill, c in zip(self.input_features, self.fills, cols):
+                vals = np.asarray(c.values, dtype=np.float32)
+                m = np.asarray(c.mask)
+                row = np.where(m, vals, np.float32(fill))
+                # clamp non-finite survivors (producers that don't fold
+                # isfinite into the mask, or float32-cast overflow):
+                # NaN -> 0, inf -> max
+                np.nan_to_num(row, copy=False)
+                put(row)
+                meta.append(VectorColumnMetadata(f.name,
+                                                 f.ftype.type_name()))
+                if self.track_nulls:
+                    put(~m)
+                    meta.append(VectorColumnMetadata(
+                        f.name, f.ftype.type_name(),
+                        indicator_value=NULL_INDICATOR))
+            flush(j)
         return _vec_column(out, VectorMetadata(self.get_output().name if self._output_feature else "real_vec", meta))
 
 

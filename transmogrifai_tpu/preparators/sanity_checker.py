@@ -27,6 +27,7 @@ from ..ops.vector_metadata import VectorMetadata
 from ..stages.base import BinaryEstimator, BinaryModel
 from ..types.columns import ColumnarDataset, FeatureColumn
 from ..types.feature_types import OPNumeric, OPVector
+from ..utils.profiling import count_fresh
 
 __all__ = ["SanityChecker", "SanityCheckerModel", "SanityCheckerSummary",
            "MinVarianceFilter"]
@@ -97,7 +98,9 @@ def _select_columns(X, keep: np.ndarray) -> np.ndarray:
             and keep.size == X.shape[1]
             and np.array_equal(keep, np.arange(keep.size))):
         return X
-    return np.take(X, keep, axis=1).astype(np.float32, copy=False)
+    out = np.take(X, keep, axis=1).astype(np.float32, copy=False)
+    count_fresh("sanity.filter", out.nbytes)
+    return out
 
 
 class SanityChecker(BinaryEstimator):

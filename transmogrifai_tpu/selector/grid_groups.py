@@ -795,6 +795,17 @@ class GBTGridGroup(TreeGridGroup):
         return [self.proto.copy(**p) for p in self.grid_points]
 
     def run(self, X, y, weight_ctxs):
+        from ..obs.trace import phases
+
+        # a traced run splits the group's host time in four: up to the
+        # first chain launch, the rounds' launches, the fold scoring's, the
+        # metric grid with the fetch of its rows
+        with phases("gbt.grid.prepare", cat="sweep") as ph:
+            return self._run_phased(X, y, weight_ctxs, ph)
+
+    def _run_phased(self, X, y, weight_ctxs, ph):
+        """``run``'s body; ``ph`` holds the ``gbt.grid.*`` span that is
+        open (``prepare`` on entry)."""
         import time as _time
 
         import jax
@@ -1001,6 +1012,7 @@ class GBTGridGroup(TreeGridGroup):
                       else jnp.zeros(1, jnp.float32))
         feats_b, threshs_b, leaves_b = [], [], []
         n_rounds = 0
+        ph.to("gbt.grid.rounds")
         for ci in range(-(-e0.max_iter // es_chunk)):
             if self.mesh is not None:
                 with launch("gbt_chain_rounds_sharded"):
@@ -1072,6 +1084,7 @@ class GBTGridGroup(TreeGridGroup):
         else:
             best_len[best_len == 0] = min(n_rounds, e0.max_iter)
 
+        ph.to("gbt.grid.score")
         # final per-chain scores over ALL rows: ONE (rounds, chains) restack
         # + per-chain masked-leaf predicts.  Trimming by zeroing the leaves
         # of rounds >= best_len keeps every chain on the SAME (R, nodes)
@@ -1118,6 +1131,7 @@ class GBTGridGroup(TreeGridGroup):
         scores = jnp.stack(scores).reshape(C, F, n).transpose(1, 0, 2)
         self._record_grid_observation(_time.perf_counter() - t0, n,
                                       int(X.shape[1]))
+        ph.to("gbt.grid.metrics", rows=C * F)
         # release the per-round tree stacks, margins and masked leaves
         # before the metric grid runs (see RFGridGroup.run note); the last
         # chunk's loop locals pin device buffers too

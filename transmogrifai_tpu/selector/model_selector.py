@@ -653,13 +653,14 @@ class ModelSelector(PredictorEstimator):
         mesh sweep will consume it; single-chip fits pull it to host."""
         import jax
 
-        from ..models.trees import _as_f32, _dev_f32
+        from ..models.trees import _as_f32, _dev_f32, _host_copy
 
         if isinstance(values, jax.Array) and not isinstance(values,
                                                             np.ndarray):
             if self.mesh is not None:
                 return values             # committed row-sharded already
-            values = np.asarray(values)
+            # (a copy ``_as_f32`` makes of it is booked there)
+            values = _host_copy(values, "selector.matrix")
         X = _as_f32(np.asarray(values))
         if self.mesh is None and self._grid_has_linear() and X.size > (1 << 24):
             _dev_f32(X)
@@ -782,6 +783,7 @@ class ModelSelector(PredictorEstimator):
     def _fit_columns_inner(self, X, y, n, splitter, train_mask,
                            holdout_idx, base_w):
         from ..obs.trace import span as _span
+        from ..utils.profiling import count_fresh
 
         # a mesh-padded device matrix (the streaming→sharded ingest
         # hand-off) carries pad rows: labels/weights pad with ZEROS so the
@@ -893,6 +895,10 @@ class ModelSelector(PredictorEstimator):
         # a fresh holdout matrix per metric set
         with _span("selector.predict", cat="selector"):
             full_batch = best_model.predict_batch(X)
+            for a in (full_batch.prediction, full_batch.raw_prediction,
+                      full_batch.probability):
+                if a is not None:
+                    count_fresh("selector.predict", a.nbytes)
         with _span("selector.metrics", cat="selector"):
             train_metrics = self._full_metrics(full_batch, y, train_mask)
             holdout_metrics = (

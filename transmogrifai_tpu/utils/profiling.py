@@ -26,7 +26,8 @@ __all__ = ["OpStep", "MetricsCollector", "AppMetrics", "StepMetrics",
            "with_job_group", "current_collector", "install_collector",
            "profile_to", "RunCounters", "COUNTERS", "reset_counters",
            "count_upload", "count_fetch", "count_drain", "count_launch",
-           "launch", "count_memo", "count_rf_grid",
+           "launch", "count_memo", "count_rf_grid", "mark_run_start",
+           "count_fresh", "count_hash", "FRESH_MIN_BYTES",
            "fetch_timed", "StageProfile", "PlanProfiler",
            "IngestPass", "IngestProfiler", "LintSnapshot", "backend_name",
            "mesh_desc"]
@@ -218,6 +219,23 @@ class RunCounters:
     #: ``treesGrown``, ``launches`` of ``chunk`` trees at histogram width
     #: ``msub`` and ``levels`` heap levels)
     rf_grid: Dict[str, int] = field(default_factory=dict)
+    #: ``perf_counter()`` of the run's start: ``OpWorkflow.train`` stamps
+    #: it on entry (``mark_run_start``), else it is the moment these
+    #: counters were made
+    origin: float = field(default_factory=time.perf_counter)
+    #: seconds from ``origin`` to the FIRST ``count_launch`` of each tag
+    #: since the reset: how long the device waited for its first program
+    #: of that kind, on any machine, traced or not
+    first_launch_s: Dict[str, float] = field(default_factory=dict)
+    #: bytes of host arrays of ``FRESH_MIN_BYTES`` or more that the
+    #: program made anew, by site (``count_fresh``): memory the allocator
+    #: maps fresh from the system, so whoever writes it first pays a page
+    #: fault a page
+    host_fresh: Dict[str, int] = field(default_factory=dict)
+    #: full-content hashes of big arrays (``trees._content_hash`` beside
+    #: its ``tree.prep.hash`` span): bytes given to ``_full_hash``, calls
+    hash_bytes: int = 0
+    hashes: int = 0
     #: elastic-sweep accounting (parallel/elastic.py mirrors its per-sweep
     #: ElasticCounters here): retries / mesh_shrinks / mesh_repacks /
     #: quarantined / watchdog_fires / device_losses
@@ -243,6 +261,11 @@ class RunCounters:
             "launchTags": dict(self.launch_tags),
             "memoTags": {k: dict(v) for k, v in self.memo_tags.items()},
             "rfGrid": dict(self.rf_grid),
+            "firstLaunchSecs": {k: round(v, 6)
+                                for k, v in self.first_launch_s.items()},
+            "hostFresh": dict(self.host_fresh),
+            "hashBytes": self.hash_bytes,
+            "hashes": self.hashes,
             "elastic": dict(self.elastic),
             "refresh": dict(self.refresh),
         }
@@ -301,9 +324,18 @@ def count_drain(seconds: float, tag: Optional[str] = None,
                 COUNTERS.drain_tags.get(key, 0.0) + seconds)
 
 
+def mark_run_start() -> None:
+    """Stamp the run's origin: ``firstLaunchSecs`` counts from here."""
+    with _COUNTERS_LOCK:
+        COUNTERS.origin = time.perf_counter()
+
+
 def count_launch(tag: str, n: int = 1) -> None:
     with _COUNTERS_LOCK:
         COUNTERS.launches += n
+        if tag not in COUNTERS.launch_tags:
+            COUNTERS.first_launch_s[tag] = (time.perf_counter()
+                                            - COUNTERS.origin)
         COUNTERS.launch_tags[tag] = COUNTERS.launch_tags.get(tag, 0) + n
 
 
@@ -345,6 +377,32 @@ def count_rf_grid(**counts: int) -> None:
                 tags[key] = max(tags.get(key, 0), int(n))
             else:
                 tags[key] = tags.get(key, 0) + int(n)
+
+
+#: the least size ``count_fresh`` books: glibc serves a request of 32 MiB
+#: or more by a mapping of its own whatever its heap holds (the ceiling of
+#: its dynamic mmap threshold), and gives it back on free, so every such
+#: array is first touched anew
+FRESH_MIN_BYTES = 32 << 20
+
+
+def count_fresh(site: str, nbytes: int) -> None:
+    """A host array of ``nbytes`` that the program made anew at ``site`` (a
+    result, a buffer of the call, a fetch from the device): booked in
+    ``RunCounters.host_fresh`` from ``FRESH_MIN_BYTES`` up.  A smaller one
+    costs the comparison alone, so a serving-size call pays nothing."""
+    if nbytes < FRESH_MIN_BYTES:
+        return
+    with _COUNTERS_LOCK:
+        COUNTERS.host_fresh[site] = (COUNTERS.host_fresh.get(site, 0)
+                                     + int(nbytes))
+
+
+def count_hash(nbytes: int) -> None:
+    """One full-content hash of a big array (``trees._content_hash``)."""
+    with _COUNTERS_LOCK:
+        COUNTERS.hash_bytes += int(nbytes)
+        COUNTERS.hashes += 1
 
 
 def count_elastic(kind: str, n: int = 1) -> None:

@@ -239,7 +239,8 @@ class OpWorkflow(_WorkflowCore):
         redirects or disables) — the learned cost model's training data.
         """
         from ..obs.trace import begin_span, end_span
-        from ..utils.profiling import OpStep, with_job_group
+        from ..utils.profiling import (OpStep, mark_run_start,
+                                       with_job_group)
 
         retain_mb = None
         if (tuner is not None and getattr(tuner, "auto_plan", False)
@@ -257,6 +258,7 @@ class OpWorkflow(_WorkflowCore):
                 "pod trains run out-of-core only — pass chunk_rows=k "
                 "(the pod protocol is built on host-sharded chunk "
                 "streams and mergeable fit states; docs/distributed.md)")
+        mark_run_start()    # RunCounters.first_launch_s counts from here
         root = begin_span("workflow.train", cat="workflow",
                           chunked=chunk_rows is not None,
                           chunk_rows=chunk_rows)
