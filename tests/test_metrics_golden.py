@@ -98,3 +98,101 @@ class TestRegressionMulticlassGolden:
         # per-class precision: c0 1/2, c1 2/3, c2 1/1
         assert m["Precision"] == pytest.approx(
             (0.5 * 2 + 2 / 3 * 2 + 1.0 * 2) / 6)
+
+
+# -- the batched metric grids --------------------------------------------------
+
+def _grid_inputs(kind, folds=3, cands=4, n=300):
+    """(F, C, N) scores with ties, (F, N) weights with zeros, (F, N) labels
+    that differ from fold to fold."""
+    rng = np.random.default_rng(9)
+    if kind == "multiclass":
+        y = rng.integers(0, 3, (folds, n)).astype(np.float32)
+        s = rng.integers(0, 3, (folds, cands, n)).astype(np.float32)
+    elif kind == "binary":
+        y = (rng.random((folds, n)) < 0.3).astype(np.float32)
+        s = np.round(rng.random((folds, cands, n)), 2).astype(np.float32)
+    else:
+        y = rng.normal(size=(folds, n)).astype(np.float32)
+        s = (y[:, None, :] + rng.normal(size=(folds, cands, n))
+             ).astype(np.float32)
+    w = rng.choice(np.array([0.0, 0.5, 1.0, 2.0], np.float32), (folds, n))
+    return y, s, w
+
+
+def _grid(kind, metric):
+    import functools
+
+    from transmogrifai_tpu.evaluators import metrics as M
+
+    if kind == "multiclass":
+        return functools.partial(
+            lambda y, s, w, metric: M.multiclass_metric_grid(y, s, w, 3,
+                                                             metric),
+            metric=metric)
+    fn = (M.binary_metric_grid if kind == "binary"
+          else M.regression_metric_grid)
+    return functools.partial(fn, metric=metric)
+
+
+GRID_METRICS = [("binary", "AuPR"), ("binary", "AuROC"),
+                ("regression", "RootMeanSquaredError"),
+                ("regression", "R2"), ("multiclass", "F1"),
+                ("multiclass", "Error")]
+
+
+@pytest.mark.parametrize("kind, metric", GRID_METRICS,
+                         ids=[f"{k}-{m}" for k, m in GRID_METRICS])
+class TestMetricGridLabels:
+    def test_a_label_row_a_fold_is_that_fold_s_own_call(self, kind, metric):
+        """(F, N) labels: each fold is ranked against its own row, exactly
+        as a call with that row as the shared label vector ranks it."""
+        y, s, w = _grid_inputs(kind)
+        grid = _grid(kind, metric)
+        got = np.asarray(grid(y, s, w))
+        assert got.shape == s.shape[:2]
+        for f in range(len(y)):
+            want = np.asarray(grid(y[f], s[f:f + 1], w[f:f + 1]))
+            assert np.array_equal(got[f:f + 1], want)
+        # the folds' labels matter: fold 0's row for all is another result
+        assert not np.array_equal(got, np.asarray(grid(y[0], s, w)))
+
+    def test_one_shared_label_vector_is_the_kernel_s_value_a_cell(
+            self, kind, metric):
+        """(N,) labels, the linear and boosted groups' call shape: (F, C,
+        N) scores and (F, N) weights give each cell what the metric's
+        kernel gives for that score row under that fold's weights."""
+        import jax.numpy as jnp
+
+        from transmogrifai_tpu.evaluators import metrics as M
+
+        y, s, w = _grid_inputs(kind)
+        y = y[0]
+        got = np.asarray(_grid(kind, metric)(y, jnp.asarray(s),
+                                             jnp.asarray(w)))
+        assert got.shape == s.shape[:2] and np.isfinite(got).all()
+        for f in range(s.shape[0]):
+            for c in range(s.shape[1]):
+                if kind == "binary":
+                    kernel = {"AuPR": M._aupr_dev, "AuROC": M._auroc_dev}
+                    want = kernel[metric](y, s[f, c], w[f])
+                elif kind == "regression":
+                    want = M._regression_metric_dev(
+                        jnp.asarray(y), jnp.asarray(s[f, c]),
+                        jnp.asarray(w[f]), metric)
+                else:
+                    want = M._multiclass_metric_dev(
+                        jnp.asarray(y, jnp.int32),
+                        jnp.asarray(s[f, c], jnp.int32), jnp.asarray(w[f]),
+                        3, metric)
+                assert got[f, c] == pytest.approx(float(want), abs=1e-6)
+
+
+def test_metric_grids_without_a_device_kernel_decline_either_label_shape():
+    from transmogrifai_tpu.evaluators import metrics as M
+
+    y, s, w = _grid_inputs("binary")
+    for labels in (y, y[0]):
+        assert M.binary_metric_grid(labels, s, w, "F1") is None
+        assert M.regression_metric_grid(labels, s, w, "AuPR") is None
+        assert M.multiclass_metric_grid(labels, s, w, 3, "AuPR") is None

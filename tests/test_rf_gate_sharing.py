@@ -252,7 +252,7 @@ def two_gates():
 
 def test_two_gate_grid_counts_one_base(two_gates):
     got = dict(two_gates["counters"])
-    for shape in ("chunk", "launches", "msub", "levels"):
+    for shape in ("chunk", "launches", "msub", "levels", "scoredRows"):
         got.pop(shape)
     # one base at (depth 3, gate 0.001) x folds, and a pair a refit
     assert got == {"candidates": 4, "bases": 1, "pairs": FOLDS + 3,
@@ -304,8 +304,10 @@ def test_one_gate_grid_shares_by_depth_alone_and_grows_the_same_trees():
         6, 2, 4, 0, 2 * FOLDS * TREES)
     assert [p[0] for p in run["parts"]] == [3, 1, 2]
     for depth, feats, threshs, leaves in run["parts"]:
-        cps = [(c, f) for c, p in enumerate(run["points"])
-               for f in range(FOLDS) if p["max_depth"] == depth]
+        # fold-major: a fold's pairs are scored together
+        cps = [(c, f) for f in range(FOLDS)
+               for c, p in enumerate(run["points"])
+               if p["max_depth"] == depth]
         assert len(cps) == len(feats)
         for i, (c, f) in enumerate(cps):
             want = _reference_forest(run, run["points"][c], run["W"][f])

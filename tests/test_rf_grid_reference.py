@@ -88,12 +88,13 @@ def grown():
     counters = profiling.COUNTERS.to_json()
 
     # candidate-pair cp = c * FOLDS + f; the full-depth part comes first,
-    # then one part a shallower depth, each in cp order
+    # then one part a shallower depth, each fold-major (a fold's pairs are
+    # scored together on that fold's validation rows)
     trees = [[None] * FOLDS for _ in POINTS]
     assert [p[0] for p in parts] == [BASE_DEPTH, 1, 2]
     for depth, feats, threshs, leaves in parts:
-        cps = [c * FOLDS + f for c, p in enumerate(POINTS)
-               for f in range(FOLDS) if p["max_depth"] == depth]
+        cps = [c * FOLDS + f for f in range(FOLDS)
+               for c, p in enumerate(POINTS) if p["max_depth"] == depth]
         assert len(cps) == len(feats)
         for i, cp in enumerate(cps):
             trees[cp // FOLDS][cp % FOLDS] = (feats[i], threshs[i],
@@ -177,7 +178,9 @@ def test_rf_grid_counters_say_what_was_asked_for_and_what_was_grown(grown):
     assert got == {"candidates": 18, "bases": 2, "pairs": 2 * FOLDS + 2,
                    "truncated": 12, "gateShared": 12,
                    "treesGrown": sweep + 2 * TREES,
-                   "msub": grown["msub"], "levels": BASE_DEPTH}
+                   "msub": grown["msub"], "levels": BASE_DEPTH,
+                   # the longest fold's validation rows, rounded up
+                   "scoredRows": 1024}
     # the chunker's own count: the sweep's launches and one a refit
     assert 1 <= chunk <= sweep
     assert launches == -(-sweep // chunk) + 2
@@ -195,10 +198,10 @@ def test_rf_grid_counts_add_up_shapes_keep_the_largest_and_both_reset():
     from transmogrifai_tpu.utils import profiling
 
     profiling.reset_counters()
-    profiling.count_rf_grid(treesGrown=3, chunk=5)
-    profiling.count_rf_grid(treesGrown=4, chunk=2)
-    assert profiling.COUNTERS.to_json()["rfGrid"] == {"treesGrown": 7,
-                                                      "chunk": 5}
+    profiling.count_rf_grid(treesGrown=3, chunk=5, scoredRows=2048)
+    profiling.count_rf_grid(treesGrown=4, chunk=2, scoredRows=1024)
+    assert profiling.COUNTERS.to_json()["rfGrid"] == {
+        "treesGrown": 7, "chunk": 5, "scoredRows": 2048}
     assert profiling.reset_counters().to_json()["rfGrid"] == {}
 
 

@@ -152,17 +152,29 @@ def _aupr_dev(y_true, y_score, sample_weight=None) -> jnp.ndarray:
         return jnp.clip(jnp.sum(dr * precision), 0.0, 1.0)
 
 
+def _grid_by_fold(fn, y, scores, weights):
+    """``fn(labels, scores, weights)`` over a grid whose folds each bring
+    their own rows: (F, L) labels mapped over the fold axis together with
+    the (F, C, L) scores and the (F, L) weights -> (F, C)."""
+    return jax.vmap(lambda y_f, s_f, w_f:
+                    jax.vmap(lambda s: fn(y_f, s, w_f))(s_f))(
+                        y, scores, weights)
+
+
 def binary_metric_grid(y_true, scores, weights, metric: str):
     """Batched device metric for a validation sweep: ``scores`` (F, C, N)
     per-(fold, candidate) score rows and ``weights`` (F, N) per-fold eval
     weights (broadcast over candidates — never replicated) against one
-    shared label vector -> (F, C) device metric values, or None when
-    ``metric`` has no device kernel (callers fall back to per-candidate
-    host metrics)."""
+    shared label vector, or against (F, N) labels, a row a fold (each
+    fold's own rows: ``grid_groups._fold_eval_rows``) -> (F, C) device
+    metric values, or None when ``metric`` has no device kernel (callers
+    fall back to per-candidate host metrics)."""
     fn = {"AuPR": _aupr_dev, "AuROC": _auroc_dev}.get(metric)
     if fn is None:
         return None
     y = jnp.asarray(y_true, jnp.float32)
+    if y.ndim == 2:
+        return _grid_by_fold(fn, y, scores, weights)
     return jax.vmap(lambda s_f, w_f:
                     jax.vmap(lambda s: fn(y, s, w_f))(s_f))(scores, weights)
 
@@ -186,11 +198,16 @@ def _regression_metric_dev(y, p, w, metric: str):
 
 def regression_metric_grid(y_true, preds, weights, metric: str):
     """Batched device regression metric: (F, C, N) predictions + (F, N)
-    weights -> (F, C) device values; None when unsupported."""
+    weights against (N,) labels, or (F, N) a row a fold -> (F, C) device
+    values; None when unsupported."""
     if metric not in ("RootMeanSquaredError", "MeanSquaredError",
                      "MeanAbsoluteError", "R2"):
         return None
     y = jnp.asarray(y_true, jnp.float32)
+    if y.ndim == 2:
+        return _grid_by_fold(
+            lambda y_f, p, w_f: _regression_metric_dev(y_f, p, w_f, metric),
+            y, preds, weights)
     return jax.vmap(lambda p_f, w_f: jax.vmap(
         lambda p: _regression_metric_dev(y, p, w_f, metric))(p_f))(
             preds, weights)
@@ -231,11 +248,17 @@ def _multiclass_metric_dev(y, p, w, n_classes: int, metric: str):
 def multiclass_metric_grid(y_true, preds, weights, n_classes: int,
                            metric: str):
     """Batched device multiclass metric: (F, C, N) predicted labels (float
-    or int) + (F, N) eval weights against one shared label vector ->
-    (F, C) device values; None when ``metric`` has no device kernel."""
+    or int) + (F, N) eval weights against one shared label vector, or
+    (F, N) labels a row a fold -> (F, C) device values; None when
+    ``metric`` has no device kernel."""
     if metric not in _MULTI_GRID_METRICS:
         return None
     y = jnp.asarray(y_true, jnp.int32)
+    if y.ndim == 2:
+        return _grid_by_fold(
+            lambda y_f, p, w_f: _multiclass_metric_dev(
+                y_f, jnp.asarray(p, jnp.int32), w_f, n_classes, metric),
+            y, preds, weights)
     return jax.vmap(lambda p_f, w_f: jax.vmap(
         lambda p: _multiclass_metric_dev(
             y, jnp.asarray(p, jnp.int32), w_f, n_classes, metric))(p_f))(

@@ -410,7 +410,9 @@ class RFGridGroup(TreeGridGroup):
     identical randomness to the sequential per-candidate fits.  Candidates
     that differ only in max_depth and min_info_gain share ONE grown base
     forest a fold (the deepest, under the lowest gate) and are read off it
-    by truncation and pruning (``gbdt_kernels.prune_rf_grid``).  Covers
+    by truncation and pruning (``gbdt_kernels.prune_rf_grid``).  A pair is
+    scored and ranked on its own fold's validation rows, compacted once a
+    sweep (``_fold_eval_rows``), not on every row of the table.  Covers
     binary, multiclass (one-hot targets, argmax scores against the
     multiclass metric grid) and regression sweeps.  On a sweep mesh the
     same pair stream runs sharded (``grow_rf_grid_sharded``) with
@@ -446,7 +448,7 @@ class RFGridGroup(TreeGridGroup):
                                           regression_metric_grid)
         from ..models.gbdt_kernels import grow_rf_grid, prune_rf_grid
         from ..models.trees import (_dev_memo, _feature_subset_size,
-                                    _score_ensemble_jit)
+                                    _fold_rows_jit)
         from ..obs.trace import span as _span
         from ..utils.profiling import count_rf_grid
 
@@ -487,6 +489,7 @@ class RFGridGroup(TreeGridGroup):
         F = W_tr.shape[0]
         C = len(self.grid_points)
         T = int(self._param(self.grid_points[0], "num_trees"))
+        ev_rows, W_ev_c = _fold_eval_rows(W_ev)
 
         # Depth and gate sharing: candidates that differ ONLY in max_depth
         # and min_info_gain share bags/folds by construction (bags key on
@@ -598,25 +601,39 @@ class RFGridGroup(TreeGridGroup):
         part_depths = [(np.where(cp_full)[0], heap_depth)] + [
             (np.where(~cp_full & (cp_depth == dt))[0], dt)
             for dt in sorted(set(cp_depth[~cp_full].tolist()))]
+        # a pair is ranked under its fold's eval weights alone, so it is
+        # scored on the rows those weigh and on no other: one compacted
+        # matrix a fold, all of one length (gathered here, behind the
+        # growth's launches, not held while they are made)
+        if ev_rows is None:
+            scored, y_ev = [binned], y
+        else:
+            scored = [_fold_rows_jit(binned, r) for r in ev_rows]
+            y_ev = y[ev_rows]
+        count_rf_grid(scoredRows=W_ev_c.shape[1])
         for idx, depth in part_depths:
             if not len(idx):
                 continue
+            # fold-major: a fold's pairs stand together in the part
+            idx = idx[np.argsort(idx % F, kind="stable")]
             with _span(f"rf.grid.score:d{depth}", cat="sweep",
                        pairs=len(idx)):
                 parts.append(_score_pairs_jit(
-                    binned, *part_trees(idx, depth), depth, mode, ptype))
+                    scored, *part_trees(idx, depth), depth, mode, ptype))
             order.extend(idx.tolist())
         scores = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
         if order != list(range(C * F)):
             inv = np.empty(C * F, np.int32)
             inv[np.asarray(order, np.int32)] = np.arange(C * F, dtype=np.int32)
             scores = scores[jnp.asarray(inv)]
-        scores = scores.reshape(C, F, n).transpose(1, 0, 2)  # (F, C, N)
-        # release the grown forests and per-part score buffers before the
-        # metric grid dispatches: at 1M-row sweeps the groups run back to
-        # back and holding every phase's device intermediates to the end
-        # of the sweep needlessly raises cumulative HBM pressure
-        del grown, feats, threshs, leaves, parts
+        # (F, C, L): L the folds' compacted length, or every row
+        scores = scores.reshape(C, F, -1).transpose(1, 0, 2)
+        # release the grown forests, the folds' matrices and per-part score
+        # buffers before the metric grid dispatches: at 1M-row sweeps the
+        # groups run back to back and holding every phase's device
+        # intermediates to the end of the sweep needlessly raises
+        # cumulative HBM pressure
+        del grown, feats, threshs, leaves, parts, scored
         # context for refit_model: the winner's full-train forest grows as
         # ONE more base pair through the same (cached) grid program, with
         # identical randomness to a sequential full fit.  Single-chip
@@ -634,11 +651,11 @@ class RFGridGroup(TreeGridGroup):
                 seed=int(proto.seed), subsample=subsample)
         with _span("rf.grid.metrics", cat="sweep", rows=C * F):
             if multiclass:
-                m = multiclass_metric_grid(y, scores, jnp.asarray(W_ev),
+                m = multiclass_metric_grid(y_ev, scores, jnp.asarray(W_ev_c),
                                            n_classes, self.metric)
             else:
                 fn = binary_metric_grid if cls else regression_metric_grid
-                m = fn(y, scores, jnp.asarray(W_ev), self.metric)
+                m = fn(y_ev, scores, jnp.asarray(W_ev_c), self.metric)
         if m is None:
             return None
         return m.T
@@ -740,11 +757,41 @@ class RFGridGroup(TreeGridGroup):
             n_classes=ctx["k"] if ctx["cls"] else 2)
 
 
-def _score_pairs_jit(binned, feats, threshs, leaves, heap_depth: int,
+#: the folds' compacted validation rows share one length, the longest
+#: fold's rounded up to this many rows: one program shape a sweep whatever
+#: the folds' sizes
+_FOLD_ROWS_MULTIPLE = 1024
+
+
+def _fold_eval_rows(W_ev: np.ndarray):
+    """``(rows, weights)``, both (F, L): for each fold the rows its metric
+    reads (eval weight above 0: not the rows it trained on, nor what a
+    balancer or a hold-out reservation dropped) and their weights.  A
+    shorter fold is padded with its own last row under weight 0, which adds
+    nothing to any metric.  ``(None, W_ev)`` where L would reach the
+    table's rows: nothing to leave out."""
+    keep = [np.flatnonzero(w > 0) for w in W_ev]
+    L = -(-max(len(k) for k in keep) // _FOLD_ROWS_MULTIPLE
+          ) * _FOLD_ROWS_MULTIPLE
+    if L >= W_ev.shape[1]:
+        return None, W_ev
+    rows = np.zeros((len(keep), L), np.int32)
+    weights = np.zeros((len(keep), L), np.float32)
+    for f, k in enumerate(keep):
+        rows[f, :len(k)] = k
+        rows[f, len(k):] = k[-1] if len(k) else 0
+        weights[f, :len(k)] = W_ev[f, k]
+    return rows, weights
+
+
+def _score_pairs_jit(mats, feats, threshs, leaves, heap_depth: int,
                      mode: str, ptype: str):
     """Pair validation scores in memory-bounded vmapped launches (12
     separate predict+transform launches measured ~8 s at 200k x 500; a
-    single unbounded vmap OOMs on the (pairs, trees, rows) leaf values)."""
+    single unbounded vmap OOMs on the (pairs, trees, rows) leaf values).
+    ``mats`` lists the binned matrices the pairs are scored on: one for
+    every pair, or F of one shape (``_fold_eval_rows``) with the pairs
+    fold-major, the f-th F-th of them scored on the f-th matrix."""
     import functools
 
     import jax
@@ -754,16 +801,18 @@ def _score_pairs_jit(binned, feats, threshs, leaves, heap_depth: int,
 
     fn = functools.partial(_score_ensemble_jit, depth=heap_depth, mode=mode,
                            problem_type=ptype)
-    P, T = feats.shape[0], feats.shape[1]
-    n = binned.shape[0]
+    P, T = feats.shape[0] // len(mats), feats.shape[1]
+    n = mats[0].shape[0]
     k = leaves.shape[-1]
     per_pair = T * n * k * 4
     chunk = int(max(1, min(P, (64 << 20) // max(per_pair, 1))))
     parts = []
-    for s in range(0, P, chunk):
-        parts.append(jax.vmap(lambda f, t, lf: fn(binned, f, t, lf,
-                                                  jnp.float32(0.0)))(
-            feats[s:s + chunk], threshs[s:s + chunk], leaves[s:s + chunk]))
+    for f, mat in enumerate(mats):
+        for s in range(f * P, (f + 1) * P, chunk):
+            e = min(s + chunk, (f + 1) * P)
+            parts.append(jax.vmap(lambda f, t, lf: fn(mat, f, t, lf,
+                                                      jnp.float32(0.0)))(
+                feats[s:e], threshs[s:e], leaves[s:e]))
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
 

@@ -217,7 +217,9 @@ class RunCounters:
     #: (``bases`` = distinct min_instances values, ``pairs`` = base x fold
     #: forests and each refit's one,
     #: ``treesGrown``, ``launches`` of ``chunk`` trees at histogram width
-    #: ``msub`` and ``levels`` heap levels)
+    #: ``msub`` and ``levels`` heap levels) and on how many rows a
+    #: candidate pair was scored (``scoredRows``: a fold's validation rows,
+    #: not the table's)
     rf_grid: Dict[str, int] = field(default_factory=dict)
     #: ``perf_counter()`` of the run's start: ``OpWorkflow.train`` stamps
     #: it on entry (``mark_run_start``), else it is the moment these
@@ -362,14 +364,14 @@ def count_memo(kind: str, outcome: str) -> None:
 
 
 #: ``rfGrid`` keys that describe a launch's shape: the largest seen is kept
-_RF_GRID_SHAPES = ("chunk", "msub", "levels")
+_RF_GRID_SHAPES = ("chunk", "msub", "levels", "scoredRows")
 
 
 def count_rf_grid(**counts: int) -> None:
     """Random-forest grid accounting (``RunCounters.rf_grid``): counts add
     up over a run (the sweep's base pairs and the winner's refit are two
-    calls); the shape keys ``chunk``, ``msub`` and ``levels`` keep the
-    largest value seen."""
+    calls); the shape keys ``chunk``, ``msub``, ``levels`` and
+    ``scoredRows`` keep the largest value seen."""
     with _COUNTERS_LOCK:
         tags = COUNTERS.rf_grid
         for key, n in counts.items():
