@@ -2,7 +2,8 @@
 
 Set-up builds the cell's workflow through the public entry points exactly
 as ``chip_smoke.py`` does (FeatureBuilder -> transmogrify -> SanityChecker
--> BinaryClassificationModelSelector.with_cross_validation -> OpWorkflow,
+-> BinaryClassificationModelSelector.with_cross_validation, or
+RegressionModelSelector's for a regression label -> OpWorkflow,
 ``.with_mesh`` on four chips), trains it ONCE to compile or load every
 program the cell uses, scores the hold-out and runs the checks of
 ``checks.py``.  The window then repeats ``wf.train(profile=True)`` on the
@@ -34,6 +35,7 @@ import math
 import os
 import shutil
 import statistics
+import tempfile
 import time
 import warnings
 
@@ -108,18 +110,25 @@ def build_models(traffic: dict) -> list:
 
 def selector_workflow(df, label, checked, config: dict, traffic: dict,
                       chips: int):
+    from perfbench import checks
     from transmogrifai_tpu import OpWorkflow
-    from transmogrifai_tpu.selector import BinaryClassificationModelSelector
+    from transmogrifai_tpu import selector as selectors
 
-    if config["problem"] != "binary":
+    if config["problem"] not in checks.LABEL_KINDS:
         raise CellFailure(
-            f"train_loop builds binary selectors only, the configuration "
-            f"says {config['problem']!r}: a multi-class or regression cell "
-            f"needs a quality metric beside holdout_aupr (and its selector) "
-            f"first, which is a benchmark issue of its own")
-    models = build_models(traffic)
+            f"train_loop builds binary and regression selectors, the "
+            f"configuration says {config['problem']!r}: a multi-class cell "
+            f"needs a quality metric beside holdout_aupr and holdout_rmse "
+            f"(and its selector) first, which is a benchmark issue of its "
+            f"own")
+    # the file must name the splitter its label kind's selector takes
+    kind = checks.label_kind(config)
     val = config["validator"]
-    selector = BinaryClassificationModelSelector.with_cross_validation(
+    if val["splitter"] != kind.splitter:
+        raise CellFailure(f"{kind.selector} splits with {kind.splitter}, the "
+                          f"configuration says {val['splitter']!r}")
+    models = build_models(traffic)
+    selector = getattr(selectors, kind.selector).with_cross_validation(
         num_folds=val["num_folds"], seed=val["selector_seed"],
         models_and_parameters=models,
         parallel=chips if chips > 1 else None)
@@ -205,7 +214,7 @@ def one_train(ctx, wf, selector, n_candidates: int, leg: str):
             upload_mb=round(counters["uploadBytes"] / 2**20, 1),
             launches=counters["launchTags"], memo=counters["memoTags"],
             selector_cols=rec["selector_cols"], compile=compiled,
-            problems=rec["problems"])
+            problems=rec["problems"], rf_grid=counters.get("rfGrid"))
     return rec, model
 
 
@@ -221,8 +230,9 @@ def traced_train(ctx, wf, selector, n_candidates: int,
 
     from perfbench import trace_reduce
 
-    trace_dir = os.path.join(ctx.out_dir, "trace")
-    shutil.rmtree(trace_dir, ignore_errors=True)
+    # a directory of this process's own: two runs of one cell side by side
+    # (the tests' parallel workers) would delete each other's trace
+    trace_dir = tempfile.mkdtemp(prefix="trace-", dir=ctx.out_dir)
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0  # host spans come from obs, not the VM
     tracer = obs_trace.start_trace("perfbench", capture_hlo=False)
@@ -385,8 +395,9 @@ def run(ctx) -> dict:
                             f"{ANNOTATION!r} annotation, so its device "
                             f"seconds are not those of the train alone")
     walls = [rec["wall_s"] for rec in trains]
+    holdout = checks.label_kind(config).holdout
     end_to_end = {"train_s": statistics.median(walls),
-                  "holdout_aupr": verdict["holdout_aupr"]}
+                  holdout: verdict[holdout]}
     busy = device_seconds(trains)
     if busy:
         end_to_end["train_device_s"] = statistics.median(busy)

@@ -140,8 +140,10 @@ def test_a_later_cell_is_a_data_file_and_an_entry(cell, traffic, tmp_path):
             "name": cell, "config": "dense500-binary", "traffic": traffic,
             "chips": 1, "why": "entered by a later PR"})
         # a metric that exists only in some cells lists them: the new cell
-        # puts its name on the lists of the metrics it reports
-        _list_cell(bench, cell, "train_s", "selector_s", "drain_s")
+        # puts its name on the lists of the metrics it reports, its label
+        # kind's quality metric among them
+        _list_cell(bench, cell, "train_s", "holdout_aupr", "selector_s",
+                   "drain_s")
         if cell == "dense500-linear":
             bench["per_layer"].append({
                 "name": "linear_device_s", "unit": "s", "better": "lower",
@@ -233,8 +235,8 @@ def test_a_configuration_with_another_schema_is_new_files_and_entries(
         bench["workloads"].append({
             "name": cell, "config": "typed-probe", "traffic": "xgb-typed",
             "chips": 1, "why": "entered by a later PR"})
-        _list_cell(bench, cell, "train_device_s", "tree_device_s",
-                   "tree_hist_roofline", "peak_hbm_gib")
+        _list_cell(bench, cell, "train_device_s", "holdout_aupr",
+                   "tree_device_s", "tree_hist_roofline", "peak_hbm_gib")
 
     before = _enter(tmp_path, edit, {
         "perfbench/generators/typed_planted.py": _later("typed_planted.py"),
@@ -287,6 +289,145 @@ def test_a_configuration_with_another_schema_is_new_files_and_entries(
     after = _hashes(tmp_path)
     assert [k for k in before if after.get(k) != before[k]] == [
         "BENCHMARK.json"]
+
+
+#: the regression probe kept in ``later/``: its cell, and the metrics the
+#: cell appends its name to (a forest cell on one chip, as
+#: ``dense500-rf-grid18``)
+REGRESSION_CELL = "regression-probe-rf"
+REGRESSION_METRICS = (
+    "train_device_s", "tree_device_s", "peak_hbm_gib",
+    "rf_grow_device_s", "rf_score_device_s", "rf_trees_grown", "rf_launches",
+    "rf_hist_roofline", "rf_scored_rows")
+#: the end-to-end entry the first regression cell appends, with its own
+#: name on the list (the contract admits no empty one); its bound is
+#: PERF.md section 2's
+HOLDOUT_RMSE = {"name": "holdout_rmse", "unit": "RMSE", "better": "lower",
+                "bound": 0.02, "source": "host_clock"}
+#: the benchmark's own test files that hold BENCHMARK.json's entries
+PINS = ("test_perfbench_contract.py", "test_perfbench_spans.py",
+        "test_perfbench_host_metrics.py", "test_perfbench_rf_grid.py",
+        "test_perfbench_rf_scored_rows.py")
+
+
+def _enter_regression_probe(tmp_path):
+    """The probe as a later PR enters a regression cell: three new files,
+    a configuration, a cell and ``holdout_rmse`` appended, and the cell's
+    name appended to the lists of the other metrics it reports."""
+    config = json.loads(_later("regression-probe.json"))
+
+    def edit(bench):
+        bench["configs"].append({
+            "name": "regression-probe", "source": config["source"],
+            "file": "perfbench/configs/regression-probe.json",
+            "reduced": sorted(config["reduced"]),
+            "why": "entered by a later PR: a regression label"})
+        bench["workloads"].append({
+            "name": REGRESSION_CELL, "config": "regression-probe",
+            "traffic": "rf-reg-grid18", "chips": 1,
+            "why": "entered by a later PR"})
+        assert "holdout_rmse" not in {m["name"] for m in bench["end_to_end"]}
+        bench["end_to_end"].append(
+            dict(HOLDOUT_RMSE, workloads=[REGRESSION_CELL]))
+        _list_cell(bench, REGRESSION_CELL, *REGRESSION_METRICS)
+
+    return _enter(tmp_path, edit, {
+        "perfbench/generators/planted_regression.py": _later(
+            "planted_regression.py"),
+        "perfbench/configs/regression-probe.json": _later(
+            "regression-probe.json"),
+        "perfbench/traffic/rf-reg-grid18.json": _later("rf-reg-grid18.json")})
+
+
+def test_the_regression_generator_plants_one_model_and_owns_its_oracle():
+    import importlib.util
+
+    import numpy as np
+
+    file = importlib.util.spec_from_file_location(
+        "planted_regression", os.path.join(LATER, "planted_regression.py"))
+    gen = importlib.util.module_from_spec(file)
+    file.loader.exec_module(gen)
+    sys.path.insert(0, ROOT)
+    from perfbench.reference import oracle
+
+    a, planted_a = gen.generate(20_000, 30, 3)
+    b, _ = gen.generate(20_000, 30, 3)
+    c, planted_c = gen.generate(20_000, 30, 2147483900, mean=1998.0)
+    assert a.equals(b) and not a.head(500).equals(c.head(500))
+    assert (planted_a["beta"] == planted_c["beta"]).all()      # one model
+    assert (planted_a["beta"] != 0).sum() == 15
+    assert list(a.columns) == ["label"] + [f"f{j}" for j in range(30)]
+    assert a["label"].dtype == np.float32
+    # the oracle is the planted mean: its RMSE is the noise's sd
+    for frame, planted, mean in ((a, planted_a, 0.0), (c, planted_c, 1998.0)):
+        best = oracle.rmse(frame["label"].to_numpy(),
+                           gen.oracle_predict(frame, planted))
+        assert best == pytest.approx(2.0, rel=0.02)
+        assert frame["label"].mean() == pytest.approx(mean, abs=0.1)
+        assert frame["label"].std() > 1.5 * best
+
+
+def test_a_regression_configuration_is_new_files_and_entries(tmp_path):
+    """The regression probe enters the copy as new files and appended
+    entries and names only; every rule of the contract's test file and every
+    pin of the benchmark's own tests then holds on the copy."""
+    before = _enter_regression_probe(tmp_path)
+    rules = subprocess.run(
+        [sys.executable, "-m", "pytest", "-v", "-p", "no:cacheprovider",
+         "-p", "no:xdist", "-k", "not rehearsal",
+         *(os.path.join("tests", "perfbench", f) for f in PINS)],
+        capture_output=True, text=True, timeout=600, cwd=tmp_path,
+        env=child_env(tmp_path / "jax_cache"))
+    assert rules.returncode == 0, rules.stdout[-3000:] + rules.stderr[-2000:]
+    for held in ("test_config_entry[regression-probe]",
+                 f"test_cell_entry_and_files[{REGRESSION_CELL}]",
+                 "test_the_cuts_a_configuration_states_are_the_mix_s_own_"
+                 f"values[{REGRESSION_CELL}]",
+                 "test_a_cell_reports_the_quality_metric_of_its_label_kind",
+                 "test_holdout_rmse_waits_for_its_first_cell_with_its_bound",
+                 "test_every_file_of_the_benchmark_serves_a_cell",
+                 "test_the_twelve_entries_stand_at_the_end_and_move_the_"
+                 "mesh_cell_s_wall",
+                 "test_the_eight_entries_stand_last_in_the_table_s_order",
+                 "test_new_entries_stand_at_the_end_and_touch_nothing_that_"
+                 "was_there",
+                 "test_the_entry_stands_after_every_accepted_one_in_their_"
+                 "order"):
+        assert f"{held} PASSED" in rules.stdout, held
+    assert " failed" not in rules.stdout and " error" not in rules.stdout
+    after = _hashes(tmp_path)
+    assert [k for k in before if after.get(k) != before[k]] == [
+        "BENCHMARK.json"]
+
+
+def test_the_regression_probe_runs_correct_through_the_regression_selector(
+        tmp_path):
+    """The probe's cell, at its own shape: ``RegressionModelSelector`` with
+    ``DataSplitter`` over upstream's 18-point forest-regressor grid, held to
+    the planted mean, the NumPy walker and its quality bands, and reporting
+    ``holdout_rmse`` in place of ``holdout_aupr``."""
+    _enter_regression_probe(tmp_path)
+    out, last = run_cell(REGRESSION_CELL, "--allow-cpu", root=str(tmp_path),
+                         cache_dir=tmp_path / "jax_cache")
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert last["correct"] is True and last["failed"] == 0
+    assert last["attempted"] == 18 * 3 + 1
+    assert set(last["metrics"]) == {"holdout_rmse", "setup_s"}
+    assert last["metrics"]["holdout_rmse"]["unit"] == "RMSE"
+    compared = last["compared"]
+    assert {"rmse_over_oracle", "tree_scorer_diff", "holdout_rmse",
+            "cv_rmse.OpRandomForestRegressor",
+            "cv_spread.OpRandomForestRegressor"} <= set(compared)
+    assert not [k for k in compared if "aupr" in k]
+    ratio, floor = compared["rmse_over_oracle"]
+    assert ratio >= floor
+    # the grid's depths and gates grew forests that score apart
+    spread, least = compared["cv_spread.OpRandomForestRegressor"]
+    assert spread >= least > 0
+    assert compared["tree_scorer_diff"][0] <= compared["tree_scorer_diff"][1]
+    assert 'oracle_from="oracle_predict"' in out.stdout
+    assert "rows=12000 cols=30" in out.stdout
 
 
 COLD_MODE = '''"""Mode ``train_loop_cold``: ``train_loop`` with JAX's in-memory
@@ -368,7 +509,7 @@ def test_new_files_and_entries_are_enough(tmp_path):
         "name": "steps_done", "unit": "count", "better": "higher",
         "source": "program_counter", "layer": "front end",
         "moves": "train_s", "workloads": ["uniform64-matmul"]})
-    _list_cell(bench, "uniform64-matmul", "train_s")
+    _list_cell(bench, "uniform64-matmul", "train_s", "holdout_aupr")
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
 
     cache = tmp_path / "jax_cache"

@@ -153,15 +153,18 @@ def test_reader_constants_are_the_entries():
 # -- BENCHMARK.json ------------------------------------------------------------
 
 def test_the_eight_entries_stand_last_in_the_table_s_order():
-    tail = BENCH["per_layer"][-len(NEW):]
-    assert [m["name"] for m in tail] == list(NEW)
-    for m in tail:
+    """The eight were appended after the thirty that stood; later entries
+    are appended after them, so their place is held, not the tail."""
+    mine = BENCH["per_layer"][30:30 + len(NEW)]
+    assert [m["name"] for m in mine] == list(NEW)
+    for m in mine:
         unit, source, layer = NEW[m["name"]]
-        assert m == {"name": m["name"], "unit": unit, "better": "lower",
-                     "source": source, "layer": layer, "moves": "train_s",
-                     "workloads": [CELL]}
+        assert dict(m, workloads=m["workloads"][:1]) == {
+            "name": m["name"], "unit": unit, "better": "lower",
+            "source": source, "layer": layer, "moves": "train_s",
+            "workloads": [CELL]}
     # the thirty that stood are the thirty that stand
-    assert len(BENCH["per_layer"]) == 30 + len(NEW)
+    assert len(BENCH["per_layer"]) >= 30 + len(NEW)
     assert BENCH["per_layer"][29]["name"] == "rf_hist_roofline"
     assert BENCH["per_layer"][0]["name"] == "vectorize_s"
 

@@ -70,9 +70,11 @@ def test_reader_gives_nothing_where_the_program_has_no_such_counter(grid):
 def test_reader_constants_are_the_entry():
     reader = spec.load_module("metrics", NAME)
     (entry,) = [m for m in BENCH["per_layer"] if m["name"] == NAME]
-    assert entry == {"name": NAME, "unit": reader.UNIT, "better": "lower",
-                     "source": "program_counter", "layer": reader.LAYER,
-                     "moves": reader.MOVES, "workloads": [CELL]}
+    # a later forest cell may append its name to the list
+    assert dict(entry, workloads=entry["workloads"][:1]) == {
+        "name": NAME, "unit": reader.UNIT, "better": "lower",
+        "source": "program_counter", "layer": reader.LAYER,
+        "moves": reader.MOVES, "workloads": [CELL]}
     assert (reader.UNIT, reader.LAYER, reader.MOVES) == (
         "count", "sweep", "train_device_s")
 
@@ -81,8 +83,8 @@ def test_the_entry_stands_after_every_accepted_one_in_their_order():
     names = [m["name"] for m in BENCH["per_layer"]]
     assert names[:len(ACCEPTED)] == ACCEPTED
     assert names[len(ACCEPTED)] == NAME
-    # the cell reports it, and no other cell does
-    for cell in (w["name"] for w in BENCH["workloads"]):
+    # the cell reports it, and no other accepted cell does
+    for cell in ("dense500-xgb", "mesh4-trees", CELL):
         reported = {m["name"] for m in spec.load_cell(cell)["per_layer"]}
         assert (NAME in reported) == (cell == CELL)
 

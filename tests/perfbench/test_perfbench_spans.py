@@ -250,14 +250,24 @@ def test_broad_spans_name_nothing(reduced):
 
 # -- BENCHMARK.json ------------------------------------------------------------
 
+#: the per-layer metrics the benchmark had before these twelve, in its order
+BEFORE = ["vectorize_s", "sanity_s", "selector_s", "drain_s",
+          "mesh_tree_device_s", "collective_s", "window_programs",
+          "tree_device_s", "tree_hist_roofline", "peak_hbm_gib", "compile_s",
+          "programs", "peak_host_gib"]
+
+
 def test_the_twelve_entries_stand_at_the_end_and_move_the_mesh_cell_s_wall():
-    tail = BENCH["per_layer"][-len(NEW):]
-    assert [m["name"] for m in tail] == NEW
+    """The twelve were appended after the thirteen that stood, and later
+    entries are appended after them: their place is held, not the table's
+    tail, so a later cell or metric leaves this green."""
+    mine = BENCH["per_layer"][len(BEFORE):len(BEFORE) + len(NEW)]
+    assert [m["name"] for m in mine] == NEW
     layers = {"compile": ["window_compile_s"],
               "sweep": ["xgb_group_s", "rf_group_s", "refit_s",
                         "winner_eval_s", "host_unnamed_s"]}
-    for m in tail:
-        assert (m["moves"], m["better"], m["workloads"]) == (
+    for m in mine:
+        assert (m["moves"], m["better"], m["workloads"][:1]) == (
             "train_s", "lower", ["mesh4-trees"])
         want = ("program_counter" if m["name"] == "prep_builds"
                 else "program_span")
@@ -266,8 +276,4 @@ def test_the_twelve_entries_stand_at_the_end_and_move_the_mesh_cell_s_wall():
                      "tree input prep")
         assert m["layer"] == layer, m["name"]
     # and nothing the accepted benchmark had was touched
-    assert [m["name"] for m in BENCH["per_layer"][:-len(NEW)]] == [
-        "vectorize_s", "sanity_s", "selector_s", "drain_s",
-        "mesh_tree_device_s", "collective_s", "window_programs",
-        "tree_device_s", "tree_hist_roofline", "peak_hbm_gib", "compile_s",
-        "programs", "peak_host_gib"]
+    assert [m["name"] for m in BENCH["per_layer"][:len(BEFORE)]] == BEFORE
