@@ -1,7 +1,8 @@
 """Child-process plumbing shared by the perfbench tests: the platform, x64
 and the compile-cache directory all latch at first jax use, so every run of
 ``perfbench/run.py`` is a process of its own (the pattern of
-``tests/test_chip_smoke.py``)."""
+``tests/test_chip_smoke.py``); and what a tiny run of a cell is given and
+has to print, which follows from the cell's configuration alone."""
 import json
 import os
 import subprocess
@@ -12,6 +13,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 TINY = ["--rows", "3000", "--cols", "32"]
+#: the generator's own oracle, by label kind (``perfbench/checks.py``); a
+#: binary generator without one is scored by ``X @ beta``
+OWN_ORACLE = {"binary": "oracle_score", "regression": "oracle_predict"}
 
 
 def child_env(cache_dir, devices: int = 1, **extra) -> dict:
@@ -43,3 +47,24 @@ def run_cell(workload: str, *flags, root: str = ROOT, cache_dir,
     if lines and lines[-1].startswith("{"):
         last = json.loads(lines[-1])
     return out, last
+
+
+def tiny_run(cell: str, root: str = ROOT) -> dict:
+    """A tiny run of the one-chip ``cell`` under ``root``: its ``flags`` (a
+    schema that lists its columns keeps them), and what its last line and
+    log have to show by the cell's label kind (``checks.LABEL_KINDS``) and
+    generator: ``holdout``, the quality metric; ``oracle_key``, the metric's
+    comparison with the oracle in ``compared``; ``oracle_from``."""
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    from perfbench import checks, spec
+
+    config = spec.load_cell(cell, root=root)["config"]
+    kind = checks.label_kind(config)
+    generator = spec.load_module("generators", config["generator"]["name"],
+                                 root=root)
+    own = OWN_ORACLE[config["problem"]]
+    listed = "columns" in config["schema"]["predictors"]
+    return {"flags": TINY[:2] if listed else TINY, "holdout": kind.holdout,
+            "oracle_key": kind.metric.lower() + "_over_oracle",
+            "oracle_from": own if hasattr(generator, own) else "X @ beta"}

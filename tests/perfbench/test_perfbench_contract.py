@@ -143,8 +143,9 @@ def test_cells_and_chips():
     assert four <= max(1, len(chips) // 4)
 
 
-#: ISSUE 22's two cells that wait under PERF.md's Open questions; their files
-#: are kept here and entered in a temporary copy (test_perfbench_extend.py)
+#: the mixes of the two cells that wait under PERF.md's Open questions, kept
+#: here under probe names and entered in a temporary copy
+#: (test_perfbench_extend.py)
 LATER = os.path.join(ROOT, "tests", "perfbench", "later")
 
 
@@ -159,11 +160,11 @@ def test_never_cut_depths_are_in_the_traffic_files():
     # XGB runs at the estimator's defaults, which must stay upstream's
     xgb = OpXGBoostClassifier(num_round=8)
     assert (xgb.max_depth, xgb.max_bins) == (10, 32)
-    for mix in (_traffic("tree-groups"), _traffic("rf-pairs", LATER)):
+    for mix in (_traffic("tree-groups"), _traffic("rf-pairs-probe", LATER)):
         (rf,) = [m for m in mix["models_and_parameters"]
                  if m["estimator"] == "OpRandomForestClassifier"]
         assert rf["grid"]["max_depth"] == [12]
-    lr = _traffic("lr-grid-full-train", LATER)
+    lr = _traffic("lr-grid-full-train-probe", LATER)
     assert lr["models_and_parameters"][0]["grid"] == {
         "reg_param": [0.001, 0.01, 0.1, 0.2],
         "elastic_net_param": [0.1, 0.5]}
@@ -434,7 +435,7 @@ def test_histogram_bytes_of_the_roofline_metric():
     assert histogram_bytes(250_000, 500, xgb, 3, "OpXGBoostClassifier") == (
         32 * 10 * 100_000 * 508)
     # a forest that does not win is not refitted: 4 trees x 3 folds
-    rf = _traffic("rf-pairs", LATER)
+    rf = _traffic("rf-pairs-probe", LATER)
     assert histogram_bytes(1000, 500, rf, 3, "OpLogisticRegression") == (
         12 * 12 * 1000 * 508)
     assert histogram_bytes(1000, 500, rf, 3, "OpRandomForestClassifier") == (

@@ -2,12 +2,14 @@
 metric, a generator and a mode as NEW files plus BENCHMARK.json entries,
 and edits no file that is there.  Shown here in a temporary copy of the
 benchmark: one of each is added, the new cell runs, and every file the
-copy started with is byte-for-byte what it was.
+copy started with is byte-for-byte what it was.  The probes of ``later/``
+enter under names of their own (``_probes.py``), so each of them enters a
+tree that already holds a real cell of its kind as well as one that holds
+none: ``test_perfbench_second_cell.py`` runs the tests here that run no cell
+on a copy that holds a first regression cell.
 """
-import hashlib
 import json
 import os
-import shutil
 import subprocess
 import sys
 
@@ -16,6 +18,10 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from _child import (RESULT_KEYS, ROOT, TINY, child_env,  # noqa: E402
                     run_cell)
+from _probes import (LATER, PINS, REGRESSION_CELL,  # noqa: E402
+                     TYPED_CELL, copy_of_the_benchmark, enter,
+                     enter_probe, enter_regression_probe, enter_typed_probe,
+                     hashes, later, list_cell)
 
 NEW_MODE = '''"""Mode ``matmul_loop``: a stand-in for a later PR's mode (say an
 open-loop server): it never trains, it runs one jitted product per step."""
@@ -67,73 +73,25 @@ def read(sources):
 '''
 
 
-def _hashes(root):
-    out = {}
-    for base, dirs, files in os.walk(root):
-        dirs[:] = [d for d in dirs if d not in ("__pycache__", ".jax_cache",
-                                                "chiprun_out")]
-        for f in files:
-            path = os.path.join(base, f)
-            if os.path.islink(path):
-                continue
-            with open(path, "rb") as fh:
-                out[os.path.relpath(path, root)] = hashlib.sha256(
-                    fh.read()).hexdigest()
-    return out
-
-
-def _copy_of_the_benchmark(tmp_path):
-    """BENCHMARK.json and both directories of its ``paths``."""
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
-    for path in ("perfbench", os.path.join("tests", "perfbench")):
-        shutil.copytree(os.path.join(ROOT, path), tmp_path / path,
-                        ignore=shutil.ignore_patterns("__pycache__"))
-    # the program beside the copy, as in a checkout
-    os.symlink(os.path.join(ROOT, "transmogrifai_tpu"),
-               tmp_path / "transmogrifai_tpu")
-
-
-LATER = os.path.join(ROOT, "tests", "perfbench", "later")
-
-
-def _enter(tmp_path, bench_edit, files: dict):
-    """Add ``files`` (``{path under the copy: text}``) and the entries
-    ``bench_edit`` makes to the copy; returns the hashes from before."""
-    _copy_of_the_benchmark(tmp_path)
-    before = _hashes(tmp_path)
-    for rel, text in files.items():
-        assert rel not in before, f"{rel} would edit a file that is there"
-        (tmp_path / rel).write_text(text)
-    with open(tmp_path / "BENCHMARK.json") as f:
-        bench = json.load(f)
-    bench_edit(bench)
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    return before
-
-
-def _list_cell(bench, cell, *metrics):
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if m["name"] in metrics:
-            m["workloads"].append(cell)
-
-
-def _later(name):
-    with open(os.path.join(LATER, name)) as f:
-        return f.read()
-
-
 @pytest.mark.parametrize("cell,traffic", [
-    ("dense500-rf", "rf-pairs"), ("dense500-linear", "lr-grid-full-train")])
+    # the ids are the cells' and mixes' names before they took ``-probe``
+    pytest.param("dense500-rf-probe", "rf-pairs-probe",
+                 id="dense500-rf-rf-pairs"),
+    pytest.param("dense500-linear-probe", "lr-grid-full-train-probe",
+                 id="dense500-linear-lr-grid-full-train")])
 def test_a_later_cell_is_a_data_file_and_an_entry(cell, traffic, tmp_path):
-    """ISSUE 22's ``dense500-rf`` and ``dense500-linear`` wait under
+    """The cells ``dense500-rf`` and ``dense500-linear`` wait under
     PERF.md's Open questions; their mixes are kept in ``later/``.  The PR
     that enters one adds the data file (for the LR cell its reader too) and
     entries, and the cell's path (RF grid chunks; vectorizer and
-    SanityChecker fit with the LR grid, ``full_train``) runs end to end."""
-    files = {f"perfbench/traffic/{traffic}.json": _later(traffic + ".json")}
-    if cell == "dense500-linear":
-        files["perfbench/metrics/linear_device_s.py"] = _later(
-            "linear_device_s.py")
+    SanityChecker fit with the LR grid, ``full_train``) runs end to end.
+    Entered here as probes, so that a real cell of either name, or either
+    mix copied under ``perfbench/``, leaves this test as it is."""
+    linear = cell == "dense500-linear-probe"
+    files = {f"perfbench/traffic/{traffic}.json": later(traffic + ".json")}
+    if linear:
+        files["perfbench/metrics/linear_device_s_probe.py"] = later(
+            "linear_device_s_probe.py")
 
     def edit(bench):
         bench["workloads"].append({
@@ -142,21 +100,22 @@ def test_a_later_cell_is_a_data_file_and_an_entry(cell, traffic, tmp_path):
         # a metric that exists only in some cells lists them: the new cell
         # puts its name on the lists of the metrics it reports, its label
         # kind's quality metric among them
-        _list_cell(bench, cell, "train_s", "holdout_aupr", "selector_s",
-                   "drain_s")
-        if cell == "dense500-linear":
+        list_cell(bench, cell, "train_s", "holdout_aupr", "selector_s",
+                  "drain_s")
+        if linear:
             bench["per_layer"].append({
-                "name": "linear_device_s", "unit": "s", "better": "lower",
-                "source": "device_trace", "layer": "linear solver",
-                "moves": "train_s", "workloads": [cell]})
+                "name": "linear_device_s_probe", "unit": "s",
+                "better": "lower", "source": "device_trace",
+                "layer": "linear solver", "moves": "train_s",
+                "workloads": [cell]})
 
-    before = _enter(tmp_path, edit, files)
+    before = enter_probe(copy_of_the_benchmark(tmp_path), edit, files)
     out, last = run_cell(cell, "--allow-cpu", *TINY, root=str(tmp_path),
                          cache_dir=tmp_path / "jax_cache")
     assert out.returncode == 0, out.stderr[-3000:]
     assert last["correct"] is True and last["failed"] == 0
     assert set(last["metrics"]) == {"train_s", "holdout_aupr", "setup_s"}
-    if traffic == "rf-pairs":
+    if not linear:
         assert "rf_grid_chunk" in out.stdout
     else:
         assert '"RealVectorizer:fit"' in out.stdout
@@ -166,8 +125,8 @@ def test_a_later_cell_is_a_data_file_and_an_entry(cell, traffic, tmp_path):
 
         names = [m["name"] for m in spec.load_cell(
             cell, root=str(tmp_path))["per_layer"]]
-        assert "linear_device_s" in names
-    after = _hashes(tmp_path)
+        assert "linear_device_s_probe" in names
+    after = hashes(tmp_path)
     assert [k for k in before if after.get(k) != before[k]] == [
         "BENCHMARK.json"]
 
@@ -178,7 +137,7 @@ def test_the_typed_generator_plants_one_model_and_owns_its_oracle():
     import numpy as np
 
     file = importlib.util.spec_from_file_location(
-        "typed_planted", os.path.join(LATER, "typed_planted.py"))
+        "typed_planted_probe", os.path.join(LATER, "typed_planted_probe.py"))
     typed = importlib.util.module_from_spec(file)
     file.loader.exec_module(typed)
     sys.path.insert(0, ROOT)
@@ -219,29 +178,12 @@ def test_a_configuration_with_another_schema_is_new_files_and_entries(
         tmp_path):
     """``typed-probe``: nullable ``Real``, ``Integral`` and ``PickList``
     columns, its own rows, columns and hold-out, a planted model that is not
-    linear in the raw columns (``later/typed_planted.py`` and its
+    linear in the raw columns (``later/typed_planted_probe.py`` and its
     ``oracle_score``).  It enters the copy as three new files and entries;
     every rule of the contract's test file then holds on the copy, and the
     cell rehearses ``correct`` on the CPU."""
-    config = json.loads(_later("typed-probe.json"))
-    cell = "typed-probe-xgb"
-
-    def edit(bench):
-        bench["configs"].append({
-            "name": "typed-probe", "source": config["source"],
-            "file": "perfbench/configs/typed-probe.json",
-            "reduced": sorted(config["reduced"]),
-            "why": "entered by a later PR: another schema, size and model"})
-        bench["workloads"].append({
-            "name": cell, "config": "typed-probe", "traffic": "xgb-typed",
-            "chips": 1, "why": "entered by a later PR"})
-        _list_cell(bench, cell, "train_device_s", "holdout_aupr",
-                   "tree_device_s", "tree_hist_roofline", "peak_hbm_gib")
-
-    before = _enter(tmp_path, edit, {
-        "perfbench/generators/typed_planted.py": _later("typed_planted.py"),
-        "perfbench/configs/typed-probe.json": _later("typed-probe.json"),
-        "perfbench/traffic/xgb-typed.json": _later("xgb-typed.json")})
+    cell = TYPED_CELL
+    before = enter_typed_probe(copy_of_the_benchmark(tmp_path))
     assert any(k.startswith("tests/perfbench/") for k in before)
 
     # (a) the contract's rules, as its own test file states them, on the copy
@@ -286,57 +228,9 @@ def test_a_configuration_with_another_schema_is_new_files_and_entries(
     width = int(traced.split(" selector_cols=")[1].split(" ")[0])
     assert 60 <= width <= 15 * 22
 
-    after = _hashes(tmp_path)
+    after = hashes(tmp_path)
     assert [k for k in before if after.get(k) != before[k]] == [
         "BENCHMARK.json"]
-
-
-#: the regression probe kept in ``later/``: its cell, and the metrics the
-#: cell appends its name to (a forest cell on one chip, as
-#: ``dense500-rf-grid18``)
-REGRESSION_CELL = "regression-probe-rf"
-REGRESSION_METRICS = (
-    "train_device_s", "tree_device_s", "peak_hbm_gib",
-    "rf_grow_device_s", "rf_score_device_s", "rf_trees_grown", "rf_launches",
-    "rf_hist_roofline", "rf_scored_rows")
-#: the end-to-end entry the first regression cell appends, with its own
-#: name on the list (the contract admits no empty one); its bound is
-#: PERF.md section 2's
-HOLDOUT_RMSE = {"name": "holdout_rmse", "unit": "RMSE", "better": "lower",
-                "bound": 0.02, "source": "host_clock"}
-#: the benchmark's own test files that hold BENCHMARK.json's entries
-PINS = ("test_perfbench_contract.py", "test_perfbench_spans.py",
-        "test_perfbench_host_metrics.py", "test_perfbench_rf_grid.py",
-        "test_perfbench_rf_scored_rows.py")
-
-
-def _enter_regression_probe(tmp_path):
-    """The probe as a later PR enters a regression cell: three new files,
-    a configuration, a cell and ``holdout_rmse`` appended, and the cell's
-    name appended to the lists of the other metrics it reports."""
-    config = json.loads(_later("regression-probe.json"))
-
-    def edit(bench):
-        bench["configs"].append({
-            "name": "regression-probe", "source": config["source"],
-            "file": "perfbench/configs/regression-probe.json",
-            "reduced": sorted(config["reduced"]),
-            "why": "entered by a later PR: a regression label"})
-        bench["workloads"].append({
-            "name": REGRESSION_CELL, "config": "regression-probe",
-            "traffic": "rf-reg-grid18", "chips": 1,
-            "why": "entered by a later PR"})
-        assert "holdout_rmse" not in {m["name"] for m in bench["end_to_end"]}
-        bench["end_to_end"].append(
-            dict(HOLDOUT_RMSE, workloads=[REGRESSION_CELL]))
-        _list_cell(bench, REGRESSION_CELL, *REGRESSION_METRICS)
-
-    return _enter(tmp_path, edit, {
-        "perfbench/generators/planted_regression.py": _later(
-            "planted_regression.py"),
-        "perfbench/configs/regression-probe.json": _later(
-            "regression-probe.json"),
-        "perfbench/traffic/rf-reg-grid18.json": _later("rf-reg-grid18.json")})
 
 
 def test_the_regression_generator_plants_one_model_and_owns_its_oracle():
@@ -345,7 +239,8 @@ def test_the_regression_generator_plants_one_model_and_owns_its_oracle():
     import numpy as np
 
     file = importlib.util.spec_from_file_location(
-        "planted_regression", os.path.join(LATER, "planted_regression.py"))
+        "planted_regression_probe",
+        os.path.join(LATER, "planted_regression_probe.py"))
     gen = importlib.util.module_from_spec(file)
     file.loader.exec_module(gen)
     sys.path.insert(0, ROOT)
@@ -370,9 +265,12 @@ def test_the_regression_generator_plants_one_model_and_owns_its_oracle():
 
 def test_a_regression_configuration_is_new_files_and_entries(tmp_path):
     """The regression probe enters the copy as new files and appended
-    entries and names only; every rule of the contract's test file and every
-    pin of the benchmark's own tests then holds on the copy."""
-    before = _enter_regression_probe(tmp_path)
+    entries and names only, on a tree with no regression cell (it appends
+    ``holdout_rmse``) or on one that holds a regression cell already (it
+    appends its name to the entry's list); every rule of the contract's test
+    file and every pin of the benchmark's own tests then holds on the copy,
+    ``holdout_rmse`` listing each regression cell."""
+    before = enter_regression_probe(copy_of_the_benchmark(tmp_path))
     rules = subprocess.run(
         [sys.executable, "-m", "pytest", "-v", "-p", "no:cacheprovider",
          "-p", "no:xdist", "-k", "not rehearsal",
@@ -396,7 +294,7 @@ def test_a_regression_configuration_is_new_files_and_entries(tmp_path):
                  "order"):
         assert f"{held} PASSED" in rules.stdout, held
     assert " failed" not in rules.stdout and " error" not in rules.stdout
-    after = _hashes(tmp_path)
+    after = hashes(tmp_path)
     assert [k for k in before if after.get(k) != before[k]] == [
         "BENCHMARK.json"]
 
@@ -406,8 +304,9 @@ def test_the_regression_probe_runs_correct_through_the_regression_selector(
     """The probe's cell, at its own shape: ``RegressionModelSelector`` with
     ``DataSplitter`` over upstream's 18-point forest-regressor grid, held to
     the planted mean, the NumPy walker and its quality bands, and reporting
-    ``holdout_rmse`` in place of ``holdout_aupr``."""
-    _enter_regression_probe(tmp_path)
+    ``holdout_rmse`` in place of ``holdout_aupr``.  The probe enters beside
+    any regression cell the tree holds: its files and names are its own."""
+    enter_regression_probe(copy_of_the_benchmark(tmp_path))
     out, last = run_cell(REGRESSION_CELL, "--allow-cpu", root=str(tmp_path),
                          cache_dir=tmp_path / "jax_cache")
     assert out.returncode == 0, out.stderr[-3000:]
@@ -467,7 +366,7 @@ def test_one_chip_cell_on_the_mesh_cell_s_mix_fails_on_a_program_in_the_window(
             "traffic": "tree-groups-cold", "chips": 1,
             "why": "the mesh cell's mix on one chip, re-jitting"})
 
-    _enter(tmp_path, edit, {
+    enter(copy_of_the_benchmark(tmp_path), edit, {
         "perfbench/modes/train_loop_cold.py": COLD_MODE,
         "perfbench/traffic/tree-groups-cold.json": json.dumps(mix)})
     out, last = run_cell("dense500-trees-cold", "--allow-cpu", *TINY,
@@ -479,8 +378,8 @@ def test_one_chip_cell_on_the_mesh_cell_s_mix_fails_on_a_program_in_the_window(
 
 
 def test_new_files_and_entries_are_enough(tmp_path):
-    _copy_of_the_benchmark(tmp_path)
-    before = _hashes(tmp_path)
+    copy_of_the_benchmark(tmp_path)
+    before = hashes(tmp_path)
 
     pb = tmp_path / "perfbench"
     (pb / "modes" / "matmul_loop.py").write_text(NEW_MODE)
@@ -509,7 +408,7 @@ def test_new_files_and_entries_are_enough(tmp_path):
         "name": "steps_done", "unit": "count", "better": "higher",
         "source": "program_counter", "layer": "front end",
         "moves": "train_s", "workloads": ["uniform64-matmul"]})
-    _list_cell(bench, "uniform64-matmul", "train_s", "holdout_aupr")
+    list_cell(bench, "uniform64-matmul", "train_s", "holdout_aupr")
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
 
     cache = tmp_path / "jax_cache"
@@ -521,7 +420,7 @@ def test_new_files_and_entries_are_enough(tmp_path):
     assert set(last["metrics"]) == {"train_s", "holdout_aupr", "setup_s"}
     assert "rows=2000 cols=64" in out.stdout
 
-    after = _hashes(tmp_path)
+    after = hashes(tmp_path)
     changed = [k for k in before if after.get(k) != before[k]]
     assert changed == ["BENCHMARK.json"]  # entries added, no file edited
     added = sorted(set(after) - set(before))

@@ -34,7 +34,8 @@ def _later_json(name):
 
 def _generator():
     file = importlib.util.spec_from_file_location(
-        "planted_regression", os.path.join(LATER, "planted_regression.py"))
+        "planted_regression_probe",
+        os.path.join(LATER, "planted_regression_probe.py"))
     gen = importlib.util.module_from_spec(file)
     file.loader.exec_module(gen)
     return gen
@@ -128,7 +129,7 @@ def test_rmse_of_the_oracle():
 # -- the checks' RMSE bands ---------------------------------------------------
 
 def _regression_ctx(rehearsal_shape=False):
-    return SimpleNamespace(traffic=_later_json("rf-reg-grid18.json"),
+    return SimpleNamespace(traffic=_later_json("rf-reg-grid18-probe.json"),
                            config=_later_json("regression-probe.json"),
                            rehearsal_shape=rehearsal_shape)
 
@@ -212,7 +213,7 @@ def test_a_grid_whose_candidates_all_read_one_cv_rmse_is_a_problem():
 
 
 def test_the_probe_s_bands_hold_the_reference_s_readings():
-    band = _later_json("rf-reg-grid18.json")["checks"]["quality_band"]
+    band = _later_json("rf-reg-grid18-probe.json")["checks"]["quality_band"]
     ref = band["reference"]["OpRandomForestRegressor"]
     assert "perfbench/reference/rf_grid.py --problem regression" in (
         ref["command"])

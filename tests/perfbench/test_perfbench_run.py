@@ -15,10 +15,10 @@ import sys
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from _child import RESULT_KEYS, ROOT, TINY, run_cell  # noqa: E402
+from _child import RESULT_KEYS, ROOT, TINY, run_cell, tiny_run  # noqa: E402
 
 sys.path.insert(0, ROOT)
-from perfbench import spec  # noqa: E402
+from perfbench import checks, spec  # noqa: E402
 
 BENCH = spec.load_benchmark()
 ONE_CHIP = [w["name"] for w in BENCH["workloads"] if w["chips"] == 1]
@@ -37,7 +37,7 @@ def _check_last_line(last, cell: str, traced: bool, devices: int = 1):
     assert last["rehearsal"] is True
     # each number compared beside its limit, as the line's last key
     assert list(last)[-1] == "compared"
-    assert {"aupr_over_oracle", "tree_scorer_diff",
+    assert {tiny_run(cell)["oracle_key"], "tree_scorer_diff",
             "window_programs"} <= set(last["compared"])
     for value, limit in last["compared"].values():
         assert value is not None and limit is not None
@@ -58,7 +58,9 @@ def _check_last_line(last, cell: str, traced: bool, devices: int = 1):
 
 @pytest.mark.parametrize("cell", ONE_CHIP)
 def test_cell_runs_tiny_on_cpu_and_prints_the_contract_s_line(cell, cache_dir):
-    out, last = run_cell(cell, "--allow-cpu", *TINY, cache_dir=cache_dir)
+    tiny = tiny_run(cell)
+    out, last = run_cell(cell, "--allow-cpu", *tiny["flags"],
+                         cache_dir=cache_dir)
     assert out.returncode == 0, out.stderr[-3000:]
     units = _check_last_line(last, cell, traced=False)
     # every end-to-end metric of the cell, but for the one taken from the
@@ -67,7 +69,7 @@ def test_cell_runs_tiny_on_cpu_and_prints_the_contract_s_line(cell, cache_dir):
               if m["source"] == "device_trace"}
     assert device == {"train_device_s"}
     assert set(last["metrics"]) == set(units) - device
-    assert last["metrics"]["holdout_aupr"]["value"] > 0
+    assert last["metrics"][tiny["holdout"]]["value"] > 0
     assert last["metrics"]["setup_s"]["value"] > 0
     # such a cell traces every train of its window, untraced run or not
     assert "[perfbench] trace file=" in out.stdout
@@ -75,8 +77,30 @@ def test_cell_runs_tiny_on_cpu_and_prints_the_contract_s_line(cell, cache_dir):
     assert "x64=false" in out.stdout
     assert f'cache_dir="{cache_dir}"' in out.stdout
     assert "[perfbench] window trains=" in out.stdout
-    # planted_linear defines no oracle_score: the float32 matrix by beta
-    assert 'oracle_from="X @ beta"' in out.stdout
+    # the generator's own oracle; planted_linear has none: the float32
+    # matrix by beta
+    assert f'oracle_from="{tiny["oracle_from"]}"' in out.stdout
+
+
+@pytest.mark.parametrize("cell", ONE_CHIP)
+def test_a_tiny_run_asks_what_the_cell_s_configuration_gives(cell):
+    """The test above asks of each cell what its label kind and generator
+    give, and no cell is named there; this runs no cell, so a copy that
+    holds a new one runs it too."""
+    tiny = tiny_run(cell)
+    loaded = spec.load_cell(cell)
+    config = loaded["config"]
+    holdouts = {k.holdout for k in checks.LABEL_KINDS.values()}
+    reported = {m["name"] for m in loaded["end_to_end"]}
+    assert reported & holdouts == {tiny["holdout"]}
+    generator = spec.load_module("generators", config["generator"]["name"])
+    if tiny["oracle_from"] == "X @ beta":
+        assert config["problem"] == "binary"
+    else:
+        assert callable(getattr(generator, tiny["oracle_from"]))
+    # a schema that lists its columns is drawn at its own width
+    assert ("--cols" in tiny["flags"]) == (
+        "columns" not in config["schema"]["predictors"])
 
 
 def test_traced_run_prints_per_layer_metrics_and_a_breakdown(cache_dir):
