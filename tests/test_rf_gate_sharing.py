@@ -254,10 +254,12 @@ def test_two_gate_grid_counts_one_base(two_gates):
     got = dict(two_gates["counters"])
     for shape in ("chunk", "launches", "msub", "levels", "scoredRows"):
         got.pop(shape)
-    # one base at (depth 3, gate 0.001) x folds, and a pair a refit
+    # one base at (depth 3, gate 0.001) x folds, and a pair a refit; the
+    # longer fold trains on 1,028 rows, which round up to the table's 2,000:
+    # grown on every row
     assert got == {"candidates": 4, "bases": 1, "pairs": FOLDS + 3,
                    "truncated": 2, "gateShared": 2,
-                   "treesGrown": (FOLDS + 3) * TREES}
+                   "treesGrown": (FOLDS + 3) * TREES, "grownRows": ROWS}
 
 
 @pytest.mark.parametrize("row", [1, 2, 3],

@@ -180,7 +180,9 @@ def test_rf_grid_counters_say_what_was_asked_for_and_what_was_grown(grown):
                    "treesGrown": sweep + 2 * TREES,
                    "msub": grown["msub"], "levels": BASE_DEPTH,
                    # the longest fold's validation rows, rounded up
-                   "scoredRows": 1024}
+                   "scoredRows": 1024,
+                   # its training rows (1,368) round up to the table's
+                   "grownRows": ROWS}
     # the chunker's own count: the sweep's launches and one a refit
     assert 1 <= chunk <= sweep
     assert launches == -(-sweep // chunk) + 2

@@ -1569,7 +1569,7 @@ def _grow_chunk_rf_grid(binned, Y, W_tr, seed, flat_start, total,
                         min_child_weight, t_per: int,
                         onehot_targets: bool = False,
                         prune_outputs: bool = False,
-                        hist_bf16: bool = False):
+                        hist_bf16: bool = False, bag_rows=None):
     """RF chunk spanning the WHOLE (candidate x fold) grid.
 
     Flat tree index i = pair * t_per + t: tree t of grid pair ``i // t_per``
@@ -1580,20 +1580,28 @@ def _grow_chunk_rf_grid(binned, Y, W_tr, seed, flat_start, total,
     candidate's forest for every fold with results identical to the
     per-candidate path (same randomness, same split masking).
 
+    ``bag_rows`` (L,): the table rows that ``binned`` holds, where the
+    launch grows one fold's pairs on that fold's own rows
+    (``grow_rf_grid``'s ``rows``); ``W_tr`` then holds the folds' weights
+    at their own rows.  The bags are still drawn over the table's rows
+    (``Y``'s) and taken at these, and so are the targets.
+
     ``prune_outputs`` additionally emits each tree's level values, gate
     ratios and unsplit feature (see ``_grow_tree_traced``), which lets the
     caller run only the unique min_instances x fold pairs at their deepest
     grid depth and lowest min_info_gain and derive every shallower and
     every higher-gated candidate for free (``prune_rf_grid``).
     """
-    n, d = binned.shape
+    n, d = Y.shape[0], binned.shape[1]
     flat = flat_start + jnp.arange(chunk)
     t_loc = (flat % t_per).astype(jnp.int32)
     p_idx = jnp.minimum(flat // t_per, pair_fold.shape[0] - 1)
     BWr, feat_idx = jax.vmap(
         lambda tid: _rf_bag_and_features(tid, seed, n, d, msub,
                                          subsample_rate))(t_loc)
-    base_w = W_tr[pair_fold[p_idx]]                       # (chunk, N)
+    if bag_rows is not None:
+        BWr, Y = BWr[:, bag_rows], Y[bag_rows]
+    base_w = W_tr[pair_fold[p_idx]]                       # (chunk, rows)
     BW = base_w * BWr * (flat < total)[:, None]
     kw = dict(max_depth=max_depth, n_bins=n_bins, lam=lam,
               min_child_weight=min_child_weight, newton_leaf=jnp.bool_(False),
@@ -1612,14 +1620,35 @@ def _grow_chunk_rf_grid(binned, Y, W_tr, seed, flat_start, total,
                          pair_depth[p_idx], feat_idx)
 
 
+@jax.jit
+def _fold_rows_jit(binned, rows):
+    """``binned[rows]``, whole rows of the matrix: one fold's rows for the
+    forest grid, its training rows to grow on (``grow_rf_grid``) or its
+    validation rows to score on (``selector/grid_groups._fold_eval_rows``);
+    every index is a row's."""
+    return jnp.take(binned, rows, axis=0, mode="clip")
+
+
 def grow_rf_grid(binned, Y, W_tr, seed: int, n_trees: int,
                  pair_fold: np.ndarray, pair_min_ig: np.ndarray,
                  pair_min_inst: np.ndarray, pair_depth: np.ndarray,
                  msub: int, subsample_rate: float, n_bins: int,
                  lam: float = 1e-3, min_child_weight: float = 0.0,
-                 onehot_targets: bool = False, prune_outputs: bool = False):
-    """Grow every (candidate x fold) pair's forest as one chunked launch
-    stream; returns device (P, T, nodes...) stacked ensembles.
+                 onehot_targets: bool = False, prune_outputs: bool = False,
+                 rows: Optional[np.ndarray] = None):
+    """Grow every (candidate x fold) pair's forest as chunked launch
+    streams; returns device (P, T, nodes...) stacked ensembles.
+
+    ``rows`` (F, L): each fold's weighted table rows, padded to one length
+    (``selector/grid_groups._fold_train_rows``), with ``W_tr`` (F, L) the
+    folds' weights at them (0 on the padding).  A fold's pairs are then
+    grown on ``binned[rows[f]]``, gathered once a fold, in launches that
+    hold that fold's trees alone; their bags are drawn over the table's
+    rows and taken at the fold's, so where every histogram sum is exact
+    (whole-number weights) the trees are those grown on the table's rows.
+    Without ``rows`` every pair grows on the table's rows in one stream.
+    Either way the chunk is evened over a stream's trees: no more padding
+    trees than its launches need.
 
     With ``prune_outputs``, additionally returns a 4th element
     ``(level_values, gate_ratio, unsplit_feat)``: one (P, T, 2^l, K) array a
@@ -1631,51 +1660,89 @@ def grow_rf_grid(binned, Y, W_tr, seed: int, n_trees: int,
     higher gate by cutting the nodes whose ratio fails it (exact because the
     gate takes no part in choosing a split).
     """
-    n, d = binned.shape
+    d = binned.shape[1]
     k = Y.shape[1]
+    pair_fold = np.asarray(pair_fold, np.int32)
     P = int(pair_fold.shape[0])
     # >= 1: an all-stump grid (every max_depth <= 0) still needs one heap
     # level to emit leaf arrays (depth_limit 0 keeps the trees split-free)
     heap_depth = _resolve_compile_depth(max(int(pair_depth.max()), 1))
     hist_bf16 = _accel_bf16()
-    chunk = forest_chunk_size(
-        n_trees * P, heap_depth, msub, n_bins, k, n_rows=n,
+    # (fold or None, its pairs): one stream a fold on the fold's rows, or
+    # one stream of every pair on the table's
+    if rows is None:
+        streams = [(None, np.arange(P))]
+        n_rows = binned.shape[0]
+    else:
+        streams = [(f, np.flatnonzero(pair_fold == f))
+                   for f in range(rows.shape[0])]
+        streams = [(f, pairs) for f, pairs in streams if len(pairs)]
+        n_rows = rows.shape[1]
+    # every stream's pair arrays padded to the longest's: one program shape
+    width = max(len(pairs) for _, pairs in streams)
+    per = n_trees * width
+    cap = forest_chunk_size(
+        per, heap_depth, msub, n_bins, k, n_rows=n_rows,
         n_channels=(k if onehot_targets else k + 1), d_full=d,
         onehot_bytes=2 if hist_bf16 else 4)
-    total = n_trees * P
-    pf = jnp.asarray(pair_fold, jnp.int32)
-    pg = jnp.asarray(pair_min_ig, jnp.float32)
-    pi = jnp.asarray(pair_min_inst, jnp.float32)
-    pd_ = jnp.asarray(pair_depth, jnp.int32)
+    chunk = -(-per // -(-per // cap))
+    launches = [-(-len(pairs) * n_trees // chunk) for _, pairs in streams]
     from ..utils.profiling import count_rf_grid, launch
 
-    count_rf_grid(treesGrown=total, launches=-(-total // chunk), chunk=chunk,
-                  msub=msub, levels=heap_depth)
+    count_rf_grid(treesGrown=n_trees * P, launches=sum(launches),
+                  chunk=chunk, msub=msub, levels=heap_depth)
     parts = []
-    for s in range(0, total, chunk):
-        with launch("rf_grid_chunk"):
-            out = _grow_chunk_rf_grid(
-                binned, Y, W_tr, jnp.int32(seed), jnp.int32(s),
-                jnp.int32(total), pf, pg, pi, pd_,
-                jnp.float32(subsample_rate), chunk, msub,
-                heap_depth, n_bins, jnp.float32(lam),
-                jnp.float32(min_child_weight), n_trees,
-                onehot_targets=onehot_targets, prune_outputs=prune_outputs,
-                hist_bf16=hist_bf16)
-        parts.append(out)
-    out = _stack_rf_grid_chunks(parts, total, n_trees)
+    for f, pairs in streams:
+        sel = np.concatenate([pairs, np.repeat(pairs[-1:],
+                                               width - len(pairs))])
+        if f is None:
+            mat, bag_rows = binned, None
+        else:
+            bag_rows = jnp.asarray(rows[f], jnp.int32)
+            mat = _fold_rows_jit(binned, bag_rows)
+        pf, pg, pi, pd_ = (jnp.asarray(np.asarray(a)[sel], t) for a, t in (
+            (pair_fold, jnp.int32), (pair_min_ig, jnp.float32),
+            (pair_min_inst, jnp.float32), (pair_depth, jnp.int32)))
+        total = n_trees * len(pairs)
+        for s in range(0, total, chunk):
+            with launch("rf_grid_chunk"):
+                out = _grow_chunk_rf_grid(
+                    mat, Y, W_tr, jnp.int32(seed), jnp.int32(s),
+                    jnp.int32(total), pf, pg, pi, pd_,
+                    jnp.float32(subsample_rate), chunk, msub,
+                    heap_depth, n_bins, jnp.float32(lam),
+                    jnp.float32(min_child_weight), n_trees,
+                    onehot_targets=onehot_targets,
+                    prune_outputs=prune_outputs, hist_bf16=hist_bf16,
+                    bag_rows=bag_rows)
+            parts.append(out)
+    stacked = np.concatenate([pairs for _, pairs in streams])
+    order = (None if np.array_equal(stacked, np.arange(P))
+             else tuple(np.argsort(stacked).tolist()))
+    out = _stack_rf_grid_chunks(
+        parts, tuple((n, n_trees * len(pairs))
+                     for n, (_, pairs) in zip(launches, streams)),
+        n_trees, order)
     return out if prune_outputs else out[:3]
 
 
-@functools.partial(jax.jit, static_argnames=("total", "n_trees"))
-def _stack_rf_grid_chunks(parts, total: int, n_trees: int):
-    """The launches' outputs as (pairs, trees, ...) arrays, the last
-    launch's padding trees dropped: ONE program for all of a sweep's output
-    arrays (a slice, a concatenation and a reshape each would be three
-    programs an array to load, and as many dispatches a train)."""
+@functools.partial(jax.jit, static_argnames=("streams", "n_trees", "order"))
+def _stack_rf_grid_chunks(parts, streams, n_trees: int, order):
+    """The launches' outputs as (pairs, trees, ...) arrays: ONE program for
+    all of a grid's output arrays (a slice, a concatenation and a reshape
+    each would be three programs an array to load, and as many dispatches
+    a train).  ``streams`` gives each stream's (launches, trees), in the
+    order of ``parts``; a stream's last launch's padding trees are
+    dropped.  ``order``: where the streams' pairs stand in the output's
+    order, the row of each output pair in the stacked streams."""
     def stack(*chunks):
-        flat = jnp.concatenate(chunks)[:total]
-        return flat.reshape(total // n_trees, n_trees, *flat.shape[1:])
+        flats, at = [], 0
+        for launches, trees in streams:
+            flats.append(jnp.concatenate(chunks[at:at + launches])[:trees])
+            at += launches
+        flat = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
+        pairs = flat.reshape(-1, n_trees, *flat.shape[1:])
+        return pairs if order is None else pairs[np.asarray(order)]
 
     return jax.tree_util.tree_map(stack, *parts)
 

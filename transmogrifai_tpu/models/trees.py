@@ -152,14 +152,6 @@ def _score_ensemble_jit(binned, feat, thresh, leaf, base_score, depth: int,
     return raw[:, 0] + base_score  # gbdt_reg
 
 
-@jax.jit
-def _fold_rows_jit(binned, rows):
-    """``binned[rows]``, whole rows of the matrix: one fold's validation
-    rows for the forest grid's pair scoring
-    (``selector/grid_groups._fold_eval_rows``; every index is a row's)."""
-    return jnp.take(binned, rows, axis=0, mode="clip")
-
-
 import contextlib
 import threading
 from collections import OrderedDict

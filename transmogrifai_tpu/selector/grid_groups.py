@@ -410,9 +410,13 @@ class RFGridGroup(TreeGridGroup):
     identical randomness to the sequential per-candidate fits.  Candidates
     that differ only in max_depth and min_info_gain share ONE grown base
     forest a fold (the deepest, under the lowest gate) and are read off it
-    by truncation and pruning (``gbdt_kernels.prune_rf_grid``).  A pair is
-    scored and ranked on its own fold's validation rows, compacted once a
-    sweep (``_fold_eval_rows``), not on every row of the table.  Covers
+    by truncation and pruning (``gbdt_kernels.prune_rf_grid``).  A fold's
+    base forests are grown on the fold's own training rows, and a pair is
+    scored and ranked on its own fold's validation rows, each compacted
+    once a sweep (``_fold_train_rows``, ``_fold_eval_rows``), not on every
+    row of the table; the bags are still drawn over the table's rows, so
+    the trees are those the table's rows grow.  The winner's refit grows
+    on the rows its full weights keep.  Covers
     binary, multiclass (one-hot targets, argmax scores against the
     multiclass metric grid) and regression sweeps.  On a sweep mesh the
     same pair stream runs sharded (``grow_rf_grid_sharded``) with
@@ -446,9 +450,9 @@ class RFGridGroup(TreeGridGroup):
                                           binary_metric_grid,
                                           multiclass_metric_grid,
                                           regression_metric_grid)
-        from ..models.gbdt_kernels import grow_rf_grid, prune_rf_grid
-        from ..models.trees import (_dev_memo, _feature_subset_size,
-                                    _fold_rows_jit)
+        from ..models.gbdt_kernels import (_fold_rows_jit, grow_rf_grid,
+                                           prune_rf_grid)
+        from ..models.trees import _dev_memo, _feature_subset_size
         from ..obs.trace import span as _span
         from ..utils.profiling import count_rf_grid
 
@@ -490,6 +494,10 @@ class RFGridGroup(TreeGridGroup):
         C = len(self.grid_points)
         T = int(self._param(self.grid_points[0], "num_trees"))
         ev_rows, W_ev_c = _fold_eval_rows(W_ev)
+        # a fold's forests grow on the rows its train weights keep (on the
+        # mesh, whose rows are sharded, on every row)
+        tr_rows, W_tr_c = ((None, W_tr) if self.mesh is not None
+                           else _fold_train_rows(W_tr))
 
         # Depth and gate sharing: candidates that differ ONLY in max_depth
         # and min_info_gain share bags/folds by construction (bags key on
@@ -546,7 +554,8 @@ class RFGridGroup(TreeGridGroup):
         pair_inst = np.repeat([k[0] for k in base_keys], F)
         pair_depth = np.repeat(base_depth, F)
         count_rf_grid(candidates=C, bases=Cb, pairs=Cb * F,
-                      truncated=sum(truncated), gateShared=sum(gate_shared))
+                      truncated=sum(truncated), gateShared=sum(gate_shared),
+                      grownRows=W_tr_c.shape[1])
         t0 = _time.perf_counter()
         subsample = float(self._param(self.grid_points[0],
                                       "subsample_rate"))
@@ -562,12 +571,12 @@ class RFGridGroup(TreeGridGroup):
                     prune_outputs=prune)
             else:
                 grown = grow_rf_grid(
-                    binned, _dev_memo(Y, "rf_Y"), _dev_memo(W_tr, "rf_Wtr"),
-                    seed=int(proto.seed), n_trees=T, pair_fold=pair_fold,
-                    pair_min_ig=pair_ig, pair_min_inst=pair_inst,
-                    pair_depth=pair_depth, msub=msub,
-                    subsample_rate=subsample, n_bins=mb,
-                    onehot_targets=cls, prune_outputs=prune)
+                    binned, _dev_memo(Y, "rf_Y"),
+                    _dev_memo(W_tr_c, "rf_Wtr"), seed=int(proto.seed),
+                    n_trees=T, pair_fold=pair_fold, pair_min_ig=pair_ig,
+                    pair_min_inst=pair_inst, pair_depth=pair_depth,
+                    msub=msub, subsample_rate=subsample, n_bins=mb,
+                    onehot_targets=cls, prune_outputs=prune, rows=tr_rows)
         self._record_grid_observation(_time.perf_counter() - t0, n, d)
         feats, threshs, leaves = grown[:3]
         heap_depth = int(np.log2(feats.shape[2] + 1))
@@ -730,11 +739,12 @@ class RFGridGroup(TreeGridGroup):
         dt = ctx["cand_depth"][row]
         bd = ctx["base_depth"][bi]
         count_rf_grid(pairs=1)
+        rows, w_full = _fold_train_rows(ctx["full_w"][None])
         with _span("rf.grid.refit", cat="sweep", depth=dt, base_depth=bd), \
                 compile_depth_hint(ctx["heap_depth"]):
             grown = grow_rf_grid(
                 ctx["binned"], _dev_memo(ctx["Y"], "rf_Y"),
-                _dev_memo(ctx["full_w"][None], "rf_Wfull"),
+                _dev_memo(w_full, "rf_Wfull"),
                 seed=ctx["seed"], n_trees=ctx["T"],
                 pair_fold=np.zeros(1, np.int32),
                 pair_min_ig=np.asarray([ctx["cand_ig"][row]], np.float32),
@@ -742,7 +752,8 @@ class RFGridGroup(TreeGridGroup):
                                          np.float32),
                 pair_depth=np.asarray([bd], np.int32), msub=ctx["msub"],
                 subsample_rate=ctx["subsample"], n_bins=ctx["mb"],
-                onehot_targets=ctx["cls"], prune_outputs=ctx["prune"])
+                onehot_targets=ctx["cls"], prune_outputs=ctx["prune"],
+                rows=rows)
         feats, threshs, leaves = grown[:3]
         if dt < bd:
             nd = 2 ** dt - 1
@@ -763,25 +774,38 @@ class RFGridGroup(TreeGridGroup):
 _FOLD_ROWS_MULTIPLE = 1024
 
 
-def _fold_eval_rows(W_ev: np.ndarray):
-    """``(rows, weights)``, both (F, L): for each fold the rows its metric
-    reads (eval weight above 0: not the rows it trained on, nor what a
-    balancer or a hold-out reservation dropped) and their weights.  A
-    shorter fold is padded with its own last row under weight 0, which adds
-    nothing to any metric.  ``(None, W_ev)`` where L would reach the
-    table's rows: nothing to leave out."""
-    keep = [np.flatnonzero(w > 0) for w in W_ev]
+def _weighted_fold_rows(W: np.ndarray):
+    """``(rows, weights)``, both (F, L): for each fold the rows its weights
+    ``W[f]`` keep (above 0) and those weights.  A shorter fold is padded
+    with its own last row under weight 0, which adds nothing to a metric,
+    a histogram or a leaf.  ``(None, W)`` where L would reach the table's
+    rows: nothing to leave out."""
+    keep = [np.flatnonzero(w > 0) for w in W]
     L = -(-max(len(k) for k in keep) // _FOLD_ROWS_MULTIPLE
           ) * _FOLD_ROWS_MULTIPLE
-    if L >= W_ev.shape[1]:
-        return None, W_ev
+    if L >= W.shape[1]:
+        return None, W
     rows = np.zeros((len(keep), L), np.int32)
     weights = np.zeros((len(keep), L), np.float32)
     for f, k in enumerate(keep):
         rows[f, :len(k)] = k
         rows[f, len(k):] = k[-1] if len(k) else 0
-        weights[f, :len(k)] = W_ev[f, k]
+        weights[f, :len(k)] = W[f, k]
     return rows, weights
+
+
+def _fold_eval_rows(W_ev: np.ndarray):
+    """The rows a fold's metric reads (eval weight above 0: not the rows it
+    trained on, nor what a balancer or a hold-out reservation dropped),
+    compacted by ``_weighted_fold_rows``."""
+    return _weighted_fold_rows(W_ev)
+
+
+def _fold_train_rows(W_tr: np.ndarray):
+    """The rows a fold's forests are grown on (train weight above 0: not
+    its validation rows, nor what a balancer or a hold-out reservation
+    dropped), compacted by ``_weighted_fold_rows``."""
+    return _weighted_fold_rows(W_tr)
 
 
 def _score_pairs_jit(mats, feats, threshs, leaves, heap_depth: int,

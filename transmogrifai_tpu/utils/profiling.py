@@ -219,7 +219,8 @@ class RunCounters:
     #: ``treesGrown``, ``launches`` of ``chunk`` trees at histogram width
     #: ``msub`` and ``levels`` heap levels) and on how many rows a
     #: candidate pair was scored (``scoredRows``: a fold's validation rows,
-    #: not the table's)
+    #: not the table's) and a fold's base forests grown (``grownRows``: a
+    #: fold's training rows, not the table's)
     rf_grid: Dict[str, int] = field(default_factory=dict)
     #: ``perf_counter()`` of the run's start: ``OpWorkflow.train`` stamps
     #: it on entry (``mark_run_start``), else it is the moment these
@@ -364,14 +365,14 @@ def count_memo(kind: str, outcome: str) -> None:
 
 
 #: ``rfGrid`` keys that describe a launch's shape: the largest seen is kept
-_RF_GRID_SHAPES = ("chunk", "msub", "levels", "scoredRows")
+_RF_GRID_SHAPES = ("chunk", "msub", "levels", "scoredRows", "grownRows")
 
 
 def count_rf_grid(**counts: int) -> None:
     """Random-forest grid accounting (``RunCounters.rf_grid``): counts add
     up over a run (the sweep's base pairs and the winner's refit are two
-    calls); the shape keys ``chunk``, ``msub``, ``levels`` and
-    ``scoredRows`` keep the largest value seen."""
+    calls); the shape keys ``chunk``, ``msub``, ``levels``, ``scoredRows``
+    and ``grownRows`` keep the largest value seen."""
     with _COUNTERS_LOCK:
         tags = COUNTERS.rf_grid
         for key, n in counts.items():
